@@ -7,7 +7,7 @@ The AST tier (rules.py) sees code shapes; the runtime tier
 away, a ``pure_callback`` smuggled into a hot path, a weak-typed scalar
 poisoning a jit cache key.  This tier traces each registered entry
 point (analysis/entrypoints.py) to a ClosedJaxpr and walks every
-equation, recursing into pjit/scan/while/cond/pallas_call sub-jaxprs:
+equation, recursing into jit/scan/while/cond/pallas_call sub-jaxprs:
 
 - ``audit-float-lane``    — no inexact dtype may appear in a GF-lane
   program outside the entry's whitelisted primitives (the MXU
@@ -15,7 +15,8 @@ equation, recursing into pjit/scan/while/cond/pallas_call sub-jaxprs:
 - ``audit-callback``      — no ``io_callback`` / ``pure_callback`` /
   ``debug_callback`` in a traced hot path (each is a host round-trip
   per dispatch).
-- ``audit-transfer``      — no ``device_put`` inside a traced region
+- ``audit-transfer``      — no ``device_put`` and no host array
+  constant inside a traced region
   (a transfer baked into the program defeats the batch-first design).
 - ``audit-weak-type``     — no weak-typed avals entering the program
   or crossing an inner jit boundary (Python scalars that fork the jit
@@ -60,7 +61,7 @@ AUDIT_RULE_IDS = (
 SENTINEL_RULE = "audit-recompile"
 ERROR_RULE = "audit-error"
 
-CALLBACK_PRIMS = frozenset({"pure_callback", "io_callback",
+CALLBACK_PRIMS = frozenset({"pure_callback", "io_callback", "debug_print",
                             "debug_callback"})
 TRANSFER_PRIMS = frozenset({"device_put"})
 
@@ -73,14 +74,13 @@ _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 # jaxpr walking
 
 def _jaxpr_types():
-    import jax
+    from jax.extend import core
 
-    core = jax.core
     return core.ClosedJaxpr, core.Jaxpr, core.Literal
 
 
 def _sub_jaxprs(value) -> Iterator[object]:
-    """Yield every Jaxpr reachable from an eqn param value (pjit's
+    """Yield every Jaxpr reachable from an eqn param value (jit's
     ClosedJaxpr, scan/while bodies, cond branch tuples, pallas_call's
     raw Jaxpr)."""
     ClosedJaxpr, Jaxpr, _ = _jaxpr_types()
@@ -282,6 +282,15 @@ def _check_callbacks(entry, built, closed) -> List[Finding]:
 
 def _check_transfers(entry, built, closed) -> List[Finding]:
     out: List[Finding] = []
+    # a host array captured by the traced fn (jax.device_put or numpy
+    # fancy indexing) is hoisted to a non-scalar program constant: an
+    # upload per program build, plus the dynamic gather it feeds
+    baked = [c for c in closed.consts if getattr(c, "size", 1) > 1]
+    if baked:
+        out.append(_finding(
+            entry, built, "audit-transfer",
+            f"{len(baked)} host array constant(s) baked into a traced "
+            f"region (stage inputs before the jit boundary)"))
     seen = set()
     for eqn in iter_eqns(closed.jaxpr):
         name = eqn.primitive.name
@@ -308,7 +317,7 @@ def _check_weak_types(entry, built, closed) -> List[Finding]:
                 f"trace forks the jit cache key per call site"))
     seen = set()
     for eqn in iter_eqns(closed.jaxpr):
-        if eqn.primitive.name != "pjit":
+        if eqn.primitive.name != "jit":
             continue
         for v in eqn.invars:
             if isinstance(v, Literal):
@@ -402,10 +411,10 @@ def run_sentinel(entry: EntryPoint, built: Optional[Built] = None,
     try:
         if built is None:
             built = entry.build()
-        with _CompileCounter() as cold:
+        with built.scope(), _CompileCounter() as cold:
             out = built.fn(*built.args)
             _block(out)
-        with _CompileCounter() as warm:
+        with built.scope(), _CompileCounter() as warm:
             out2 = built.fn(*built.args)
             _block(out2)
     except Exception as e:  # noqa: BLE001 — reported, never swallowed
@@ -463,7 +472,8 @@ def audit_entry_point(entry: EntryPoint, built: Optional[Built] = None,
     try:
         if built is None:
             built = entry.build()
-        closed = jax.make_jaxpr(built.fn)(*built.args)
+        with built.scope():
+            closed = jax.make_jaxpr(built.fn)(*built.args)
     except Exception as e:  # noqa: BLE001 — reported, never swallowed
         audit.findings.append(_finding(
             entry, built, ERROR_RULE,

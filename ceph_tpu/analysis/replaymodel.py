@@ -210,8 +210,8 @@ ENV_SEAMS: Tuple[EnvSeam, ...] = (
     EnvSeam("utils.debug", "verification_enabled", ("CEPH_TPU_VERIFY",),
             "sanitizer gate: diagnostics, not dataplane state"),
     EnvSeam("utils.compile_cache", "compile_cache_dir",
-            ("CEPH_TPU_COMPILE_CACHE",),
-            "persistent-cache dir knob, read under an init memo"),
+            ("JAX_COMPILATION_CACHE_DIR",),
+            "persistent-cache dir, read at entry-point init"),
     EnvSeam("ops.fallback", "FallbackPolicy.__init__",
             ("CEPH_TPU_ENGINE",),
             "engine-tier override, bound at policy construction"),
@@ -224,9 +224,6 @@ ENV_SEAMS: Tuple[EnvSeam, ...] = (
     EnvSeam("telemetry.tracing", "maybe_install_from_env",
             ("CEPH_TPU_TRACE",),
             "the documented tracing opt-in, consulted at run start"),
-    EnvSeam("telemetry.profiler", "resolve_peak_gbps",
-            ("CEPH_TPU_HBM_PEAK_GBPS",),
-            "roofline denominator override"),
     EnvSeam("telemetry.recorder", "FlightRecorder.dump",
             ("CEPH_TPU_FLIGHT_DIR",),
             "post-mortem sink dir; dump contents stay deterministic"),

@@ -336,4 +336,6 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from ..utils.compile_cache import initialize_compile_cache
+    initialize_compile_cache()
     sys.exit(main())

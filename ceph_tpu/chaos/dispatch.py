@@ -5,18 +5,18 @@ The injectors in chaos/injectors.py damage *stored bytes*; the
 adversaries in chaos/adversaries.py attack the *orchestration* (crash
 sites, map churn).  This module attacks the third surface: the device
 dispatch itself — the seam where a host call hands a batch to XLA and
-a tunnel drop, an HBM OOM, a hang or a corrupted DMA turns a healthy
+a lost device, an HBM OOM, a hang or a corrupted DMA turns a healthy
 program into a mid-run outage.  Fault kinds (the classification the
 supervisor must recover):
 
 - ``transient``     — the dispatch raises TransientBackendError for
-                      the armed call window (flaky tunnel; bounded
+                      the armed call window (flaky backend; bounded
                       utils/retry backoff must absorb it),
 - ``oom``           — the dispatch raises a RESOURCE_EXHAUSTED-shaped
                       error (HBM OOM; the supervisor splits the batch
                       rung and redispatches the halves),
 - ``backend_loss``  — the dispatch raises a backend-unavailable error
-                      for every call in the window (the tunnel died;
+                      for every call in the window (the device died;
                       live FallbackPolicy demotion pallas→xla→numpy),
 - ``hang``          — the dispatch consumes more than the supervisor's
                       deadline on the injectable clock and then fails
@@ -199,7 +199,7 @@ class DispatchFaultPlan:
         return False
 
     def clear(self) -> None:
-        """Heal: every armed fault stops firing (the 'tunnel came
+        """Heal: every armed fault stops firing (the 'device came
         back' event the re-promotion probe then observes)."""
         with self._lock:
             self.cleared = True

@@ -358,16 +358,15 @@ class ErasureCodeClay(ErasureCode):
 
     def encode_chunks_batch(self, data: np.ndarray) -> np.ndarray:
         """(batch, k, chunk) -> (batch, m, chunk) via the probed composite
-        encode matrix (one GF(2^8) matrix application; the host tier
-        runs the identical XOR schedule when the probe prefers one —
-        ops/xor_schedule.py)."""
-        from ...ops.xor_schedule import host_matrix_apply
+        encode matrix: one GF(2^8) matrix application, on the device
+        above the shared size threshold (codes/techniques.py), the
+        host schedule below it."""
+        from ..techniques import apply_matrix_batch
         M, ms = self._encode_composite()
         b, k, chunk = data.shape
         sub = self.sub_chunk_no
-        sc = chunk // sub
-        x = data.reshape(b, k * sub, sc)
-        y = host_matrix_apply(x, M, ms, W)
+        x = np.ascontiguousarray(data).reshape(b, k * sub, chunk // sub)
+        y = apply_matrix_batch(x, M, ms, W)
         return y.reshape(b, self.m, chunk)
 
     # -- minimum_to_decode (ErasureCodeClay.cc -> minimum_to_decode) --------
@@ -495,14 +494,14 @@ class ErasureCodeClay(ErasureCode):
     def decode_chunks_batch(self, chunks: np.ndarray, available: tuple,
                             erased: tuple) -> np.ndarray:
         """(batch, len(available), chunk) -> (batch, len(erased), chunk)
-        via a probed per-pattern composite decode matrix."""
-        from ...ops.xor_schedule import host_matrix_apply
+        via a probed per-pattern composite decode matrix (device or
+        host exactly like encode_chunks_batch)."""
+        from ..techniques import apply_matrix_batch
         M, ms = self._decode_composite(tuple(available), tuple(erased))
         b, na, chunk = chunks.shape
         sub = self.sub_chunk_no
-        sc = chunk // sub
-        x = np.ascontiguousarray(chunks).reshape(b, na * sub, sc)
-        y = host_matrix_apply(x, M, ms, W)
+        x = np.ascontiguousarray(chunks).reshape(b, na * sub, chunk // sub)
+        y = apply_matrix_batch(x, M, ms, W)
         return y.reshape(b, len(erased), chunk)
 
     # -- repair (ErasureCodeClay.cc -> repair / repair_one_lost_chunk) ------

@@ -48,6 +48,44 @@ def _numpy_tier() -> bool:
     return global_policy().engine() == "numpy"
 
 
+def apply_matrix_batch(chunks: np.ndarray, matrix: np.ndarray,
+                       matrix_static, w: int,
+                       min_xla_bytes: int = 1 << 20) -> np.ndarray:
+    """The host-array matrix apply every GF(2^w) matrix code's
+    ``*_chunks_batch`` surface shares (MatrixCodeMixin, clay's
+    composites): below ``min_xla_bytes`` (or on the numpy tier) the
+    host schedule runs, above it one device dispatch through
+    :func:`apply_matrix_best`."""
+    from ..telemetry.metrics import record_dispatch
+    perf = global_perf()
+    words = regionops.words_view(np.ascontiguousarray(chunks), w)
+    if chunks.nbytes < min_xla_bytes or _numpy_tier():
+        perf.inc("ec_host_calls")
+        perf.inc("ec_host_bytes", chunks.nbytes)
+        with record_dispatch("ec_apply", path="host"):
+            # the numpy tier executes the IDENTICAL XOR schedule
+            # the device kernels run when the probe prefers one
+            # (ops/xor_schedule.py); regionops stays the ground
+            # truth for everything else — byte-identical either
+            # way (corpus + fuzz pinned)
+            from ..ops.xor_schedule import host_matrix_apply
+            return host_matrix_apply(
+                np.ascontiguousarray(chunks), matrix, matrix_static, w)
+    perf.inc("ec_device_calls")
+    perf.inc("ec_device_bytes", chunks.nbytes)
+    with perf.timed("ec_device_time"), \
+            record_dispatch("ec_apply", path="device"):
+        out = np.asarray(
+            apply_matrix_best(words, matrix_static, w)).view(np.uint8)
+    if verification_enabled():
+        ref = regionops.matrix_encode(words, matrix, w).view(np.uint8)
+        if not np.array_equal(out, ref):
+            raise DeviceVerificationError(
+                "device matrix path diverged from host ground truth "
+                f"(w={w}, shape={chunks.shape})")
+    return out
+
+
 class MatrixCodeMixin:
     """Compute paths for GF(2^w)-element matrix codes.
 
@@ -66,37 +104,8 @@ class MatrixCodeMixin:
 
     def _apply(self, chunks: np.ndarray, matrix: np.ndarray,
                matrix_static) -> np.ndarray:
-        from ..telemetry.metrics import record_dispatch
-        perf = global_perf()
-        words = regionops.words_view(np.ascontiguousarray(chunks), self.w)
-        if chunks.nbytes < self.min_xla_bytes or _numpy_tier():
-            perf.inc("ec_host_calls")
-            perf.inc("ec_host_bytes", chunks.nbytes)
-            with record_dispatch("ec_apply", path="host"):
-                # the numpy tier executes the IDENTICAL XOR schedule
-                # the device kernels run when the probe prefers one
-                # (ops/xor_schedule.py), so host-only rounds measure
-                # the same program shape; regionops stays the ground
-                # truth for everything else — byte-identical either
-                # way (corpus + fuzz pinned)
-                from ..ops.xor_schedule import host_matrix_apply
-                return host_matrix_apply(
-                    np.ascontiguousarray(chunks), matrix,
-                    matrix_static, self.w)
-        perf.inc("ec_device_calls")
-        perf.inc("ec_device_bytes", chunks.nbytes)
-        with perf.timed("ec_device_time"), \
-                record_dispatch("ec_apply", path="device"):
-            out = np.asarray(
-                apply_matrix_best(words, matrix_static, self.w)).view(np.uint8)
-        if verification_enabled():
-            ref = regionops.matrix_encode(words, matrix,
-                                          self.w).view(np.uint8)
-            if not np.array_equal(out, ref):
-                raise DeviceVerificationError(
-                    "device matrix path diverged from host ground truth "
-                    f"(w={self.w}, shape={chunks.shape})")
-        return out
+        return apply_matrix_batch(chunks, matrix, matrix_static, self.w,
+                                  self.min_xla_bytes)
 
     def encode_chunks_batch(self, data: np.ndarray) -> np.ndarray:
         return self._apply(data, self.matrix, self._matrix_static)

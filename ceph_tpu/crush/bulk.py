@@ -27,21 +27,21 @@ indep r-stride through uniform buckets is applied per descent level.
 Jewel tunables (choose_local_* == 0).  Equivalence is pinned by
 tests/test_crush_bulk.py over randomized maps, rules and reweights.
 
-int64: crush_ln is 16.48 fixed point, so this module enables
-jax_enable_x64 at import.  Import is deliberately lazy (nothing else in
-ceph_tpu pulls this module in).
+int64: crush_ln is 16.48 fixed point, so the map tables are built and
+every rung is traced and dispatched with 64-bit types on
+(``CompiledCrushMap`` and ``bulk_do_rule`` run under ``with_x64``).  The
+switch is thread-local and scoped to those calls: the rest of the
+process (the EC tiers, Pallas kernels) keeps JAX's 32-bit defaults.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
 import jax
-
-jax.config.update("jax_enable_x64", True)
-
 import jax.numpy as jnp
 
 from .hash import crush_hash32_2, crush_hash32_3, crush_hash32_4
@@ -86,8 +86,25 @@ LEAF_TRIES_CAP = 8
 # lanes per device dispatch (bulk_do_rule blocks larger sweeps)
 BULK_BLOCK = 1 << 18
 
+# residue lanes walked on the host mapper instead of compiling a
+# deeper rung, per backend: each rung is a new program.  The CPU
+# builds one in seconds; the TPU compiler takes minutes (a 32,768-PG
+# replicated sweep's rungs: 129-357 s AOT for a v5e) while the host
+# mapper takes ~9 ms a lane (~37 s for 4096).  A rung this process
+# has already compiled is always used.
+HOST_RESIDUE_LANES = {"cpu": 512, "tpu": 4096}
+
 # negln[u] = 2^48 - crush_ln(u): the straw2 numerator, one gather
 _NEGLN = (1 << 48) - np.asarray(crush_ln(np.arange(0x10000)))
+
+
+def with_x64(fn):
+    """Run ``fn`` with JAX's 64-bit types on, for this thread only."""
+    @functools.wraps(fn)
+    def run(*args, **kwargs):
+        with jax.enable_x64(True):
+            return fn(*args, **kwargs)
+    return run
 
 
 class CompiledCrushMap:
@@ -101,6 +118,7 @@ class CompiledCrushMap:
     matching bucket_straw2_choose's min(position, size-1) clamp).
     """
 
+    @with_x64
     def __init__(self, cmap: CrushMap,
                  choose_args: Optional[Dict[int, "ChooseArg"]] = None
                  ) -> None:
@@ -1241,6 +1259,7 @@ def auto_block(cmap, ruleno: int, result_max: int, tries: int) -> int:
                             budget // max(1, tries * width)))
 
 
+@with_x64
 def bulk_do_rule(cmap, ruleno: int, xs, result_max: int,
                  weight: Optional[Sequence[int]] = None,
                  bulk_tries: Optional[int] = None,
@@ -1364,6 +1383,8 @@ def bulk_do_rule(cmap, ruleno: int, xs, result_max: int,
             cnt[s:e] = np.asarray(c)[:e - s]
             need[s:e] = np.asarray(nm)[:e - s]
     redo = np.nonzero(need)[0]
+    host_limit = HOST_RESIDUE_LANES.get(jax.default_backend(),
+                                        HOST_RESIDUE_LANES["cpu"])
 
     # residue-adaptive rungs: each deeper budget re-dispatches ONLY the
     # lanes the previous rung flagged, so serial host work is bounded
@@ -1373,11 +1394,11 @@ def bulk_do_rule(cmap, ruleno: int, xs, result_max: int,
             break
         rung_key = (ruleno, result_max, tries, lcap, fix,
                     None if plane is None else (plane.mesh, plane.axis))
-        if redo.size < 512 and rung_key not in cm._jit_cache:
-            # compiling a deeper rung (~2 s) costs more than walking a
-            # few hundred lanes through the host mapper — small sweeps
-            # (tests, tools on toy maps) stop here; results are
-            # identical either way (the ladder invariant)
+        if redo.size <= host_limit and rung_key not in cm._jit_cache:
+            # compiling this rung costs more than the host mapper's
+            # walk of the residue (HOST_RESIDUE_LANES); a deeper rung
+            # already compiled still runs.  Results are identical
+            # either way (the ladder invariant)
             continue
         jf2 = _get_jitted(cm, ruleno, result_max, tries, lcap, fix,
                           plane=plane)

@@ -1,6 +1,6 @@
 """The supervised dispatch plane — ONE choke point between every
 host-side dispatch seam and the device backend, so a backend that
-fails AFTER warm-up (tunnel drop, device loss, HBM OOM, hang,
+fails AFTER warm-up (device loss, HBM OOM, hang,
 corrupted output buffer) is classified and survived instead of
 propagated.
 
@@ -88,7 +88,7 @@ from ..utils.locks import make_lock
 # XlaRuntimeError subclasses RuntimeError; PJRT surfaces gRPC-style
 # status names in the message)
 _OOM_MARKERS = ("resource_exhausted", "out of memory", "hbm oom")
-_LOSS_MARKERS = ("unavailable", "backend", "tunnel", "connection",
+_LOSS_MARKERS = ("unavailable", "backend", "connection",
                  "socket closed", "deadline_exceeded",
                  "failed_precondition")
 # a whole fault domain gone, not one chip: PJRT/coordination-service

@@ -66,9 +66,8 @@ def run_serving_scenario(spec, clock=None, executor: str = "device",
                          on_result: Optional[Callable] = None):
     """Drive ``spec``'s stream through queue → batcher → SLO ledger.
 
-    ``executor="device"`` additionally wires the persistent
-    compilation cache (utils/compile_cache.py, when the env knob is
-    set), installs the compile monitor, and reports
+    ``executor="device"`` additionally installs the compile monitor,
+    and reports
     ``stream_compiles`` — backend compiles AFTER warmup, the number
     the zero-warm-recompile acceptance gate pins at 0.
 
@@ -124,9 +123,7 @@ def run_serving_scenario(spec, clock=None, executor: str = "device",
     monitor = False
     if executor == "device":
         from ..telemetry import install_compile_monitor
-        from ..utils.compile_cache import maybe_initialize_compile_cache
 
-        maybe_initialize_compile_cache()
         monitor = install_compile_monitor()
     if warmup:
         batcher.warmup(reqs)

@@ -402,9 +402,8 @@ def run_serving_scenario(spec: TrafficSpec, clock=None,
     hooks, as here, the loop is byte-for-byte the standalone serving
     scenario this function has always run).
 
-    ``executor="device"`` additionally wires the persistent
-    compilation cache (utils/compile_cache.py, when the env knob is
-    set), installs the compile monitor, and reports
+    ``executor="device"`` additionally installs the compile monitor,
+    and reports
     ``stream_compiles`` — backend compiles AFTER warmup, the number
     the zero-warm-recompile acceptance gate pins at 0.
 

@@ -94,7 +94,7 @@ def _overlap(merged: Sequence[Tuple[int, int]], lo: int,
 def decompose(trace: dict, background: Sequence[Tuple[int, int]],
               retries: Sequence[Tuple[int, int]]) -> Optional[dict]:
     """Decompose one completed client trace (dict form) into the
-    segment taxonomy.  Returns None for incomplete traces (rejected
+    segment catalogue.  Returns None for incomplete traces (rejected
     at admission, or still in flight at export time).
 
     ``background``/``retries`` are pre-merged interval lists.  The

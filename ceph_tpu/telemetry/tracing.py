@@ -63,7 +63,7 @@ from ..utils.locks import make_lock
 
 TRACE_SCHEMA_VERSION = 1
 
-# the analyzer's segment taxonomy (docs/OBSERVABILITY.md has the
+# the analyzer's segment catalogue (docs/OBSERVABILITY.md has the
 # table); analyzer.decompose guarantees these sum exactly (integer
 # nanoseconds) to the trace's measured end-to-end latency
 SEGMENTS = ("queue_wait", "batch_wait", "arbiter_hold",

@@ -10,14 +10,14 @@
   erasure-code-profile store (`ceph osd erasure-code-profile`,
   OSDMonitor validation-by-instantiation).
 - ``log`` — dout-style per-subsystem leveled debug logging.
-- ``errors`` — the structured error taxonomy (TransientBackendError /
+- ``errors`` — the structured error catalogue (TransientBackendError /
   RetryExhausted / ScrubError / UnrecoverableError) shared by chaos/,
   scrub/, retry and the backend fallback policy (docs/ROBUSTNESS.md).
 - ``retry`` — bounded retry/backoff with an injectable clock (no real
   sleeps in tests).
-- ``compile_cache`` — the JAX persistent compilation cache behind the
-  ``CEPH_TPU_COMPILE_CACHE=<dir>`` env knob (cold-start compiles paid
-  once across processes; docs/SERVING.md).
+- ``compile_cache`` — the JAX persistent compilation cache at
+  ``JAX_COMPILATION_CACHE_DIR`` or ``<checkout>/.jax_cache`` (cold-start
+  compiles paid once across processes; docs/SERVING.md).
 """
 
 from .perf import PerfCounters, global_perf, profile_trace  # noqa: F401
@@ -46,6 +46,6 @@ from .retry import (  # noqa: F401
 from .compile_cache import (  # noqa: F401
     cache_entries,
     compile_cache_dir,
+    initialize_compile_cache,
     install_cache_monitor,
-    maybe_initialize_compile_cache,
 )

@@ -1,39 +1,38 @@
-"""JAX persistent compilation cache behind one env knob.
+"""JAX persistent compilation cache: one init function, one placement
+rule.
 
-``CEPH_TPU_COMPILE_CACHE=<dir>`` points every process at a shared
-on-disk compilation cache (SNIPPETS.md [2] —
-``jax.experimental.compilation_cache``): cold-start compiles are paid
-ONCE across processes, which is the other half of the serving
-cold-start story (the bucket-ladder warmup kills per-process warm
-recompiles; this kills the per-process cold trace cost for programs
-any previous process already built).
+:func:`initialize_compile_cache` is called by the entry points that
+run programs (``chip_smoke.py``, ``bench.py``, the ``ceph_tpu/bench``
+CLIs) and never on import.  The cache directory is:
 
-Wiring notes, pinned by tests/test_serve.py's two-process sentinel:
+- ``JAX_COMPILATION_CACHE_DIR`` when it is set — jax reads that
+  variable itself, so no other directory is set in code;
+- otherwise the fixed path ``<checkout>/.jax_cache/`` (git-ignored).
+  The path is part of every entry's key, so it is never built from a
+  temp name, a PID or the time: a directory that moves never hits.
+
+Wiring notes, re-checked on jax 0.9 (tests/test_serve.py's
+two-process test pins them):
 
 - The thresholds ``jax_persistent_cache_min_compile_time_secs`` and
   ``min_entry_size_bytes`` are zeroed: the default 1-second floor
-  would silently skip every small EC program and the knob would look
+  would silently skip every small EC program and the cache would look
   wired while caching nothing.
-- On this jax (0.4.37) a persistent-cache HIT still emits the
-  ``backend_compile`` duration event (the deserialization path runs
-  under the same span), so "second process compiled nothing" must be
-  asserted on the cache-miss counter, NOT the compile counter:
-  ``install_cache_monitor`` folds
-  ``/jax/compilation_cache/cache_hits|cache_misses`` into the
+- A persistent-cache HIT still emits jax's ``backend_compile``
+  duration event (checked on 0.9), so "second process compiled
+  nothing" is asserted on the cache-miss counter, not on the compile
+  count:
+  ``install_cache_monitor`` folds jax's
+  ``/jax/compilation_cache/cache_hits|cache_misses`` events into the
   telemetry registry as ``jax_persistent_cache_hits`` /
   ``jax_persistent_cache_misses`` — a warm replay is
   ``misses == 0 and hits > 0``.
-- Initialization is lazy and idempotent; without the env knob (or
-  without jax) everything here is a no-op returning None/False, so
-  the default test environment never writes outside its sandbox.
 """
 
 from __future__ import annotations
 
 import os
-from typing import Optional
 
-from .log import dout
 from .locks import make_lock
 
 # NOTE: telemetry is imported lazily inside the functions below — the
@@ -41,83 +40,48 @@ from .locks import make_lock
 # so a module-scope import here would close an import cycle
 # (telemetry.* → utils → compile_cache → telemetry).
 
-ENV_KNOB = "CEPH_TPU_COMPILE_CACHE"
+ENV_VAR = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 _lock = make_lock("utils.compile_cache._lock")
-_initialized_dir: Optional[str] = None
 _monitor_installed = False
 
 
-def compile_cache_dir() -> Optional[str]:
-    """The configured cache directory (env knob), or None."""
-    return os.environ.get(ENV_KNOB) or None
+def compile_cache_dir() -> str:
+    """Where the cache lives: ``JAX_COMPILATION_CACHE_DIR`` when set,
+    else the fixed ``<checkout>/.jax_cache``."""
+    return os.environ.get(ENV_VAR) or DEFAULT_DIR
 
 
-def maybe_initialize_compile_cache(
-        cache_dir: Optional[str] = None) -> Optional[str]:
-    """Point jax's persistent compilation cache at ``cache_dir`` (or
-    the env knob).  Returns the active cache dir, or None when no dir
-    is configured / jax is unavailable.  Idempotent; re-pointing at a
-    DIFFERENT directory in one process raises (the cache dir is a
-    process-wide jax config)."""
-    global _initialized_dir
-    d = cache_dir or compile_cache_dir()
-    if not d:
-        return None
+def initialize_compile_cache() -> str:
+    """Turn on jax's persistent compilation cache at
+    :func:`compile_cache_dir` with zeroed write thresholds, and fold
+    its hit/miss events into telemetry.  Idempotent; returns the
+    directory."""
+    import jax
 
-    def _check_same(existing: str) -> str:
-        if os.path.abspath(existing) != os.path.abspath(d):
-            raise ValueError(
-                f"compilation cache already initialized at "
-                f"{existing!r}; cannot re-point at {d!r}")
-        return existing
-
-    with _lock:
-        if _initialized_dir is not None:
-            return _check_same(_initialized_dir)
-    try:
-        import jax
-    except ImportError:
-        return None
-    # the mkdir + jax config writes run OUTSIDE the memo lock (conc
-    # tier: no file I/O / device-config work under a lock).  Two
-    # first-callers racing on the SAME dir repeat idempotent work;
-    # racing on different dirs still raises below — one claims the
-    # memo, the other fails the _check_same, exactly as before.
-    os.makedirs(d, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", d)
+    d = compile_cache_dir()
+    if d == DEFAULT_DIR:
+        jax.config.update("jax_compilation_cache_dir", d)
     # zero the write thresholds: EC programs compile in well under
     # the default 1 s floor and would never be cached
-    jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                      0.0)
-    jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                      -1)
-    with _lock:
-        if _initialized_dir is None:
-            _initialized_dir = d
-        else:
-            return _check_same(_initialized_dir)
-    # telemetry after release: emitting takes the registry/recorder
-    # locks, which rank ABOVE this one in analysis/lockmodel.py
-    from ..telemetry import metrics as tel
-    tel.event("compile_cache_initialized", dir=d)
-    dout("serve", 5, f"persistent compilation cache at {d}")
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    install_cache_monitor()
     return d
 
 
 def install_cache_monitor() -> bool:
     """Fold jax's persistent-cache hit/miss monitoring events into the
     telemetry registry (``jax_persistent_cache_hits`` /
-    ``jax_persistent_cache_misses``).  Idempotent; False when jax is
-    unavailable."""
+    ``jax_persistent_cache_misses``).  Idempotent."""
     global _monitor_installed
     with _lock:
         if _monitor_installed:
             return True
-        try:
-            import jax.monitoring
-        except ImportError:
-            return False
+        import jax.monitoring
 
         def _listener(name: str, **kw) -> None:
             from ..telemetry import metrics as tel
@@ -131,14 +95,13 @@ def install_cache_monitor() -> bool:
         return True
 
 
-def cache_entries(cache_dir: Optional[str] = None) -> int:
-    """Number of cached executables on disk (``*-cache`` files) —
-    provenance for demo/bench lines, 0 when unconfigured."""
+def cache_entries(cache_dir: str | None = None) -> int:
+    """Number of cached executables on disk (``*-cache`` files)."""
     d = cache_dir or compile_cache_dir()
-    if not d or not os.path.isdir(d):
+    if not os.path.isdir(d):
         return 0
     return sum(1 for f in os.listdir(d) if f.endswith("-cache"))
 
 
-__all__ = ["ENV_KNOB", "cache_entries", "compile_cache_dir",
-           "install_cache_monitor", "maybe_initialize_compile_cache"]
+__all__ = ["DEFAULT_DIR", "ENV_VAR", "cache_entries", "compile_cache_dir",
+           "initialize_compile_cache", "install_cache_monitor"]

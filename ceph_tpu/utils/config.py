@@ -62,9 +62,6 @@ OPTIONS: List[Option] = [
            desc="re-verify device results against host ground truth"),
     Option("log_level", int, 1, minimum=0, maximum=20,
            desc="default dout level (per-subsystem via CEPH_TPU_DEBUG)"),
-    Option("compile_cache", str, "",
-           desc="directory for the JAX persistent compilation cache "
-                "(utils/compile_cache.py; empty = disabled)"),
 ]
 
 

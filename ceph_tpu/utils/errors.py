@@ -1,4 +1,4 @@
-"""Structured error taxonomy for the robustness surfaces.
+"""Structured error catalogue for the robustness surfaces.
 
 The reference signals failure through int error codes threaded from
 the ObjectStore up through ECBackend (-EIO for a failed crc gate,
@@ -10,7 +10,7 @@ every deliberate failure path in chaos/, scrub/, utils/retry.py and
 ops/fallback.py raises one of them, so consumers can distinguish
 "retry this" (TransientBackendError) from "this read set cannot be
 decoded, here is exactly what is lost" (UnrecoverableError) without
-string matching.  docs/ROBUSTNESS.md has the full taxonomy table.
+string matching.  docs/ROBUSTNESS.md has the full catalogue table.
 """
 
 from __future__ import annotations

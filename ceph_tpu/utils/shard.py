@@ -1,15 +1,6 @@
-"""Version-portable shard_map — THE one import shim.
-
-jax.shard_map landed in 0.6 with ``check_vma``; on earlier releases it
-lives in jax.experimental.shard_map with the same knob named
-``check_rep`` (skip the output-replication static analysis — renamed
-upstream, semantics unchanged).  tpu-lint's PR-1 sweep found the 0.6+
-spelling hard-imported in parallel/sharded_codes.py (4 seed test
-failures on the pinned jax); the version gate that fixed it then grew
-copies as the mesh tier spread.  This module is the single place that
-knows about the rename — everything that shards (parallel/, the
-engine-selection mesh tier in ops/pallas_gf.py, codes/engine.py's
-sharded program variants) calls :func:`shard_map_compat`.
+"""shard_map with the repo's defaults — the one place every sharded
+caller builds its program (parallel/, the engine-selection mesh tier
+in ops/pallas_gf.py, codes/engine.py's sharded program variants).
 
 jax is imported lazily so the AST analysis tier keeps working in
 jax-free environments.
@@ -19,19 +10,15 @@ from __future__ import annotations
 
 
 def shard_map_compat(fn, mesh, in_specs, out_specs, check: bool = False):
-    """``shard_map(fn, mesh, in_specs, out_specs)`` on any supported
-    jax, with the replication check off by default (the GF programs
+    """``jax.shard_map(fn, mesh, in_specs, out_specs)`` with the
+    varying-manual-axes check off by default (the GF programs
     XOR-reduce across shards in ways the static analysis cannot see
     through; every sharded caller here pins byte-identity in tests
     instead)."""
     import jax
 
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check)
-    from jax.experimental.shard_map import shard_map
-    return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                     out_specs=out_specs, check_rep=check)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)
 
 
 def batch_spec(axis: str, rank: int):

@@ -766,3 +766,58 @@ def test_bulk_choose_args_with_reweights_and_leaf_tries(seed):
         w[int(i)] = int(rng.integers(0, 0x10001))
     rm = int(rng.integers(2, 6))
     pin(b, 0, rm, N=300, weight=w, choose_args=args)
+
+
+def _ladder_keys(cm):
+    return sorted(k[2:5] for k in cm._jit_cache)
+
+
+def test_residue_ladder_decides_by_lane_count(monkeypatch):
+    """The residue after the first rung walks the host mapper when it
+    holds at most HOST_RESIDUE_LANES[backend] lanes, unless a deeper
+    rung is already compiled (then that rung runs); a larger residue
+    compiles the next rung.  The choice reads no clock, and the
+    results are identical every way (the ladder invariant)."""
+    import jax
+    backend = jax.default_backend()
+    assert bulk.HOST_RESIDUE_LANES == {"cpu": 512, "tpu": 4096}
+    monkeypatch.setitem(bulk.HOST_RESIDUE_LANES, backend, 4096)
+    b, root = build(4, 2)          # 3 replicas over 4 hosts: collisions
+    b.add_rule(0, STEPS["chooseleaf_firstn"](root))
+    xs = np.arange(3000)
+    ref = [crush_do_rule(b.map, 0, int(x), 3) for x in xs]
+    ref = np.array([r + [CRUSH_ITEM_NONE] * (3 - len(r)) for r in ref])
+    rungs = bulk.auto_ladder(b.map, 0, 3, bulk.auto_tries(b.map, 0, 3))
+
+    cm = bulk.CompiledCrushMap(b.map)
+    out, _, host = bulk.bulk_do_rule(cm, 0, xs, 3, return_stats=True)
+    assert 512 < host <= 4096
+    assert _ladder_keys(cm) == [rungs[0]]          # no deeper compile
+    np.testing.assert_array_equal(out, ref)
+
+    cm = bulk.CompiledCrushMap(b.map)
+    bulk._get_jitted(cm, 0, 3, *rungs[2])          # compiled earlier
+    out, _, host_warm = bulk.bulk_do_rule(cm, 0, xs, 3, return_stats=True)
+    assert host_warm < host
+    assert rungs[1] not in _ladder_keys(cm)        # skipped, not built
+    np.testing.assert_array_equal(out, ref)
+
+    monkeypatch.setitem(bulk.HOST_RESIDUE_LANES, backend, 512)
+    cm = bulk.CompiledCrushMap(b.map)
+    out, _, host_deep = bulk.bulk_do_rule(cm, 0, xs, 3, return_stats=True)
+    assert host_deep < host and rungs[1] in _ladder_keys(cm)
+    np.testing.assert_array_equal(out, ref)
+
+
+def test_bulk_rule_leaves_32_bit_defaults():
+    """crush_ln's 16.48 fixed point runs with 64-bit types on, scoped
+    to the bulk calls: the process keeps JAX's 32-bit defaults."""
+    import jax
+    import jax.numpy as jnp
+    b, root = build(3, 2)
+    b.add_rule(0, STEPS["chooseleaf_firstn"](root))
+    cm = bulk.CompiledCrushMap(b.map)
+    assert cm.negln.dtype == jnp.int64
+    bulk.bulk_do_rule(cm, 0, np.arange(8), 3)
+    assert not jax.config.jax_enable_x64
+    assert jnp.arange(3).dtype == jnp.int32

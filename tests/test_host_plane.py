@@ -536,13 +536,13 @@ def test_bench_diff_flags_host_chaos_regression(tmp_path, capsys):
     cur = {"metric": "m", "value": 100.0, "git_sha": "bbb",
            "timestamp": "2026-02-01T00:00:00+00:00",
            "host_chaos_rows": {"rs": {"gbps": 0.4}}}
-    (tmp_path / "BENCH_LAST_GOOD.json").write_text(json.dumps(cur))
+    (tmp_path / "BENCH_CURRENT.json").write_text(json.dumps(cur))
     rc = bd.main(["--repo", str(tmp_path), "--json"])
     assert rc == 4
     report = json.loads(capsys.readouterr().out)
     assert report["regressions"] == ["host_chaos:rs"]
     cur["host_chaos_rows"]["rs"]["gbps"] = 0.8
-    (tmp_path / "BENCH_LAST_GOOD.json").write_text(json.dumps(cur))
+    (tmp_path / "BENCH_CURRENT.json").write_text(json.dumps(cur))
     assert bd.main(["--repo", str(tmp_path)]) == 0
 
 

@@ -50,7 +50,7 @@ from ceph_tpu.analysis.jaxpr_audit import (  # noqa: E402
 )
 
 BASE_ALLOW = frozenset({
-    "pjit", "convert_element_type", "add", "xor", "and", "mul",
+    "jit", "convert_element_type", "add", "xor", "and", "mul",
     "reshape", "broadcast_in_dim", "slice", "concatenate", "squeeze",
     "shift_left", "shift_right_logical", "bitcast_convert_type",
 })

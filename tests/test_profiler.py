@@ -76,7 +76,7 @@ class _Tick:
 def test_attribution_math_is_exact():
     prof = ProgramProfiler(clock=FakeClock())
     key = ("t", "p")
-    prof.capture(key, name="t.p", platform="tpu",
+    prof.capture(key, name="t.p", device_kind="TPU v5 lite",
                  cost={"flops": 1000.0, "bytes accessed": 8190.0},
                  arg_bytes=4095, plugin="x", kind="encode",
                  engine="device", devices=1)
@@ -88,7 +88,7 @@ def test_attribution_math_is_exact():
     assert row["p50_ms"] == pytest.approx(1.0, rel=0.02)
     p50_s = row["p50_ms"] / 1e3
     # achieved = arg_bytes/p50; hbm = bytes/p50; util = 100*hbm/peak;
-    # model_bound = peak * arg_bytes / bytes  (peak: tpu = 819 GB/s)
+    # model_bound = peak * arg_bytes / bytes  (peak: v5e = 819 GB/s)
     assert row["achieved_gbps"] == pytest.approx(
         4095 / p50_s / 1e9, rel=1e-6)
     assert row["hbm_gbps"] == pytest.approx(
@@ -151,15 +151,27 @@ def test_capture_failure_never_raises():
     assert row["error"] and row["flops"] is None
 
 
-def test_analytic_model_and_peak_resolution(monkeypatch):
+def test_analytic_model_and_peak_resolution():
     cost = analytic_matrix_cost(4, 3, 8, 1024)
     assert cost["flops"] == 2.0 * 4 * 3 * 8 * 1024
     assert cost["bytes accessed"] == 4 * 11 * 1024
-    assert resolve_peak_gbps("tpu") == 819.0
-    assert resolve_peak_gbps("gpu") is None
-    assert resolve_peak_gbps(None) is None
-    monkeypatch.setenv("CEPH_TPU_HBM_PEAK_GBPS", "1600")
-    assert resolve_peak_gbps("tpu") == 1600.0
+    # peaks are keyed by jax device_kind; a platform name, the CPU or
+    # an unknown chip has no roofline ("not measured"), never a default
+    assert resolve_peak_gbps("TPU v5 lite") == 819.0
+    for kind in ("tpu", "cpu", "TPU v4", None):
+        assert resolve_peak_gbps(kind) is None
+
+
+def test_unknown_device_kind_rows_carry_no_utilization():
+    prof = ProgramProfiler(clock=FakeClock())
+    prof.capture(("c",), name="c", device_kind="cpu",
+                 cost={"flops": 1.0, "bytes accessed": 2.0}, arg_bytes=1)
+    prof.observe(("c",), 0.001)
+    (row,) = prof.attribution_rows()
+    assert row["device_kind"] == "cpu"
+    assert row["achieved_gbps"] is not None
+    assert row["utilization_pct"] is None
+    assert row["model_bound_gbps"] is None
 
 
 def test_top_programs_orders_by_total_seconds():
@@ -233,7 +245,9 @@ def test_profile_entrypoints_subset_rows_complete():
         assert row["bytes_accessed"] > 0
         assert row["calls"] == 2 and row["p50_ms"] > 0
         assert row["achieved_gbps"] > 0
-        assert row["utilization_pct"] is not None
+        # a CPU run has no roofline: utilization is "not measured"
+        assert row["device_kind"] == "cpu"
+        assert row["utilization_pct"] is None
 
 
 def test_audit_entries_registered_and_compile_free():
@@ -413,7 +427,7 @@ def test_unified_dump_optional_sections_validate():
     state = _fresh_flight_world(clk)
     try:
         prof = telemetry.global_profiler()
-        prof.capture(("p",), name="p", platform="cpu",
+        prof.capture(("p",), name="p", device_kind="TPU v5 lite",
                      cost={"flops": 1.0, "bytes accessed": 2.0},
                      arg_bytes=1)
         UnrecoverableError("x", shards=[1])
@@ -505,7 +519,7 @@ def _write_trajectory(tmp_path, prior_value=100.0, current_value=100.0,
     cur = {"metric": "m", "value": current_value, "unit": "GB/s",
            "git_sha": "bbb", "timestamp": "2026-02-01T00:00:00+00:00"}
     cur.update(current_rows or {})
-    (tmp_path / "BENCH_LAST_GOOD.json").write_text(json.dumps(cur))
+    (tmp_path / "BENCH_CURRENT.json").write_text(json.dumps(cur))
 
 
 def test_bench_diff_flags_20pct_headline_regression(tmp_path, capsys):
@@ -557,25 +571,26 @@ def test_bench_diff_missing_row_is_a_regression(tmp_path):
     assert bd.main(["--repo", str(tmp_path)]) == 4
 
 
-def test_bench_diff_error_line_uses_last_good(tmp_path):
-    """A tunnel-down candidate is judged by its embedded last_good
-    record — an outage is not a throughput regression."""
+def test_bench_diff_candidate_without_value_is_not_judged(tmp_path,
+                                                         capsys):
+    """A candidate with no device value (a failed run) is not a
+    throughput regression: nothing is judged, rc 0."""
     bd = _load_bench_diff()
     _write_trajectory(tmp_path, prior_value=100.0,
                       current_value=101.0)
     cand = tmp_path / "candidate.json"
-    cand.write_text(json.dumps(
-        {"metric": "m", "value": None, "error": "tunnel down",
-         "last_good": {"metric": "m", "value": 99.0,
-                       "git_sha": "ccc",
-                       "timestamp": "2026-03-01T00:00:00+00:00"}}))
+    cand.write_text(json.dumps({"metric": "m", "value": None}))
     assert bd.main(["--repo", str(tmp_path),
                     "--candidate", str(cand)]) == 0
+    assert "nothing to diff" in capsys.readouterr().err
 
 
-def test_bench_diff_real_trajectory_rc0():
-    """The repo's own checked-in trajectory must be clean — this IS
-    the test_full.sh gate, asserted in tier-1 too."""
+def test_bench_diff_empty_trajectory_is_no_baseline(tmp_path, capsys):
+    """No BENCH_r*.json at all (the repo's own state: the old chip
+    records are gone) means no baseline, not a failure."""
     bd = _load_bench_diff()
-    assert (REPO_ROOT / "BENCH_LAST_GOOD.json").exists()
+    (tmp_path / "BENCH_CURRENT.json").write_text(json.dumps(
+        {"metric": "m", "value": 50.0}))
+    assert bd.main(["--repo", str(tmp_path)]) == 0
+    assert "no baseline" in capsys.readouterr().err
     assert bd.main(["--repo", str(REPO_ROOT)]) == 0

@@ -378,7 +378,7 @@ def test_repair_scenario_deterministic_and_counters_correct():
         rep1.pattern_batches
     assert tel["repair_pattern_batches"] == rep1.pattern_batches
     assert tel["scrub_deep_scrub_seconds"]["count"] == 5
-    # span taxonomy: repair root with scrub/plan/dispatch/verify/
+    # span catalogue: repair root with scrub/plan/dispatch/verify/
     # write_back children
     tree = json.loads(spans1)
     (root,) = tree["spans"]
@@ -473,7 +473,7 @@ def test_recovery_scenario_counters_match_report():
     churn_keys = [k for k in tel
                   if k.startswith("chaos_injections{kind=churn_")]
     assert churn_keys and sum(tel[k] for k in churn_keys) >= 1
-    # span taxonomy: recovery.run root, journal_replay + plan +
+    # span catalogue: recovery.run root, journal_replay + plan +
     # round(decode → nested repair, writeback)
     roots = [s["name"] for s in spans["spans"]]
     assert "recovery.run" in roots
@@ -605,12 +605,12 @@ def test_prometheus_help_and_type_lines():
 def test_prometheus_label_value_escaping():
     reg = MetricsRegistry(clock=FakeClock())
     reg.counter("fallback_tier_transitions",
-                error='RuntimeError: "tunnel\\wedged"\nretrying')
+                error='RuntimeError: "link\\down"\nretrying')
     text = reg.to_prometheus()
     (sample,) = [l for l in text.splitlines()
                  if not l.startswith("#")]
     # escaped per the exposition format: \\ then \" then \n
-    assert ('error="RuntimeError: \\"tunnel\\\\wedged\\"\\nretrying"'
+    assert ('error="RuntimeError: \\"link\\\\down\\"\\nretrying"'
             in sample)
     assert "\n" not in sample                      # one physical line
 

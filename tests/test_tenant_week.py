@@ -381,7 +381,7 @@ def test_bench_diff_flags_tenant_isolation_regression(tmp_path,
            "timestamp": "2026-02-01T00:00:00+00:00",
            "tenant_week_rows": {"tenant_week_isolation": {
                "victim_gbps_under_slo": 0.4}}}
-    (tmp_path / "BENCH_LAST_GOOD.json").write_text(json.dumps(cur))
+    (tmp_path / "BENCH_CURRENT.json").write_text(json.dumps(cur))
     rc = bd.main(["--repo", str(tmp_path), "--json"])
     assert rc == 4
     report = json.loads(capsys.readouterr().out)
@@ -389,7 +389,7 @@ def test_bench_diff_flags_tenant_isolation_regression(tmp_path,
         "tenant_isolation:tenant_week_isolation"]
     cur["tenant_week_rows"]["tenant_week_isolation"][
         "victim_gbps_under_slo"] = 0.9
-    (tmp_path / "BENCH_LAST_GOOD.json").write_text(json.dumps(cur))
+    (tmp_path / "BENCH_CURRENT.json").write_text(json.dumps(cur))
     assert bd.main(["--repo", str(tmp_path)]) == 0
 
 

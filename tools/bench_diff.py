@@ -1,16 +1,15 @@
 #!/usr/bin/env python3
 """bench_diff — the perf-regression sentinel over the BENCH_* trajectory.
 
-Five BENCH_r*.json rounds sit in the repo with no automated regression
-detection: the bench trajectory was write-only (ISSUE 10).  This tool
-makes it a gate:
+A bench line is judged against the best prior of every row it
+carries (ISSUE 10):
 
 1. **Parse the trajectory** — every ``BENCH_r*.json`` driver record
-   (``{n, cmd, rc, tail, parsed}``) plus ``BENCH_LAST_GOOD.json``,
-   across every metric_version (v1 bare-float rows through v8
-   ``{gbps, lat_*}`` dicts; error lines contribute their embedded
-   ``last_good`` record, deduped by (git_sha, timestamp), so a
-   tunnel-down round never reads as a 100% regression).
+   (``{n, cmd, rc, tail, parsed}``, or a raw bench line) across every
+   metric_version (v1 bare-float rows through v3+ ``{gbps, lat_*}``
+   dicts), deduped by (git_sha, timestamp, value).  Lines without a
+   device value are skipped; an empty trajectory means "no baseline"
+   and judges nothing.
 2. **Normalize** to named higher-is-better series: ``headline`` (the
    carry-chain encode GB/s), ``decode:<row>``,
    ``composite_decode:<row>`` (the shec/clay decode rows — the gap
@@ -30,20 +29,19 @@ makes it a gate:
    near-zero slack).
    Other ratios/latency rows are deliberately excluded — one
    sentinel, one direction per category.
-3. **Diff with per-row noise floors** — the CURRENT record (BENCH_
-   LAST_GOOD.json, or ``--candidate <file>`` for a fresh bench line)
+3. **Diff with per-row noise floors** — the CURRENT record
+   (``BENCH_CURRENT.json``, or ``--candidate <file>``: a fresh bench
+   line)
    regresses a row when it falls below the best prior value by more
    than the row's noise floor.  Floors are per-category: device-chained
    rows are stable (15–20%), host/scheduler-timed rows are noisy
    (40–50%) — see FLOORS; override any category with
    ``--floor cat=frac``.
 4. **Fail loudly** — rc 4 with one REGRESSION line per failing row;
-   rc 0 when clean (including the "single sample, nothing to diff yet"
-   case, reported as such).  tools/test_full.sh runs this against the
-   checked-in trajectory, so a perf PR (the shec/clay XOR kernels are
-   next) cannot merge a silent throughput cliff.
+   rc 0 when clean, and when there is no baseline or no current
+   measurement (reported as such).
 
-Exit codes: 0 clean · 2 usage · 3 no usable trajectory · 4 regression.
+Exit codes: 0 clean or nothing to judge · 2 usage · 4 regression.
 """
 
 from __future__ import annotations
@@ -223,8 +221,7 @@ def _record_id(rec: dict) -> Tuple:
 
 def load_trajectory(repo: str) -> List[Tuple[str, dict]]:
     """(label, record) for every usable measurement in the BENCH_r*
-    trajectory, oldest first, deduped: a direct good round's parsed
-    line, or the last_good record an error line carries."""
+    trajectory, oldest first, deduped."""
     out: List[Tuple[str, dict]] = []
     seen: set = set()
 
@@ -251,7 +248,6 @@ def load_trajectory(repo: str) -> List[Tuple[str, dict]]:
         if not isinstance(parsed, dict):
             continue
         _add(base, parsed)
-        _add(f"{base}:last_good", parsed.get("last_good"))
     return out
 
 
@@ -259,20 +255,13 @@ def load_current(repo: str, candidate: Optional[str]
                  ) -> Tuple[str, Optional[dict]]:
     if candidate:
         with open(candidate, encoding="utf-8") as f:
-            rec = json.load(f)
-        if rec.get("value") is None and isinstance(
-                rec.get("last_good"), dict):
-            # an error-line candidate is judged by its embedded
-            # last-good device measurement, same as the trajectory
-            return (f"{os.path.basename(candidate)}:last_good",
-                    rec["last_good"])
-        return os.path.basename(candidate), rec
-    path = os.path.join(repo, "BENCH_LAST_GOOD.json")
+            return os.path.basename(candidate), json.load(f)
+    path = os.path.join(repo, "BENCH_CURRENT.json")
     try:
         with open(path, encoding="utf-8") as f:
-            return "BENCH_LAST_GOOD.json", json.load(f)
+            return "BENCH_CURRENT.json", json.load(f)
     except (OSError, ValueError):
-        return "BENCH_LAST_GOOD.json", None
+        return "BENCH_CURRENT.json", None
 
 
 def diff(trajectory: List[Tuple[str, dict]], current_label: str,
@@ -342,10 +331,10 @@ def main(argv=None) -> int:
         description=__doc__.splitlines()[0])
     ap.add_argument("--repo", default=REPO,
                     help="directory holding BENCH_r*.json + "
-                         "BENCH_LAST_GOOD.json")
+                         "BENCH_CURRENT.json")
     ap.add_argument("--candidate", default=None, metavar="FILE",
                     help="judge this bench JSON line instead of "
-                         "BENCH_LAST_GOOD.json (a fresh run's output)")
+                         "BENCH_CURRENT.json (a fresh run's output)")
     ap.add_argument("--floor", action="append", default=[],
                     metavar="CAT=FRAC",
                     help="override a category noise floor, e.g. "
@@ -366,15 +355,13 @@ def main(argv=None) -> int:
     trajectory = load_trajectory(args.repo)
     label, current = load_current(args.repo, args.candidate)
     if current is None or current.get("value") is None:
-        # no current device measurement at all: nothing to judge — an
-        # outage is the error line's job to report, not a regression
         print("bench_diff: no current device measurement "
               f"({label}); nothing to diff", file=sys.stderr)
-        return 0 if trajectory else 3
+        return 0
     if not trajectory:
-        print("bench_diff: no BENCH_r*.json trajectory found",
-              file=sys.stderr)
-        return 3
+        print("bench_diff: no baseline (empty BENCH_r*.json "
+              "trajectory); nothing to diff", file=sys.stderr)
+        return 0
 
     report = diff(trajectory, label, current, floors)
     if args.json_out:

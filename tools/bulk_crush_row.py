@@ -2,7 +2,7 @@
 """BASELINE.md row 5: 1M-PG bulk CRUSH sweep on the live device.
 
 Prints one JSON line; invoked by tools/bench_rows.sh (which records it
-in BENCH_ROWS_LAST_GOOD.jsonl with provenance).  --ec sweeps the
+in chiprun_out/bench_rows.jsonl with provenance).  --ec sweeps the
 canonical mon-generated erasure rule (SET steps + chooseleaf indep 0,
 6-wide) instead of the replicated firstn rule.
 """
