@@ -44,6 +44,8 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..telemetry import metrics as tel
+from ..telemetry.spans import span
 from .hash import crush_hash32_2, crush_hash32_3, crush_hash32_4
 from .ln import crush_ln
 from .mapper import crush_do_rule
@@ -1286,6 +1288,13 @@ def bulk_do_rule(cmap, ruleno: int, xs, result_max: int,
 
     Returns (results (N, result_max) int32 with CRUSH_ITEM_NONE holes,
     counts (N,)); with return_stats also the host-fallback lane count.
+
+    Spans (self time into a histogram each): ``crush.rung`` {rung}
+    per rung that dispatches, from the H2D of ``xs`` to the fetched
+    results (``crush_rung_seconds``; lanes re-dispatched at rungs >= 1
+    count ``crush_redo_lanes``), and ``crush.host_residue`` around the
+    serial host walk (``crush_host_residue_seconds``; its lanes count
+    ``crush_host_residue_lanes``).
     """
     from ..parallel.plane import resolve_plane
     plane = resolve_plane(mesh)
@@ -1320,7 +1329,6 @@ def bulk_do_rule(cmap, ruleno: int, xs, result_max: int,
                               rungs[0][0])) or 1
     if nd > 1:
         block = -(-block // nd) * nd  # shard_map-divisible blocks
-        from ..telemetry import metrics as tel
         tel.counter("engine_mesh_dispatches", tier="crush-bulk",
                     devices=str(nd))
     jf = _get_jitted(cm, ruleno, result_max, *rungs[0], plane=plane)
@@ -1352,36 +1360,39 @@ def bulk_do_rule(cmap, ruleno: int, xs, result_max: int,
     # owns compilation), every block dispatch lands in the program's
     # latency histogram.  Keyed like the jit cache, plus the map size
     # so a 10k-OSD sweep and a toy map don't share a row.
-    from ..telemetry import metrics as _tel
     from ..telemetry.profiler import global_profiler
     prof = global_profiler()
     prof_key = ("crush.bulk_rule", ruleno, result_max, rungs[0],
                 block, nd, len(wv))
-    captured = not _tel.enabled()
-    for s in range(0, n, block):
-        e = min(s + block, n)
-        xs_b = xs[s:e]
-        if e - s < block:
-            xs_b = np.concatenate([xs_b, xs_b[:1].repeat(block - (e - s))])
-        xs_d = jnp.asarray(xs_b)
-        if not captured:
-            captured = True
-            prof.capture(prof_key, jf, (xs_d, wv),
-                         name="crush.bulk_rule", plugin="crush",
-                         kind="bulk-rule", batch=block,
-                         pattern=f"rule{ruleno}x{result_max}",
-                         engine="mesh" if nd > 1 else "device",
-                         devices=nd)
-        with prof.timed(prof_key, eager=_tel.enabled()):
-            # verifiable=False: the device block legitimately differs
-            # from the reference twin (need-host flags feed the
-            # residue ladder), so CRC self-verify does not apply here
-            o, c, nm = global_supervisor().dispatch(
-                "crush.bulk_rule", _dev_block, (xs_b,),
-                host_fn=_host_block, verifiable=False)
-            out[s:e] = np.asarray(o)[:e - s]
-            cnt[s:e] = np.asarray(c)[:e - s]
-            need[s:e] = np.asarray(nm)[:e - s]
+    captured = not tel.enabled()
+    with span("crush.rung", metric="crush_rung_seconds", rung=0) as sp:
+        sp.attrs["lanes"] = n
+        for s in range(0, n, block):
+            e = min(s + block, n)
+            xs_b = xs[s:e]
+            if e - s < block:
+                xs_b = np.concatenate([xs_b,
+                                       xs_b[:1].repeat(block - (e - s))])
+            xs_d = jnp.asarray(xs_b)
+            if not captured:
+                captured = True
+                prof.capture(prof_key, jf, (xs_d, wv),
+                             name="crush.bulk_rule", plugin="crush",
+                             kind="bulk-rule", batch=block,
+                             pattern=f"rule{ruleno}x{result_max}",
+                             engine="mesh" if nd > 1 else "device",
+                             devices=nd)
+            with prof.timed(prof_key, eager=tel.enabled()):
+                # verifiable=False: the device block legitimately
+                # differs from the reference twin (need-host flags feed
+                # the residue ladder), so CRC self-verify does not
+                # apply here
+                o, c, nm = global_supervisor().dispatch(
+                    "crush.bulk_rule", _dev_block, (xs_b,),
+                    host_fn=_host_block, verifiable=False)
+                out[s:e] = np.asarray(o)[:e - s]
+                cnt[s:e] = np.asarray(c)[:e - s]
+                need[s:e] = np.asarray(nm)[:e - s]
     redo = np.nonzero(need)[0]
     host_limit = HOST_RESIDUE_LANES.get(jax.default_backend(),
                                         HOST_RESIDUE_LANES["cpu"])
@@ -1389,7 +1400,7 @@ def bulk_do_rule(cmap, ruleno: int, xs, result_max: int,
     # residue-adaptive rungs: each deeper budget re-dispatches ONLY the
     # lanes the previous rung flagged, so serial host work is bounded
     # by the residue of the deepest rung (VERDICT r04 Next#4)
-    for tries, lcap, fix in rungs[1:]:
+    for rung, (tries, lcap, fix) in enumerate(rungs[1:], 1):
         if not redo.size:
             break
         rung_key = (ruleno, result_max, tries, lcap, fix,
@@ -1406,32 +1417,41 @@ def bulk_do_rule(cmap, ruleno: int, xs, result_max: int,
                                        tries)) or 1
         if nd > 1:
             rblock = -(-rblock // nd) * nd
+        tel.counter("crush_redo_lanes", int(redo.size))
         host_lanes = []
-        for s in range(0, len(redo), rblock):
-            idx = redo[s:s + rblock]
-            m = len(idx)
-            # pad to the next power of two so redo batches reuse a
-            # bounded set of compiled shapes
-            padm = 1 << max(10, (m - 1).bit_length())
-            padm = min(padm, rblock)
-            if nd > 1:
-                padm = min(-(-padm // nd) * nd, rblock)
-            xs_r = xs[idx]
-            if padm > m:
-                xs_r = np.concatenate([xs_r, xs_r[:1].repeat(padm - m)])
-            o, c, nh = jf2(jnp.asarray(xs_r), wv)
-            out[idx] = np.asarray(o)[:m]
-            cnt[idx] = np.asarray(c)[:m]
-            host_lanes.append(idx[np.asarray(nh)[:m]])
+        with span("crush.rung", metric="crush_rung_seconds",
+                  rung=rung) as sp:
+            sp.attrs["lanes"] = int(redo.size)
+            for s in range(0, len(redo), rblock):
+                idx = redo[s:s + rblock]
+                m = len(idx)
+                # pad to the next power of two so redo batches reuse a
+                # bounded set of compiled shapes
+                padm = 1 << max(10, (m - 1).bit_length())
+                padm = min(padm, rblock)
+                if nd > 1:
+                    padm = min(-(-padm // nd) * nd, rblock)
+                xs_r = xs[idx]
+                if padm > m:
+                    xs_r = np.concatenate([xs_r,
+                                           xs_r[:1].repeat(padm - m)])
+                o, c, nh = jf2(jnp.asarray(xs_r), wv)
+                out[idx] = np.asarray(o)[:m]
+                cnt[idx] = np.asarray(c)[:m]
+                host_lanes.append(idx[np.asarray(nh)[:m]])
         redo = np.concatenate(host_lanes) if host_lanes \
             else np.empty(0, np.int64)
 
     n_fallback = int(redo.size)
-    for i in redo:
-        r = crush_do_rule(cm.cmap, ruleno, int(xs[i]), result_max,
-                          weight=list(weight), choose_args=choose_args)
-        out[i] = r + [NONE] * (result_max - len(r))
-        cnt[i] = len(r)
+    tel.counter("crush_host_residue_lanes", n_fallback)
+    with span("crush.host_residue",
+              metric="crush_host_residue_seconds") as sp:
+        sp.attrs["lanes"] = n_fallback
+        for i in redo:
+            r = crush_do_rule(cm.cmap, ruleno, int(xs[i]), result_max,
+                              weight=list(weight), choose_args=choose_args)
+            out[i] = r + [NONE] * (result_max - len(r))
+            cnt[i] = len(r)
     from ..utils.debug import DeviceVerificationError, verification_enabled
     if verification_enabled():
         # sanitizer mode: every lane re-evaluated on the host oracle

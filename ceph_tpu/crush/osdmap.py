@@ -29,6 +29,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from ..telemetry.spans import span
 from .hash import crush_hash32_2
 from .mapper import crush_do_rule
 from .types import CRUSH_ITEM_NONE, CrushMap, RULE_TYPE_REPLICATED
@@ -386,30 +387,33 @@ class OSDMap:
         else:
             raw_arr = np.asarray(raw, dtype=np.int64)
 
-        # sparse layer: the few pgs with upmap entries take the scalar
-        # stages (and may widen the arrays past pool.size)
-        overrides: Dict[int, Tuple[List[int], int]] = {}
-        touched = {seed for pid, seed in self.pg_upmap if pid == pool_id}
-        touched |= {seed for pid, seed in self.pg_upmap_items
-                    if pid == pool_id}
-        # for ps in [0, pg_num), raw_pg_to_pg(ps) == ps (the stable-mod
-        # fold only matters for raw seeds beyond pg_num), so pgs with
-        # upmap entries are exactly the entry seeds themselves
-        for ps in sorted(t for t in touched if 0 <= t < pool.pg_num):
-            overrides[ps] = self.up_row_from_raw(pool, ps, raw_arr[ps],
-                                                 int(pps[ps]))
+        with span("crush.up_derive", metric="crush_up_derive_seconds"):
+            # sparse layer: the few pgs with upmap entries take the
+            # scalar stages (and may widen the arrays past pool.size)
+            overrides: Dict[int, Tuple[List[int], int]] = {}
+            touched = {seed for pid, seed in self.pg_upmap
+                       if pid == pool_id}
+            touched |= {seed for pid, seed in self.pg_upmap_items
+                        if pid == pool_id}
+            # for ps in [0, pg_num), raw_pg_to_pg(ps) == ps (the
+            # stable-mod fold only matters for raw seeds beyond
+            # pg_num), so pgs with upmap entries are exactly the entry
+            # seeds themselves
+            for ps in sorted(t for t in touched if 0 <= t < pool.pg_num):
+                overrides[ps] = self.up_row_from_raw(
+                    pool, ps, raw_arr[ps], int(pps[ps]))
 
-        up, up_primary = self._bulk_up_from_raw(pool, raw_arr, pps)
-        width = max([up.shape[1]]
-                    + [len(u) for u, _ in overrides.values()])
-        if width > up.shape[1]:
-            wider = np.full((pool.pg_num, width), CRUSH_ITEM_NONE,
-                            np.int32)
-            wider[:, :up.shape[1]] = up
-            up = wider
-        for ps, (u, prim) in overrides.items():
-            up[ps] = u + [CRUSH_ITEM_NONE] * (width - len(u))
-            up_primary[ps] = prim
+            up, up_primary = self._bulk_up_from_raw(pool, raw_arr, pps)
+            width = max([up.shape[1]]
+                        + [len(u) for u, _ in overrides.values()])
+            if width > up.shape[1]:
+                wider = np.full((pool.pg_num, width), CRUSH_ITEM_NONE,
+                                np.int32)
+                wider[:, :up.shape[1]] = up
+                up = wider
+            for ps, (u, prim) in overrides.items():
+                up[ps] = u + [CRUSH_ITEM_NONE] * (width - len(u))
+                up_primary[ps] = prim
         return up, up_primary
 
     def _bulk_up_from_raw(self, pool: PGPool, raw: np.ndarray,

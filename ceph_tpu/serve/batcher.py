@@ -16,9 +16,9 @@ it, the Ragged-Paged-Attention discipline (PAPERS.md, arxiv
   fixed ladder (default 1/4/16/64) instead of dispatching every
   occupancy as its own shape: |ladder| programs per bucket, warmed
   once, reused forever.  Padding waste is counted per dispatch
-  (``serve_padded_stripes`` / ``serve_padding_bytes``) — the SLO
-  report carries the overhead ratio, because padding is the price of
-  shape stability and must stay visible.
+  (``padding_stats()``) — the SLO report carries the overhead ratio,
+  because padding is the price of shape stability and must stay
+  visible.
 - **Deadline-aware firing.**  A bucket fires when it reaches the top
   rung (full) OR when its oldest request's slack — deadline minus now
   minus the bucket's EWMA service estimate — runs out.  Under load
@@ -47,6 +47,15 @@ entry runs).  Every dispatch is demuxed back to per-request
 the host side, so batched results are byte-identical to per-request
 execution by construction (pinned for all five plugin families in
 tests/test_serve.py).
+
+**Spans** (telemetry/spans.py; each feeds a self-time histogram):
+``serve.admit`` (classifying drained requests; a full-bucket fire
+nested in it is its child), and under each ``serve.batch``:
+``serve.pack`` (stacking the payloads), ``serve.dispatch`` (the engine
+call: H2D and launch), ``serve.wait`` (the device result),
+``serve.d2h`` (its copy to the host) and ``serve.demux`` (slicing out
+the per-request results).  ``serve_dispatches`` carries the fire
+``reason``: ``full``, ``deadline``, ``flush`` or ``backpressure``.
 """
 
 from __future__ import annotations
@@ -337,9 +346,20 @@ class ContinuousBatcher:
     def admit(self, requests: List[EcRequest]) -> List[EcResult]:
         """Classify requests into buckets; a bucket reaching the top
         rung fires immediately (continuous batching — full buckets
-        never wait for the next poll)."""
-        if self.paged:
-            return self._admit_paged(requests)
+        never wait for the next poll).  Each request is stamped
+        ``drained`` with one clock read: its batch wait runs from
+        here to its fire."""
+        if not requests:
+            return []
+        now = self.clock.monotonic()
+        for req in requests:
+            req.drained = now
+        with span("serve.admit", metric="serve_admit_seconds"):
+            if self.paged:
+                return self._admit_paged(requests)
+            return self._admit_dense(requests)
+
+    def _admit_dense(self, requests: List[EcRequest]) -> List[EcResult]:
         results: List[EcResult] = []
         for req in requests:
             b = self._bucket_for(req)
@@ -358,7 +378,7 @@ class ContinuousBatcher:
                               bucket="|".join(str(p) for p in b.key),
                               pending=len(b.requests))
             if len(b.requests) >= self.ladder[-1]:
-                results += self._fire(b)
+                results += self._fire(b, "full")
         return results
 
     def _admit_paged(self, requests: List[EcRequest]) -> List[EcResult]:
@@ -381,7 +401,7 @@ class ContinuousBatcher:
             try:
                 q.pool.write(req.req_id, req.payload)
             except PoolExhausted:
-                results += self._fire_ragged(q)
+                results += self._fire_ragged(q, "backpressure")
                 q.pool.write(req.req_id, req.payload)
             q.requests.append(req)
             if req.trace is not None:
@@ -390,7 +410,7 @@ class ContinuousBatcher:
                               pending=len(q.requests),
                               pages=q.pool.used_pages())
             if q.pool.free_pages() == 0:
-                results += self._fire_ragged(q)
+                results += self._fire_ragged(q, "full")
         return results
 
     # -- deadline-aware firing ------------------------------------------
@@ -421,10 +441,10 @@ class ContinuousBatcher:
         yield from self._buckets.values()
         yield from self._queues.values()
 
-    def _fire_unit(self, b) -> List[EcResult]:
+    def _fire_unit(self, b, reason: str) -> List[EcResult]:
         if isinstance(b, _RaggedQueue):
-            return self._fire_ragged(b)
-        return self._fire(b)
+            return self._fire_ragged(b, reason)
+        return self._fire(b, reason)
 
     def poll(self, queue: Optional[AdmissionQueue] = None
              ) -> List[EcResult]:
@@ -439,7 +459,7 @@ class ContinuousBatcher:
         due = sorted((b for b in self._units() if self._due(b, now)),
                      key=lambda b: b.oldest_deadline)
         for b in due:
-            results += self._fire_unit(b)
+            results += self._fire_unit(b, "deadline")
         return results
 
     def flush(self) -> List[EcResult]:
@@ -447,7 +467,7 @@ class ContinuousBatcher:
         results: List[EcResult] = []
         for b in sorted((b for b in self._units() if b.requests),
                         key=lambda b: b.oldest_deadline):
-            results += self._fire_unit(b)
+            results += self._fire_unit(b, "flush")
         return results
 
     def next_wakeup(self) -> Optional[float]:
@@ -462,20 +482,16 @@ class ContinuousBatcher:
 
     # -- dispatch --------------------------------------------------------
 
-    def _execute(self, b: _Bucket, stack: np.ndarray):
-        """One batched execution: the jitted serve program (device) or
-        the numpy batch surfaces (host).  Returns op-shaped host
-        arrays (device outputs fetched once per batch)."""
+    def _launch(self, b: _Bucket, stack: np.ndarray):
+        """Start one batched execution: the jitted serve program
+        (device; the result stays on the device until fetched) or the
+        numpy batch surfaces (host)."""
         self._programs.add((b.key, stack.shape[0]))
         if self.executor == "device":
             from ..codes.engine import serve_dispatch_call
 
             call = serve_dispatch_call(b.ec, b.op, b.available, b.erased)
-            out = call(stack)
-            if b.op == "repair":
-                rec, parity = out
-                return np.asarray(rec), np.asarray(parity)
-            return np.asarray(out)
+            return call(stack)
         # host tier: numpy end to end (the trace still names the
         # program family it rode — "host:" tier, so a host-executor
         # trace joins nothing in attribution_rows but stays honest
@@ -491,7 +507,27 @@ class ContinuousBatcher:
                 stack, b.available, b.erased))
         return _host_repair(b.ec, stack, b.available, b.erased)
 
-    def _fire(self, b: _Bucket) -> List[EcResult]:
+    def _execute(self, b: _Bucket, stack: np.ndarray):
+        """One batched execution, returned as op-shaped host arrays."""
+        return _host_arrays(self._launch(b, stack))
+
+    def _run(self, op: str, launch: Callable):
+        """The dispatch stages of one fire: ``serve.dispatch`` around
+        the launch, then (device executor) ``serve.wait`` for the
+        result and ``serve.d2h`` for its copy to the host."""
+        with span("serve.dispatch", executor=self.executor):
+            out = launch()
+        if self.executor != "device":
+            return out
+        import jax
+
+        with span("serve.wait", metric="serve_device_wait_seconds",
+                  op=op):
+            jax.block_until_ready(out)
+        with span("serve.d2h", metric="serve_d2h_seconds", op=op):
+            return _host_arrays(out)
+
+    def _fire(self, b: _Bucket, reason: str) -> List[EcResult]:
         """Fire a bucket; occupancy above the top rung (an oversized
         admission burst) is split into top-rung slices — every slice
         rides an already-warmed program, so the legacy hard error is
@@ -501,65 +537,60 @@ class ContinuousBatcher:
         top = self.ladder[-1]
         while reqs:
             take, reqs = reqs[:top], reqs[top:]
-            results += self._fire_slice(b, take)
+            results += self._fire_slice(b, take, reason)
         return results
 
-    def _fire_slice(self, b: _Bucket,
-                    reqs: List[EcRequest]) -> List[EcResult]:
+    def _fire_slice(self, b: _Bucket, reqs: List[EcRequest],
+                    reason: str) -> List[EcResult]:
         n = len(reqs)
         rung = rung_for(n, self.ladder)
-        stack = np.zeros((rung, b.rows, b.chunk_size), np.uint8)
-        for i, r in enumerate(reqs):
-            stack[i] = r.payload
-        traced = (tracing.enabled()
-                  and any(r.trace is not None for r in reqs))
-        if traced:
-            tracing.clear_program()
-        t0 = self.clock.monotonic()
         with span("serve.batch", op=b.op, occupancy=n, rung=rung,
-                  plugin=type(b.ec).__name__):
-            with span("serve.dispatch", executor=self.executor):
-                out = self._execute(b, stack)
+                  plugin=type(b.ec).__name__, reason=reason):
+            with span("serve.pack", metric="serve_pack_seconds",
+                      op=b.op):
+                stack = np.zeros((rung, b.rows, b.chunk_size), np.uint8)
+                for i, r in enumerate(reqs):
+                    stack[i] = r.payload
+            traced = (tracing.enabled()
+                      and any(r.trace is not None for r in reqs))
+            if traced:
+                tracing.clear_program()
+            t0 = self.clock.monotonic()
+            out = self._run(b.op, lambda: self._launch(b, stack))
             if self.service_model is not None:
                 # sim mode: deterministic service time instead of wall
                 # time — byte-identical SLO reports from a seed
                 self.clock.sleep(self.service_model(b, rung))
-        t1 = self.clock.monotonic()
-        service = t1 - t0
-        if _SERVICE_JITTER is not None:
-            service = _SERVICE_JITTER(service, self.dispatches)
-        self._est[b.key] = (service if b.key not in self._est else
-                            (1 - _EWMA_ALPHA) * self._est[b.key]
-                            + _EWMA_ALPHA * service)
-        self.dispatches += 1
-        self.stripes += n
-        pad = rung - n
-        self.padded_stripes += pad
-        self.padded_bytes += pad * b.rows * b.chunk_size
-        tel.counter("serve_dispatches", op=b.op)
-        tel.counter("serve_stripes", n, op=b.op)
-        if pad:
-            tel.counter("serve_padded_stripes", pad, op=b.op)
-        tel.observe("serve_batch_occupancy", n, op=b.op)
-        self.dispatch_log.append({
-            "bucket": "|".join(str(p) for p in b.key),
-            "op": b.op, "occupancy": n, "rung": rung,
-            "req_ids": [r.req_id for r in reqs]})
-        results = []
-        for i, r in enumerate(reqs):
-            if b.op == "repair":
-                rec, parity = out
-                payload_out = (rec[i], parity[i])
-            else:
-                payload_out = out[i]
-            wait = t0 - (r.arrival if r.arrival is not None else t0)
-            tel.observe("serve_queue_wait_seconds", max(0.0, wait),
-                        op=b.op)
-            results.append(EcResult(
-                request=r, output=payload_out, completed=t1,
-                queue_wait=max(0.0, wait), service=service,
-                batch_occupancy=n, batch_rung=rung,
-                deadline_met=(r.deadline is None or t1 <= r.deadline)))
+            t1 = self.clock.monotonic()
+            service = t1 - t0
+            if _SERVICE_JITTER is not None:
+                service = _SERVICE_JITTER(service, self.dispatches)
+            self._est[b.key] = (service if b.key not in self._est else
+                                (1 - _EWMA_ALPHA) * self._est[b.key]
+                                + _EWMA_ALPHA * service)
+            self.dispatches += 1
+            self.stripes += n
+            pad = rung - n
+            self.padded_stripes += pad
+            self.padded_bytes += pad * b.rows * b.chunk_size
+            tel.counter("serve_dispatches", op=b.op, reason=reason)
+            tel.counter("serve_stripes", n, op=b.op)
+            tel.observe("serve_batch_occupancy", n, op=b.op)
+            self.dispatch_log.append({
+                "bucket": "|".join(str(p) for p in b.key),
+                "op": b.op, "occupancy": n, "rung": rung,
+                "req_ids": [r.req_id for r in reqs]})
+            with span("serve.demux", metric="serve_demux_seconds",
+                      op=b.op):
+                results = []
+                for i, r in enumerate(reqs):
+                    if b.op == "repair":
+                        rec, parity = out
+                        payload_out = (rec[i], parity[i])
+                    else:
+                        payload_out = out[i]
+                    results.append(self._result(
+                        r, payload_out, b.op, t0, t1, service, n, rung))
         if traced:
             # the fire decision + the program the batch rode + the
             # per-request demux completion, stamped on the SAME clock
@@ -582,10 +613,27 @@ class ContinuousBatcher:
                        deadline_met=res.deadline_met)
         return results
 
+    @staticmethod
+    def _result(r: EcRequest, output, op: str, t0: float, t1: float,
+                service: float, n: int, rung: int) -> EcResult:
+        """One request's result; observes its queue wait (arrival to
+        dispatch start ``t0``) and the part of it spent in a bucket
+        (drained to ``t0``)."""
+        wait = max(0.0, t0 - (r.arrival if r.arrival is not None
+                              else t0))
+        tel.observe("serve_queue_wait_seconds", wait, op=op)
+        if r.drained is not None:
+            tel.observe("serve_batch_wait_seconds",
+                        max(0.0, t0 - r.drained), op=op)
+        return EcResult(
+            request=r, output=output, completed=t1, queue_wait=wait,
+            service=service, batch_occupancy=n, batch_rung=rung,
+            deadline_met=(r.deadline is None or t1 <= r.deadline))
+
     # -- ragged dispatch -------------------------------------------------
 
-    def _execute_ragged(self, q: _RaggedQueue, mask: np.ndarray):
-        """One ragged execution over the queue's WHOLE pool: the
+    def _launch_ragged(self, q: _RaggedQueue, mask: np.ndarray):
+        """Start one ragged execution over the queue's WHOLE pool: the
         mask-gated jitted program (device,
         engine.serve_dispatch_ragged) or the identical masked numpy
         batch surfaces (host).  Either way the program consumes
@@ -599,11 +647,7 @@ class ContinuousBatcher:
             call = serve_dispatch_ragged(
                 q.ec, q.op, q.available, q.erased,
                 pages=q.pool.pages, page_size=q.page_size)
-            out = call(q.pool.buf, mask)
-            if q.op == "repair":
-                rec, parity = out
-                return np.asarray(rec), np.asarray(parity)
-            return np.asarray(out)
+            return call(q.pool.buf, mask)
         if tracing.enabled():
             tracing.note_program(
                 "serve.host", {"op": q.op, "paged": True,
@@ -619,7 +663,12 @@ class ContinuousBatcher:
                 x, q.available, q.erased))
         return _host_repair(q.ec, x, q.available, q.erased)
 
-    def _fire_ragged(self, q: _RaggedQueue) -> List[EcResult]:
+    def _execute_ragged(self, q: _RaggedQueue, mask: np.ndarray):
+        """One ragged execution, returned as host arrays."""
+        return _host_arrays(self._launch_ragged(q, mask))
+
+    def _fire_ragged(self, q: _RaggedQueue,
+                     reason: str) -> List[EcResult]:
         reqs, q.requests = q.requests, []
         if not reqs:
             return []
@@ -630,62 +679,55 @@ class ContinuousBatcher:
                   and any(r.trace is not None for r in reqs))
         if traced:
             tracing.clear_program()
-        t0 = self.clock.monotonic()
         with span("serve.batch", op=q.op, occupancy=n, rung=live,
-                  plugin=type(q.ec).__name__, paged=True):
-            with span("serve.dispatch", executor=self.executor):
-                out = self._execute_ragged(q, mask)
+                  plugin=type(q.ec).__name__, paged=True,
+                  reason=reason):
+            t0 = self.clock.monotonic()
+            out = self._run(q.op, lambda: self._launch_ragged(q, mask))
             if self.service_model is not None:
                 # sim mode: the rung is the live page count, so the
                 # modeled bytes (live * rows * page_size) are EXACT —
                 # no padded-rung bytes in the model either
                 self.clock.sleep(self.service_model(q, live))
-        t1 = self.clock.monotonic()
-        service = t1 - t0
-        if _SERVICE_JITTER is not None:
-            service = _SERVICE_JITTER(service, self.dispatches)
-        self._est[q.key] = (service if q.key not in self._est else
-                            (1 - _EWMA_ALPHA) * self._est[q.key]
-                            + _EWMA_ALPHA * service)
-        self.dispatches += 1
-        self.stripes += n
-        # the ONLY padding in the paged path: per-request page-tail
-        # bytes (zero whenever page_size divides the chunk size)
-        tail_cols = sum(q.pool.tail_bytes(r.req_id) for r in reqs)
-        self.padded_bytes += tail_cols * q.rows
-        self.paged_tail_bytes += tail_cols * q.rows
-        self.paged_data_bytes += sum(
-            r.payload.shape[1] * q.rows for r in reqs)
-        tel.counter("serve_dispatches", op=q.op)
-        tel.counter("serve_stripes", n, op=q.op)
-        if tail_cols:
-            tel.counter("serve_page_tail_bytes", tail_cols * q.rows,
-                        op=q.op)
-        tel.observe("serve_batch_occupancy", n, op=q.op)
-        tel.observe("serve_pool_live_pages", live, op=q.op)
-        self.dispatch_log.append({
-            "bucket": "|".join(str(p) for p in q.key),
-            "op": q.op, "occupancy": n, "rung": live,
-            "req_ids": [r.req_id for r in reqs], "paged": True})
-        results = []
-        for r in reqs:
-            if q.op == "repair":
-                rec, parity = out
-                payload_out = (q.pool.read(r.req_id, rec),
-                               q.pool.read(r.req_id, parity))
-            else:
-                payload_out = q.pool.read(r.req_id, out)
-            wait = t0 - (r.arrival if r.arrival is not None else t0)
-            tel.observe("serve_queue_wait_seconds", max(0.0, wait),
-                        op=q.op)
-            results.append(EcResult(
-                request=r, output=payload_out, completed=t1,
-                queue_wait=max(0.0, wait), service=service,
-                batch_occupancy=n, batch_rung=live,
-                deadline_met=(r.deadline is None or t1 <= r.deadline)))
-            # explicit page reclaim at demux — the pool is empty again
-            # the moment every rider has its bytes back
-            q.pool.reclaim(r.req_id)
+            t1 = self.clock.monotonic()
+            service = t1 - t0
+            if _SERVICE_JITTER is not None:
+                service = _SERVICE_JITTER(service, self.dispatches)
+            self._est[q.key] = (service if q.key not in self._est else
+                                (1 - _EWMA_ALPHA) * self._est[q.key]
+                                + _EWMA_ALPHA * service)
+            self.dispatches += 1
+            self.stripes += n
+            # the ONLY padding in the paged path: per-request page-tail
+            # bytes (zero whenever page_size divides the chunk size)
+            tail_cols = sum(q.pool.tail_bytes(r.req_id) for r in reqs)
+            self.padded_bytes += tail_cols * q.rows
+            self.paged_tail_bytes += tail_cols * q.rows
+            self.paged_data_bytes += sum(
+                r.payload.shape[1] * q.rows for r in reqs)
+            tel.counter("serve_dispatches", op=q.op, reason=reason)
+            tel.counter("serve_stripes", n, op=q.op)
+            tel.observe("serve_batch_occupancy", n, op=q.op)
+            self.dispatch_log.append({
+                "bucket": "|".join(str(p) for p in q.key),
+                "op": q.op, "occupancy": n, "rung": live,
+                "req_ids": [r.req_id for r in reqs], "paged": True})
+            with span("serve.demux", metric="serve_demux_seconds",
+                      op=q.op):
+                results = []
+                for r in reqs:
+                    if q.op == "repair":
+                        rec, parity = out
+                        payload_out = (q.pool.read(r.req_id, rec),
+                                       q.pool.read(r.req_id, parity))
+                    else:
+                        payload_out = q.pool.read(r.req_id, out)
+                    results.append(self._result(
+                        r, payload_out, q.op, t0, t1, service, n, live))
+                    # explicit page reclaim at demux — the pool is
+                    # empty again the moment every rider has its
+                    # bytes back
+                    q.pool.reclaim(r.req_id)
         if traced:
             program = tracing.take_program()
             batch_seq = self.dispatches - 1
@@ -750,7 +792,6 @@ class ContinuousBatcher:
                 fired += 1
         self.warmup_dispatches += fired
         if fired:
-            tel.counter("serve_warmup_dispatches", fired)
             dout("serve", 10,
                  f"warmed {len(seen)} buckets x {len(self.ladder)} "
                  f"rungs ({fired} dispatches)")
@@ -781,7 +822,6 @@ class ContinuousBatcher:
                 fired += 1
         self.warmup_dispatches += fired
         if fired:
-            tel.counter("serve_warmup_dispatches", fired)
             dout("serve", 10,
                  f"warmed {len(seen)} paged queues ({fired} "
                  f"dispatches, one program each)")
@@ -842,6 +882,13 @@ class ContinuousBatcher:
             "paged": False,
             "cached_programs": self.cached_program_count(),
         }
+
+
+def _host_arrays(out):
+    """A dispatch result as host arrays (repair returns a pair)."""
+    if isinstance(out, (tuple, list)):
+        return tuple(np.asarray(o) for o in out)
+    return np.asarray(out)
 
 
 def _host_repair(ec, stack: np.ndarray, available: Tuple[int, ...],

@@ -85,6 +85,9 @@ class EcRequest:
     # request bills against; "" = the single-tenant legacy streams,
     # where nothing downstream consults it
     tenant: str = ""
+    # stamped by ContinuousBatcher.admit: arrival..drained is time in
+    # the admission queue, drained..dispatch time in a bucket
+    drained: Optional[float] = None
 
     def __post_init__(self) -> None:
         if self.op not in OPS:
@@ -157,7 +160,6 @@ class AdmissionQueue:
                     req.deadline = now + self.slo.deadline_for(req.op)
                 self._pending.append(req)
                 self.admitted += 1
-                depth += 1
             else:
                 self.rejected += 1
         if not admitted_now:
@@ -173,7 +175,6 @@ class AdmissionQueue:
                       tenant=req.tenant, reason="capacity")
             return False
         tel.counter("serve_admitted", op=req.op)
-        tel.gauge("serve_queue_depth", depth)
         # causal trace minted AT admission (outside the queue lock —
         # minting is collector bookkeeping): the trace's first event
         # is the same `arrival` stamp the SLO ledger measures from
@@ -188,10 +189,6 @@ class AdmissionQueue:
         with self._lock:
             out = list(self._pending)
             self._pending.clear()
-        if out:
-            # gauge emitted outside the lock (registry lock nests
-            # under it otherwise; same discipline as submit)
-            tel.gauge("serve_queue_depth", 0)
         return out
 
     def __len__(self) -> int:

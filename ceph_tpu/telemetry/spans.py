@@ -26,6 +26,11 @@ Design constraints (docs/OBSERVABILITY.md):
 - **Observable live**: enter/exit emit through utils.log at debug
   level 20 under the ``telemetry`` subsystem —
   ``CEPH_TPU_DEBUG=telemetry=20`` streams the trace as it happens.
+  The level is read once per span, and below 20 no message is built.
+- **One boundary, two readings**: ``span(..., metric=name)`` also
+  observes the span's self time (its duration less its child spans')
+  into the registry histogram ``name``, so the histogram and the
+  profiler's TraceAnnotation come from the same enter and exit.
 """
 
 from __future__ import annotations
@@ -39,7 +44,7 @@ from collections import deque
 from typing import Dict, List, Optional
 
 from ..utils.detcheck import default_clock
-from ..utils.log import dout
+from ..utils.log import emit, get_level
 from ..utils.locks import make_lock
 
 SPAN_DEBUG_LEVEL = 20   # dout level for span enter/exit events
@@ -75,6 +80,14 @@ class Span:
     @property
     def duration(self) -> Optional[float]:
         return None if self.end is None else self.end - self.start
+
+    @property
+    def self_time(self) -> Optional[float]:
+        """Duration less the child spans' (children never overlap:
+        they nest by one thread's call stack)."""
+        if self.end is None:
+            return None
+        return self.duration - sum(c.duration for c in self.children)
 
     def to_dict(self) -> dict:
         out: dict = {"name": self.name, "start": self.start,
@@ -129,23 +142,30 @@ class SpanTracer:
             return None
 
     @contextlib.contextmanager
-    def span(self, name: str, **attrs):
+    def span(self, name: str, metric: Optional[str] = None, **attrs):
         """Open a child span of the current thread's innermost open
         span (a root when none is open).  Yields the Span so callers
         can attach late attrs (e.g. the engine tier chosen inside).
+
+        ``metric``: on exit, observe the span's self time into this
+        registry histogram, labelled with the attrs given here (attrs
+        attached later annotate the tree only, so counts stay out of
+        the series identity).
 
         Honors the master recording switch (metrics.set_enabled): when
         telemetry is off, yields a throwaway span and records nothing
         — the perf_dump --check-overhead gate measures exactly this
         on/off delta."""
-        from .metrics import enabled
+        from .metrics import enabled, observe
         if not enabled():
             yield Span(name, 0.0, attrs)
             return
         stack = self._stack()
         sp = Span(name, self.clock.monotonic(), attrs)
-        path = "/".join([s.name for s in stack] + [name])
-        dout("telemetry", SPAN_DEBUG_LEVEL, f"span+ {path}")
+        path = None
+        if get_level("telemetry") >= SPAN_DEBUG_LEVEL:
+            path = "/".join([s.name for s in stack] + [name])
+            emit("telemetry", SPAN_DEBUG_LEVEL, f"span+ {path}")
         stack.append(sp)
         ann = self._annotation(name)
         if ann is not None:
@@ -157,6 +177,8 @@ class SpanTracer:
                 ann.__exit__(None, None, None)
             sp.end = self.clock.monotonic()
             stack.pop()
+            if metric is not None:
+                observe(metric, sp.self_time, **attrs)
             if stack:
                 stack[-1].children.append(sp)
             else:
@@ -173,8 +195,9 @@ class SpanTracer:
                         self.on_root(sp)
                     except Exception:  # noqa: BLE001 - observer only
                         pass
-            dout("telemetry", SPAN_DEBUG_LEVEL,
-                 f"span- {path} dur={sp.duration:.6f}s")
+            if path is not None:
+                emit("telemetry", SPAN_DEBUG_LEVEL,
+                     f"span- {path} dur={sp.duration:.6f}s")
 
     def _note_dropped(self) -> None:
         """Count every evicted root in the unified metrics plane and
@@ -231,9 +254,9 @@ def set_global_tracer(tracer: Optional[SpanTracer]
         return prev
 
 
-def span(name: str, **attrs):
+def span(name: str, metric: Optional[str] = None, **attrs):
     """Convenience: a span on the process-global tracer."""
-    return global_tracer().span(name, **attrs)
+    return global_tracer().span(name, metric=metric, **attrs)
 
 
 __all__ = ["SPAN_DEBUG_LEVEL", "Span", "SpanTracer", "global_tracer",

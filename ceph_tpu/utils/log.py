@@ -73,8 +73,14 @@ def set_stream(stream: Optional[TextIO]) -> None:
     _stream = stream if stream is not None else sys.stderr
 
 
+def emit(subsys: str, level: int, msg: str) -> None:
+    """Write one message without consulting the level: for callers
+    that read ``get_level`` once and format only when it passes."""
+    _stream.write(f"{time.strftime('%F %T')} {level:2d} "
+                  f"{subsys}: {msg}\n")
+
+
 def dout(subsys: str, level: int, msg: str) -> None:
     """dout.h -> ldout(cct, level) << ...: print when enabled."""
     if level <= get_level(subsys):
-        _stream.write(f"{time.strftime('%F %T')} {level:2d} "
-                      f"{subsys}: {msg}\n")
+        emit(subsys, level, msg)
