@@ -51,11 +51,20 @@ tests/test_serve.py).
 **Spans** (telemetry/spans.py; each feeds a self-time histogram):
 ``serve.admit`` (classifying drained requests; a full-bucket fire
 nested in it is its child), and under each ``serve.batch``:
-``serve.pack`` (stacking the payloads), ``serve.dispatch`` (the engine
-call: H2D and launch), ``serve.wait`` (the device result),
-``serve.d2h`` (its copy to the host) and ``serve.demux`` (slicing out
-the per-request results).  ``serve_dispatches`` carries the fire
-``reason``: ``full``, ``deadline``, ``flush`` or ``backpressure``.
+``serve.pack`` (copying the payloads into the staging buffer),
+``serve.dispatch`` (the engine call: H2D and launch), ``serve.wait``
+(the device result), ``serve.d2h`` (its copy to the host) and
+``serve.demux`` (slicing out the per-request results).
+``serve_dispatches`` carries the fire ``reason``: ``full``,
+``deadline``, ``flush`` or ``backpressure``.
+
+**Staging.**  Dense fires pack into one reused host buffer per
+``(rows, chunk_size)``, sized for the top rung and allocated at warm-up
+(``_Staging``): a rung-``r`` fire launches its leading ``r`` rows, so
+the program sees the same shape and bytes as a fresh zero-padded stack
+while no fire allocates (or page-faults) a stack of its own.
+``serve_pack_buffers`` counts each acquisition, ``outcome`` =
+``allocated`` or ``reused``.
 """
 
 from __future__ import annotations
@@ -140,6 +149,37 @@ def rung_for(n: int, ladder: Tuple[int, ...],
     if strict:
         raise ValueError(f"occupancy {n} exceeds top rung {ladder[-1]}")
     return ladder[-1]
+
+
+class _Staging:
+    """The reused host staging buffer of every dense bucket of one
+    ``(rows, chunk_size)``, sized for the top rung.  Fires are serial
+    and ``_run`` returns only once the result is on the host, so the
+    previous stack's H2D has finished before the next pack.  ``dirty``
+    counts the leading rows that may still hold an earlier fire's
+    payloads; every row past it is zero."""
+
+    __slots__ = ("buf", "dirty")
+
+    def __init__(self, top: int, rows: int, chunk_size: int) -> None:
+        self.buf = np.empty((top, rows, chunk_size), np.uint8)
+        # written, not calloc'd: every page is faulted in here, at
+        # allocation, instead of inside the first fire
+        self.buf.fill(0)
+        self.dirty = 0
+
+    def pack(self, reqs, rung: int) -> np.ndarray:
+        """The rung-shaped stack: ``reqs``' payloads, then zero pad
+        rows (only the stale ones among them are written)."""
+        n = len(reqs)
+        stack = self.buf[:rung]
+        for i, r in enumerate(reqs):
+            stack[i] = r.payload
+        if self.dirty > n:
+            stack[n:self.dirty] = 0
+        if self.dirty <= rung:
+            self.dirty = n
+        return stack
 
 
 class _Bucket:
@@ -264,6 +304,8 @@ class ContinuousBatcher:
         # pairs vs one key per paged queue — the collapse witness
         self._programs: set = set()
         self._est: Dict[tuple, float] = {}
+        # (rows, chunk_size) -> the dense path's reused staging buffer
+        self._staging: "Dict[tuple, _Staging]" = {}
         # per-dispatch composition log (bucket key, rung, req ids) —
         # the byte-identical-replay witness tests and the demo print
         self.dispatch_log: List[dict] = []
@@ -482,6 +524,18 @@ class ContinuousBatcher:
 
     # -- dispatch --------------------------------------------------------
 
+    def _staging_for(self, b: _Bucket) -> _Staging:
+        """The bucket's shared staging buffer, allocated on first use
+        (at warm-up, for a warmed bucket)."""
+        shape = (b.rows, b.chunk_size)
+        st = self._staging.get(shape)
+        outcome = "reused"
+        if st is None:
+            st = self._staging[shape] = _Staging(self.ladder[-1], *shape)
+            outcome = "allocated"
+        tel.counter("serve_pack_buffers", op=b.op, outcome=outcome)
+        return st
+
     def _launch(self, b: _Bucket, stack: np.ndarray):
         """Start one batched execution: the jitted serve program
         (device; the result stays on the device until fetched) or the
@@ -548,9 +602,7 @@ class ContinuousBatcher:
                   plugin=type(b.ec).__name__, reason=reason):
             with span("serve.pack", metric="serve_pack_seconds",
                       op=b.op):
-                stack = np.zeros((rung, b.rows, b.chunk_size), np.uint8)
-                for i, r in enumerate(reqs):
-                    stack[i] = r.payload
+                stack = self._staging_for(b).pack(reqs, rung)
             traced = (tracing.enabled()
                       and any(r.trace is not None for r in reqs))
             if traced:
@@ -755,7 +807,10 @@ class ContinuousBatcher:
         (bucket, rung).  After this, a stream drawn from the same mix
         compiles NOTHING — the armed recompile budget and the compile
         monitor both stay flat (the acceptance gate's 'zero warm
-        recompiles').  Returns the number of warmup dispatches.
+        recompiles').  The dispatches run on zeroed slices of the
+        bucket's staging buffer, so the buffer is allocated here and
+        not in the measured stream.  Returns the number of warmup
+        dispatches.
 
         Paged mode warms ONE program per queue instead of |ladder| per
         bucket — the activity mask is a traced operand, so a single
@@ -770,9 +825,9 @@ class ContinuousBatcher:
                 continue
             seen.add(key)
             b = self._bucket_for(req)
+            st = self._staging_for(b)
             for rung in self.ladder:
-                zeros = np.zeros((rung, b.rows, b.chunk_size), np.uint8)
-                self._execute(b, zeros)
+                self._execute(b, st.pack((), rung))
                 fired += 1
             # seed the service estimator with a timed WARM dispatch of
             # the top rung (the first run above paid the compile, so
@@ -784,8 +839,7 @@ class ContinuousBatcher:
             if self.service_model is not None:
                 self._est[key] = self.service_model(b, self.ladder[-1])
             else:
-                top = np.zeros((self.ladder[-1], b.rows, b.chunk_size),
-                               np.uint8)
+                top = st.pack((), self.ladder[-1])
                 t0 = self.clock.monotonic()
                 self._execute(b, top)
                 self._est[key] = self.clock.monotonic() - t0
