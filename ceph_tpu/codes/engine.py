@@ -29,6 +29,12 @@ repair plan.  Two pieces fix that:
   composes the same decode_chunks_jax / encode_chunks_jax the
   per-stripe path uses).
 
+- ``serve_decode_generic`` — for a plain w=8 matrix code the linear
+  map is a small (erased, k) block, so the serve decode takes it as an
+  operand (``decode_table``, the blocks cached here per pattern): one
+  program per (plugin, profile, erasure count) serves every pattern,
+  and stripes of different patterns share a dispatch.
+
 Engine selection for the matrix applies themselves lives in
 ops/pallas_gf.py::select_matrix_engine (the Pallas→XLA→numpy table,
 documented in docs/PERF.md); this module is the layer above it.
@@ -78,13 +84,9 @@ class PatternCache:
         self._warned = False
 
     def get_or_build(self, key: tuple, builder: Callable[[], object]):
-        with self._lock:
-            hit = self._entries.get(key)
-            if hit is not None:
-                self._entries.move_to_end(key)
-                self.hits += 1
-                tel.counter("pattern_cache_hits")
-                return hit
+        hit = self.get(key)
+        if hit is not None:
+            return hit
         # build OUTSIDE the lock: clay's impulse probe can take
         # seconds and must not serialize unrelated patterns
         with tel.record_dispatch("pattern_cache_build"):
@@ -130,6 +132,17 @@ class PatternCache:
                          f"patterns; evicting LRU (repeat plans will "
                          f"re-trace)")
             return value
+
+    def get(self, key: tuple):
+        """The value cached under ``key`` (counted as a hit and made
+        the most recent), or None."""
+        with self._lock:
+            hit = self._entries.get(key)
+            if hit is not None:
+                self._entries.move_to_end(key)
+                self.hits += 1
+                tel.counter("pattern_cache_hits")
+            return hit
 
     def stats(self) -> dict:
         with self._lock:
@@ -194,9 +207,10 @@ def _profiler():
     return global_profiler()
 
 
-def _shard_program(raw, plane, n_out: int):
-    """Wrap a per-shard (B_local, ..., C) -> rank-3 outputs body in
-    shard_map over the plane's stripe axis: the batch sharded, every
+def _shard_program(raw, plane, n_out: int, n_in: int = 1):
+    """Wrap a per-shard body of ``n_in`` rank-3 operands, each
+    (B_local, ..., ...), -> rank-3 outputs in shard_map over the
+    plane's stripe axis: every operand's batch sharded, every
     trace-time constant (decode/encode matrices, GF tables) replicated
     by construction, non-dividing batches zero-padded and the pad rows
     sliced off the outputs.  The body traces under
@@ -212,21 +226,24 @@ def _shard_program(raw, plane, n_out: int):
     ndev = plane.n_devices
     spec = batch_spec(plane.axis, 3)
 
-    def body(local):
+    def body(*local):
         with single_device():
-            return raw(local)
+            return raw(*local)
 
     sharded = shard_map_compat(
-        body, plane.mesh, in_specs=spec,
+        body, plane.mesh,
+        in_specs=tuple([spec] * n_in) if n_in > 1 else spec,
         out_specs=tuple([spec] * n_out) if n_out > 1 else spec)
 
     @jax.jit
-    def fn(stack):
-        b = stack.shape[0]
+    def fn(*operands):
+        b = operands[0].shape[0]
         pad = (-b) % ndev
-        x = (jnp.pad(stack, ((0, pad),) + ((0, 0),) * (stack.ndim - 1))
-             if pad else stack)
-        out = sharded(x)
+        if pad:
+            operands = tuple(
+                jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1))
+                for x in operands)
+        out = sharded(*operands)
         if not pad:
             return out
         if n_out == 1:
@@ -249,7 +266,9 @@ def fused_repair_call(ec, available: Tuple[int, ...],
     for the re-encode are assembled from survivor and decoded columns
     by static index, so the whole body jit-fuses.  Cached per
     (plugin, profile, pattern) in the global PatternCache — repeat
-    repair plans hit the warm trace.
+    repair plans hit the warm trace.  (Repair stays per pattern on
+    the serve path too; only the serve decode of a plain w=8 matrix
+    code is pattern-generic, :func:`serve_decode_generic`.)
 
     When a data plane is active (parallel/plane.py; ``mesh`` overrides
     it — a DataPlane, or falsy to force single-device), the program is
@@ -391,11 +410,70 @@ def fused_repair_call(ec, available: Tuple[int, ...],
 
 # -- serving dispatch seam (serve/batcher.py's one device call) ---------
 
+def _serve_timed(ec, fn, key: tuple, kind: str, pattern: str, seam: str,
+                 op: str, plane, host_twin, rebuild):
+    """The eager face of one serve program ``fn(stack, *operands)``:
+    cost-attribution capture, the causal-trace program link and the
+    ``serve_dispatch`` histogram around a supervised dispatch (tracer
+    inputs record nothing and call ``fn`` straight, so jaxprs stay
+    telemetry-free).  ``key`` is the PatternCache key — the program
+    identity, so two profiles of one plugin class never share an
+    attribution row; ``config`` = tuned|default records which config
+    regime BUILT the program (see fused_repair_call)."""
+    import jax
+
+    from ..tune.table import active_source
+    ndev = plane.n_devices if plane is not None else 1
+    prof_key = ("prof",) + key
+    prof_labels = dict(
+        plugin=type(ec).__name__, kind=kind,
+        profile=",".join(f"{pk}={pv}" for pk, pv in
+                         sorted(ec.get_profile().items())),
+        pattern=pattern,
+        engine="mesh" if plane is not None else "device",
+        devices=ndev, config=active_source()[0])
+
+    def timed(*args):
+        eager = not any(isinstance(a, jax.core.Tracer) for a in args)
+        batch = int(args[0].shape[0])
+        prof = _profiler()
+        if eager and tel.enabled():
+            if plane is not None:
+                tel.counter("engine_mesh_dispatches", tier=kind,
+                            devices=str(plane.n_devices))
+            # keyed per batch rung: one jit wrapper serves many
+            # stripe-batch shapes, each its own compiled program
+            pk = prof_key + (batch,)
+            prof.capture(pk, fn, args, name="engine.serve_dispatch",
+                         batch=batch, **prof_labels)
+        else:
+            pk = prof_key
+        if eager and trc.enabled():
+            # causal-trace link: see fused_repair_call
+            trc.note_program("engine.serve_dispatch",
+                             dict(prof_labels, batch=batch))
+        with tel.record_dispatch(
+                "serve_dispatch", eager=eager,
+                op=op, plugin=type(ec).__name__), \
+                prof.timed(pk, eager=eager):
+            if not eager:
+                return fn(*args)
+            from ..ops.supervisor import global_supervisor
+            return global_supervisor().dispatch(
+                seam, fn, args, host_fn=host_twin, rebuild=rebuild)
+
+    timed._raw = fn
+    return timed
+
+
 def serve_dispatch_call(ec, op: str, available: Tuple[int, ...] = (),
                         erased: Tuple[int, ...] = (), mesh=None):
     """One cached, jitted program per (plugin, profile, op, erasure
     pattern): the seam the continuous batcher (serve/batcher.py) fires
-    its shape buckets through.
+    its per-pattern shape buckets through.  (The batcher sends the
+    decode of a plain w=8 matrix code through
+    :func:`serve_decode_generic` instead, one program for every
+    pattern; this seam still serves every op of every code.)
 
     The cache key is :func:`pattern_key` with ``kind=f"serve-{op}"`` —
     the SAME keying the decode-matrix and fused-repair artifacts use,
@@ -456,58 +534,108 @@ def serve_dispatch_call(ec, op: str, available: Tuple[int, ...] = (),
             return serve_dispatch_call(ec, op, available, erased,
                                        mesh=mesh)._raw
 
-        ndev = plane.n_devices if plane is not None else 1
-        # keyed on the PatternCache key: program identity includes
-        # the profile, so rs_k4_m2 and rs_k8_m3 never share a row;
-        # config = tuned|default records which config regime BUILT
-        # this program (ISSUE 14 — see fused_repair_call)
-        from ..tune.table import active_source
-        prof_key = ("prof",) + key
-        prof_labels = dict(
-            plugin=type(ec).__name__, kind=f"serve-{op}",
-            profile=",".join(f"{pk}={pv}" for pk, pv in
-                             sorted(ec.get_profile().items())),
-            pattern="e" + "_".join(map(str, erased)),
-            engine="mesh" if plane is not None else "device",
-            devices=ndev, config=active_source()[0])
+        return _serve_timed(ec, fn, key, f"serve-{op}",
+                            "e" + "_".join(map(str, erased)),
+                            f"engine.serve-{op}", op, plane, host_twin,
+                            rebuild)
 
-        def timed(stack):
-            # same trace-eagerness discipline as fused_repair_call:
-            # record nothing when WE are being traced into a larger
-            # program, so jaxprs stay telemetry-free
-            eager = not isinstance(stack, jax.core.Tracer)
-            prof = _profiler()
-            if eager and tel.enabled():
-                if plane is not None:
-                    tel.counter("engine_mesh_dispatches",
-                                tier=f"serve-{op}",
-                                devices=str(plane.n_devices))
-                # keyed per batch rung: one jit wrapper serves many
-                # stripe-batch shapes, each its own compiled program
-                pk = prof_key + (int(stack.shape[0]),)
-                prof.capture(pk, fn, (stack,),
-                             name="engine.serve_dispatch",
-                             batch=int(stack.shape[0]), **prof_labels)
-            else:
-                pk = prof_key
-            if eager and trc.enabled():
-                # causal-trace link (ISSUE 15): see fused_repair_call
-                trc.note_program(
-                    "engine.serve_dispatch",
-                    dict(prof_labels, batch=int(stack.shape[0])))
-            with tel.record_dispatch(
-                    "serve_dispatch", eager=eager,
-                    op=op, plugin=type(ec).__name__), \
-                    prof.timed(pk, eager=eager):
-                if not eager:
-                    return fn(stack)
-                from ..ops.supervisor import global_supervisor
-                return global_supervisor().dispatch(
-                    f"engine.serve-{op}", fn, (stack,),
-                    host_fn=host_twin, rebuild=rebuild)
+    return global_pattern_cache().get_or_build(key, build)
 
-        timed._raw = fn
-        return timed
+
+# -- pattern-generic decode of matrix codes (serve/batcher.py) ----------
+
+def pattern_generic_decode(ec) -> bool:
+    """Whether ``ec`` decodes by the plain w=8 matrix-code decode
+    (jerasure reed_sol_van / reed_sol_r6_op, isa): one decode-rows
+    block per erasure pattern, so the serve decode can take the rows
+    as an operand.  Codes that override the decode (shec's plans) and
+    the composite codes (clay, lrc) are not."""
+    from .techniques import MatrixCodeMixin
+    cls = type(ec)
+    return (isinstance(ec, MatrixCodeMixin)
+            and getattr(ec, "w", None) == 8
+            and cls.decode_chunks_jax is MatrixCodeMixin.decode_chunks_jax
+            and cls.decode_chunks_batch
+            is MatrixCodeMixin.decode_chunks_batch)
+
+
+def generic_decode_key(ec, n_erased: int, extra: tuple = ()) -> tuple:
+    """PatternCache key of the pattern-generic decode program of
+    (plugin, profile, erasure count)."""
+    return pattern_key(ec, "serve-decode-generic", (), (),
+                       ("erased", int(n_erased)) + tuple(extra))
+
+
+def decode_table(ec, patterns, index, rung: int):
+    """The ``(rung, e, k)`` uint8 coefficient operand of one fire of
+    the pattern-generic decode: row ``i`` is the decode rows
+    (``ec.decode_rows``) of ``patterns[index[i]]``, each pattern an
+    ``(available[:k], erased)`` pair; rows whose index is negative or
+    past ``len(index)`` (pad stripes, dead pages) are zero.  Each
+    pattern's rows come from the bounded process PatternCache,
+    inverted on a miss (``engine_decode_matrix{outcome=hit|miss}``,
+    one per pattern); span ``engine.decode_table``."""
+    import numpy as np
+
+    from ..telemetry import span
+
+    with span("engine.decode_table",
+              metric="engine_decode_table_seconds"):
+        cache = global_pattern_cache()
+        rows = []
+        for available, erased in patterns:
+            key = pattern_key(ec, "decode-rows", available, erased)
+            hit = cache.get(key)
+            tel.counter("engine_decode_matrix",
+                        outcome="miss" if hit is None else "hit")
+            if hit is None:
+                hit = cache.get_or_build(
+                    key, lambda a=available, e=erased: ec.decode_rows(a, e))
+            rows.append(hit)
+        e = len(patterns[0][1]) if patterns else 0
+        out = np.zeros((rung, e, ec.get_data_chunk_count()), np.uint8)
+        index = np.asarray(index, np.int64)
+        live = index >= 0
+        if live.any():
+            out[:len(index)][live] = np.stack(rows)[index[live]]
+        return out
+
+
+def serve_decode_generic(ec, n_erased: int, mesh=None):
+    """One cached, jitted decode program per (plugin, profile, erasure
+    count) of a :func:`pattern_generic_decode` code: ``(stack (B, k,
+    C), coef (B, n_erased, k))`` → ``(B, n_erased, C)``, the decode
+    rows of every stripe an operand (:func:`decode_table`), so the
+    stripes of all erasure patterns with ``n_erased`` losses share the
+    program and a dispatch.  jit compiles it once per (rung, chunk
+    size), as the per-pattern programs of :func:`serve_dispatch_call`.
+
+    With an active data plane the stack and the coefficients are
+    sharded alike over the stripe axis, under a mesh-suffixed key."""
+    import jax
+
+    plane = _resolve_mesh(mesh)
+    extra = ("mesh", plane.n_devices) if plane is not None else ()
+    key = generic_decode_key(ec, n_erased, extra)
+
+    def build():
+        def raw_operand(stack, coef):
+            return ec.decode_chunks_operand_jax(stack, coef)
+
+        fn = (jax.jit(raw_operand) if plane is None
+              else _shard_program(raw_operand, plane, n_out=1, n_in=2))
+
+        def host_twin(stack, coef):
+            import numpy as np
+            return ec.decode_chunks_operand_batch(np.asarray(stack),
+                                                  np.asarray(coef))
+
+        def rebuild():
+            return serve_decode_generic(ec, n_erased, mesh=mesh)._raw
+
+        return _serve_timed(ec, fn, key, "serve-decode-generic",
+                            f"n{int(n_erased)}", "engine.serve-decode",
+                            "decode", plane, host_twin, rebuild)
 
     return global_pattern_cache().get_or_build(key, build)
 

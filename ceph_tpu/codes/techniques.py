@@ -152,6 +152,31 @@ class MatrixCodeMixin:
         words = jax_words_view(chunks[..., :ns, :], self.w)
         return jax_bytes_view(apply_matrix_best(words, dm_static, self.w))
 
+    # -- operand-coefficient decode (the pattern-generic serve path) -----
+
+    def decode_rows(self, available: tuple, erased: tuple) -> np.ndarray:
+        """The (len(erased), k) uint8 decode rows of one pattern: the
+        GF(2^8) matrix taking the survivors ``available[:k]``, stacked
+        in that order, to the erased chunks (w=8)."""
+        if len(available) < self.k:
+            raise IOError(f"need {self.k} chunks, have {len(available)}")
+        return regionops.matrix_decode_matrix(
+            self.matrix, self.k, list(available[:self.k]), list(erased),
+            self.w).astype(np.uint8)
+
+    def decode_chunks_operand_jax(self, chunks, coef):
+        """(B, >= k, C) uint8 survivors + (B, e, k) uint8 per-stripe
+        decode rows (:meth:`decode_rows`) -> (B, e, C): stripes of
+        different erasure patterns in one device program (w=8)."""
+        from ..ops.pallas_gf import apply_matrix_operand_best
+        return apply_matrix_operand_best(chunks[:, :self.k, :], coef)
+
+    def decode_chunks_operand_batch(self, chunks: np.ndarray,
+                                    coef: np.ndarray) -> np.ndarray:
+        """Numpy twin of :meth:`decode_chunks_operand_jax`."""
+        from ..ops.pallas_gf import host_apply_operand
+        return host_apply_operand(np.asarray(chunks)[:, :self.k], coef)
+
     # -- ragged paged surfaces (ISSUE 18: serve/pool.py page pools) ------
 
     def page_unit(self) -> int:

@@ -1513,3 +1513,141 @@ def apply_matrix_packed_best_ragged(words: jax.Array, matrix_t,
                 "ops.apply_matrix_packed_ragged", words, mask,
                 matrix_t, True, eng)
         return _run_matrix_packed_ragged(words, matrix_t, mask, eng)
+
+
+# -- operand-coefficient family (pattern-generic decode) -----------------
+#
+# Every kernel above bakes its matrix into the trace, so a decode
+# program exists per erasure pattern.  The family below takes the
+# coefficients as an OPERAND instead: one (r, s) uint8 block per
+# stripe of the batch, so stripes of different erasure patterns (same
+# r, same s) share one compiled program and one dispatch.  The body
+# walks the xtime doubling ladder of each input tile once and, for
+# every output row, folds plane t in under a mask made from bit t of
+# that stripe's coefficient — the static kernel's schedule with the
+# "does bit t of matrix[i][j] exist" decision moved from trace time to
+# a scalar read.
+
+OPERAND_SCOPE = "gf_operand_apply"
+
+
+def _gf8_operand_kernel(s: int, r: int, interpret: bool):
+    """w=8 operand-coefficient body.  Index 0 of the kernel args is the
+    scalar-prefetch ref of the flattened (B, r, s) int32 coefficients;
+    grid axis 0 walks the stripes."""
+
+    def kernel(coef_ref, in_ref, out_ref):
+        base = pl.program_id(0) * (r * s)
+        accs = [None] * r
+        for j in range(s):
+            plane = _pack_words(in_ref[0, j], interpret)
+            col = [coef_ref[base + i * s + j] for i in range(r)]
+            for t in range(8):
+                if t > 0:
+                    plane = _xtime_swar(plane, 8)
+                for i in range(r):
+                    # all ones where bit t of the coefficient is set
+                    mask = (-((col[i] >> t) & 1)).astype(jnp.uint32)
+                    term = plane & mask
+                    accs[i] = term if accs[i] is None else accs[i] ^ term
+        for i in range(r):
+            out_ref[0, i] = _unpack_words(accs[i], interpret)
+
+    return kernel
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3))
+def apply_matrix_operand_pallas(chunks: jax.Array, coef: jax.Array,
+                                interpret: bool = False,
+                                row_tile_cap: int | None = None
+                                ) -> jax.Array:
+    """Apply a per-stripe (r, s) GF(2^8) matrix to (B, s, C) uint8
+    chunks -> (B, r, C): ``out[b, i] = XOR_j coef[b, i, j] * chunks[b,
+    j]``.  ``coef`` (B, r, s) uint8 is a TRACED operand (scalar
+    prefetch), so one compiled program serves every matrix of that
+    shape; for a batch that repeats one matrix the bytes equal
+    :func:`apply_matrix_pallas`'s.  Caller gates on
+    pallas_matrix_padded_supported."""
+    b, s, c = chunks.shape
+    r = coef.shape[1]
+    assert coef.shape == (b, r, s) and chunks.dtype == jnp.uint8
+    rows = c // LANE
+    tiles = chunks.reshape(b, s, rows, LANE)
+    pad = (-rows) % SUBLANE_U8
+    if pad:
+        tiles = jnp.pad(tiles, ((0, 0), (0, 0), (0, pad), (0, 0)))
+    prows = rows + pad
+    rt = _row_tile8(prows, row_tile_cap)
+    with jax.named_scope(OPERAND_SCOPE):
+        out = _pallas_call(
+            _gf8_operand_kernel(s, r, interpret),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(b, prows // rt),
+                in_specs=[pl.BlockSpec((1, s, rt, LANE),
+                                       lambda i, j, cf: (i, 0, j, 0))],
+                out_specs=pl.BlockSpec((1, r, rt, LANE),
+                                       lambda i, j, cf: (i, 0, j, 0)),
+            ),
+            out_shape=jax.ShapeDtypeStruct((b, r, prows, LANE),
+                                           jnp.uint8),
+            interpret=interpret,
+        )(coef.astype(jnp.int32).reshape(-1), tiles)
+    if pad:
+        out = out[..., :rows, :]
+    return out.reshape(b, r, c)
+
+
+def _xtime8_bytes(v: jax.Array) -> jax.Array:
+    """xtime on uint8 elements (one field byte each)."""
+    hi = v >> jnp.uint8(7)
+    return (v << jnp.uint8(1)) ^ (hi * jnp.uint8(0x1D))
+
+
+@jax.jit
+def apply_matrix_operand_xla(chunks: jax.Array,
+                             coef: jax.Array) -> jax.Array:
+    """The XLA tier of :func:`apply_matrix_operand_pallas` (backends
+    without Mosaic, chunk sizes off the lane width): the same ladder
+    and masks on uint8 elements, byte-identical."""
+    b, s, c = chunks.shape
+    r = coef.shape[1]
+    with jax.named_scope(OPERAND_SCOPE):
+        acc = jnp.zeros((b, r, c), jnp.uint8)
+        for j in range(s):
+            plane = chunks[:, j, None, :]
+            for t in range(8):
+                if t > 0:
+                    plane = _xtime8_bytes(plane)
+                mask = jnp.uint8(0) - ((coef[:, :, j] >> t) & 1)
+                acc = acc ^ (plane & mask[:, :, None])
+    return acc
+
+
+def host_apply_operand(chunks, coef) -> np.ndarray:
+    """Numpy ground truth of the operand family: one product-table
+    gather per (output row, input chunk)."""
+    from ..gf.gf8 import gf8
+    mul = gf8().mul_table
+    x = np.asarray(chunks)
+    cf = np.asarray(coef)
+    b, s, c = x.shape
+    r = cf.shape[1]
+    out = np.zeros((b, r, c), np.uint8)
+    for i in range(r):
+        for j in range(s):
+            out[:, i] ^= mul[cf[:, i, j, None], x[:, j]]
+    return out
+
+
+def apply_matrix_operand_best(chunks: jax.Array,
+                              coef: jax.Array) -> jax.Array:
+    """Per-stripe-coefficient apply on the device tier: the Pallas
+    kernel on TPU where the chunk size tiles, the XLA tier otherwise.
+    Byte-identical either way; the serve seam that calls it
+    (codes/engine.py::serve_decode_generic) supervises the dispatch
+    and carries the numpy twin."""
+    if use_pallas() and pallas_matrix_padded_supported(chunks.shape, 8):
+        return apply_matrix_operand_pallas(
+            chunks, coef, row_tile_cap=tuned_row_tile_cap(False))
+    return apply_matrix_operand_xla(chunks, coef)

@@ -338,7 +338,8 @@ class DispatchSupervisor:
         (the engine seams pass their own cached-call constructors, so
         a rebuilt program lands on the demoted tier / shrunk plane).
         ``splittable``: the first argument carries the stripe batch on
-        axis 0, so an OOM can downshift the rung by splitting it.
+        axis 0, so an OOM can downshift the rung by splitting it (with
+        every argument of the same leading size).
         ``verifiable=False`` opts the seam out of self-verify — for
         seams whose device output legitimately differs from the
         reference twin (crush bulk's need-host residue flags feed a
@@ -528,10 +529,15 @@ class DispatchSupervisor:
                   split=(mid, b - mid))
         dout("ec", 1, f"supervisor: RESOURCE_EXHAUSTED at {seam}; "
                       f"splitting batch {b} -> {mid}+{b - mid}")
+        # every operand that carries the stripe batch on axis 0 (the
+        # stack, a per-stripe coefficient operand) splits with it
+        per_stripe = [self._batch_of((a,)) == b for a in args]
         halves = []
-        for part in (stack[:mid], stack[mid:]):
+        for lo, hi in ((0, mid), (mid, b)):
+            part = tuple(a[lo:hi] if cut else a
+                         for a, cut in zip(args, per_stripe))
             halves.append(self.dispatch(
-                seam, fn, (part,) + tuple(args[1:]), host_fn=host_fn,
+                seam, fn, part, host_fn=host_fn,
                 rebuild=rebuild, splittable=True,
                 verifiable=verifiable, _depth=depth + 1))
         return _concat_outputs(halves[0], halves[1])

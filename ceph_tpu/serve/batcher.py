@@ -12,6 +12,16 @@ it, the Ragged-Paged-Attention discipline (PAPERS.md, arxiv
   available, erased) via :func:`~ceph_tpu.codes.engine.pattern_key`,
   extended with the chunk size — so bucket identity ≡ device-program
   identity and a warm bucket can never trace a new program.
+- **Pattern-generic decode.**  The decode of a plain w=8 matrix code
+  (jerasure reed_sol_van / reed_sol_r6_op, isa;
+  :func:`~ceph_tpu.codes.engine.pattern_generic_decode`) is one
+  bucket per (plugin, profile, erasure count, chunk size) whatever the
+  erasure pattern: each request's first k survivor rows are packed,
+  and its pattern's decode rows ride the stack as a ``(rung, e, k)``
+  coefficient operand (:func:`~ceph_tpu.codes.engine.decode_table`)
+  into :func:`~ceph_tpu.codes.engine.serve_decode_generic` — one
+  program per rung for every pattern.  ``serve_dispatch_patterns``
+  observes the distinct patterns of every decode/repair fire.
 - **The rung ladder.**  The batch dimension is padded up to a small
   fixed ladder (default 1/4/16/64) instead of dispatching every
   occupancy as its own shape: |ladder| programs per bucket, warmed
@@ -169,12 +179,14 @@ class _Staging:
         self.dirty = 0
 
     def pack(self, reqs, rung: int) -> np.ndarray:
-        """The rung-shaped stack: ``reqs``' payloads, then zero pad
-        rows (only the stale ones among them are written)."""
+        """The rung-shaped stack: ``reqs``' payloads (their leading
+        ``rows`` rows: a generic decode's first k survivors), then
+        zero pad rows (only the stale ones among them are written)."""
         n = len(reqs)
         stack = self.buf[:rung]
+        rows = stack.shape[1]
         for i, r in enumerate(reqs):
-            stack[i] = r.payload
+            stack[i] = r.payload[:rows]
         if self.dirty > n:
             stack[n:self.dirty] = 0
         if self.dirty <= rung:
@@ -184,13 +196,15 @@ class _Staging:
 
 class _Bucket:
     """One shape bucket: same plugin/profile/op/pattern/chunk-size —
-    same device program family."""
+    same device program family.  A ``generic`` decode bucket holds
+    every pattern with ``n_erased`` losses (``available`` and
+    ``erased`` empty; ``rows`` = k)."""
 
     __slots__ = ("key", "ec", "op", "available", "erased", "chunk_size",
-                 "rows", "requests")
+                 "rows", "requests", "generic", "n_erased")
 
     def __init__(self, key, ec, op, available, erased, chunk_size,
-                 rows) -> None:
+                 rows, generic: bool = False, n_erased: int = 0) -> None:
         self.key = key
         self.ec = ec
         self.op = op
@@ -198,6 +212,8 @@ class _Bucket:
         self.erased = erased
         self.chunk_size = chunk_size
         self.rows = rows
+        self.generic = generic
+        self.n_erased = n_erased
         self.requests: List[EcRequest] = []
 
     @property
@@ -215,16 +231,19 @@ class _RaggedQueue:
     page_size)."""
 
     __slots__ = ("key", "ec", "op", "available", "erased", "rows",
-                 "page_size", "pool", "requests")
+                 "page_size", "pool", "requests", "generic", "n_erased")
 
     def __init__(self, key, ec, op, available, erased, rows,
-                 page_size, pool_pages) -> None:
+                 page_size, pool_pages, generic: bool = False,
+                 n_erased: int = 0) -> None:
         self.key = key
         self.ec = ec
         self.op = op
         self.available = available
         self.erased = erased
         self.rows = rows
+        self.generic = generic
+        self.n_erased = n_erased
         self.page_size = page_size
         self.pool = PagedStripePool(pool_pages, rows, page_size,
                                     ec.page_interleave())
@@ -337,14 +356,27 @@ class ContinuousBatcher:
             self._instances[pkey] = ec
         return ec
 
+    @staticmethod
+    def _generic(ec, req: EcRequest) -> bool:
+        """Whether ``req`` rides a pattern-generic decode bucket."""
+        from ..codes.engine import pattern_generic_decode
+
+        return (req.op == "decode"
+                and len(req.available) >= ec.get_data_chunk_count()
+                and pattern_generic_decode(ec))
+
     def bucket_key(self, req: EcRequest) -> tuple:
         """The bucket identity — the PatternCache key of the program
         the bucket will fire, extended with the chunk size (the only
-        shape axis the pattern alone doesn't fix)."""
-        from ..codes.engine import pattern_key
+        shape axis the program key doesn't fix).  That key holds the
+        erasure pattern, except for a pattern-generic decode, whose
+        program is keyed by the erasure count alone."""
+        from ..codes.engine import generic_decode_key, pattern_key
 
         ec = self._instance(req.plugin, req.profile)
         chunk = ec.get_chunk_size(req.stripe_size)
+        if self._generic(ec, req):
+            return generic_decode_key(ec, len(req.erased), extra=(chunk,))
         return pattern_key(ec, f"serve-{req.op}", req.available,
                            req.erased, extra=(chunk,))
 
@@ -354,19 +386,29 @@ class ContinuousBatcher:
         if b is None:
             ec = self._instance(req.plugin, req.profile)
             chunk = ec.get_chunk_size(req.stripe_size)
-            rows = (ec.get_data_chunk_count() if req.op == "encode"
-                    else len(req.available))
-            b = self._buckets[key] = _Bucket(
-                key, ec, req.op, req.available, req.erased, chunk, rows)
+            if self._generic(ec, req):
+                b = _Bucket(key, ec, req.op, (), (), chunk,
+                            ec.get_data_chunk_count(), generic=True,
+                            n_erased=len(req.erased))
+            else:
+                rows = (ec.get_data_chunk_count() if req.op == "encode"
+                        else len(req.available))
+                b = _Bucket(key, ec, req.op, req.available, req.erased,
+                            chunk, rows)
+            self._buckets[key] = b
         return b
 
     def ragged_key(self, req: EcRequest) -> tuple:
         """The paged-queue identity — the PatternCache key WITHOUT the
         chunk-size extra: mixed stripe sizes co-batch into one queue,
-        one pool, ONE ragged device program."""
-        from ..codes.engine import pattern_key
+        one pool, ONE ragged device program.  A pattern-generic decode
+        queue is keyed by the erasure count (its pages take the
+        generic decode program, a page a mini-stripe)."""
+        from ..codes.engine import generic_decode_key, pattern_key
 
         ec = self._instance(req.plugin, req.profile)
+        if self._generic(ec, req):
+            return generic_decode_key(ec, len(req.erased))
         return pattern_key(ec, f"serve-{req.op}", req.available,
                            req.erased)
 
@@ -375,12 +417,18 @@ class ContinuousBatcher:
         q = self._queues.get(key)
         if q is None:
             ec = self._instance(req.plugin, req.profile)
-            rows = (ec.get_data_chunk_count() if req.op == "encode"
-                    else len(req.available))
             ps = effective_page_size(self.page_size, ec.page_unit())
-            q = self._queues[key] = _RaggedQueue(
-                key, ec, req.op, req.available, req.erased, rows, ps,
-                self.pool_pages)
+            if self._generic(ec, req):
+                q = _RaggedQueue(key, ec, req.op, (), (),
+                                 ec.get_data_chunk_count(), ps,
+                                 self.pool_pages, generic=True,
+                                 n_erased=len(req.erased))
+            else:
+                rows = (ec.get_data_chunk_count() if req.op == "encode"
+                        else len(req.available))
+                q = _RaggedQueue(key, ec, req.op, req.available,
+                                 req.erased, rows, ps, self.pool_pages)
+            self._queues[key] = q
         return q
 
     # -- admission -------------------------------------------------------
@@ -405,7 +453,8 @@ class ContinuousBatcher:
         results: List[EcResult] = []
         for req in requests:
             b = self._bucket_for(req)
-            want = (b.rows, b.chunk_size)
+            want = (len(req.available) if b.generic else b.rows,
+                    b.chunk_size)
             if tuple(req.payload.shape) != want:
                 raise ValueError(
                     f"request {req.req_id}: payload shape "
@@ -434,17 +483,18 @@ class ContinuousBatcher:
         for req in requests:
             q = self._queue_for(req)
             chunk = q.ec.get_chunk_size(req.stripe_size)
-            want = (q.rows, chunk)
+            want = (len(req.available) if q.generic else q.rows, chunk)
             if tuple(req.payload.shape) != want:
                 raise ValueError(
                     f"request {req.req_id}: payload shape "
                     f"{tuple(req.payload.shape)} != {want} for "
                     f"op={req.op} plugin={req.plugin}")
+            payload = req.payload[:q.rows]
             try:
-                q.pool.write(req.req_id, req.payload)
+                q.pool.write(req.req_id, payload)
             except PoolExhausted:
                 results += self._fire_ragged(q, "backpressure")
-                q.pool.write(req.req_id, req.payload)
+                q.pool.write(req.req_id, payload)
             q.requests.append(req)
             if req.trace is not None:
                 req.trace.add("bucket", self.clock.monotonic(),
@@ -536,14 +586,18 @@ class ContinuousBatcher:
         tel.counter("serve_pack_buffers", op=b.op, outcome=outcome)
         return st
 
-    def _launch(self, b: _Bucket, stack: np.ndarray):
+    def _launch(self, b: _Bucket, stack: np.ndarray, coef=None):
         """Start one batched execution: the jitted serve program
         (device; the result stays on the device until fetched) or the
-        numpy batch surfaces (host)."""
+        numpy batch surfaces (host).  ``coef``: a generic decode
+        bucket's ``(rung, e, k)`` coefficient operand."""
         self._programs.add((b.key, stack.shape[0]))
         if self.executor == "device":
-            from ..codes.engine import serve_dispatch_call
+            from ..codes.engine import (serve_decode_generic,
+                                        serve_dispatch_call)
 
+            if b.generic:
+                return serve_decode_generic(b.ec, b.n_erased)(stack, coef)
             call = serve_dispatch_call(b.ec, b.op, b.available, b.erased)
             return call(stack)
         # host tier: numpy end to end (the trace still names the
@@ -554,6 +608,8 @@ class ContinuousBatcher:
             tracing.note_program(
                 "serve.host", {"op": b.op,
                                "plugin": type(b.ec).__name__})
+        if b.generic:
+            return b.ec.decode_chunks_operand_batch(stack, coef)
         if b.op == "encode":
             return np.asarray(b.ec.encode_chunks_batch(stack))
         if b.op == "decode":
@@ -561,9 +617,37 @@ class ContinuousBatcher:
                 stack, b.available, b.erased))
         return _host_repair(b.ec, stack, b.available, b.erased)
 
-    def _execute(self, b: _Bucket, stack: np.ndarray):
+    def _execute(self, b: _Bucket, stack: np.ndarray, coef=None):
         """One batched execution, returned as op-shaped host arrays."""
-        return _host_arrays(self._launch(b, stack))
+        return _host_arrays(self._launch(b, stack, coef))
+
+    def _coefficients(self, unit, reqs: List[EcRequest], rung: int):
+        """A generic decode fire's coefficient operand, one row per
+        stripe of the rung (a dense bucket) or per page of the pool (a
+        paged queue), zero where no request is; None for any other
+        unit.  Observes the fire's distinct erasure patterns
+        (``serve_dispatch_patterns``) for every decode/repair fire."""
+        if unit.op == "encode":
+            return None
+        if not unit.generic:
+            tel.observe("serve_dispatch_patterns", 1, op=unit.op)
+            return None
+        from ..codes.engine import decode_table
+
+        k = unit.rows
+        paged = isinstance(unit, _RaggedQueue)
+        patterns: Dict[tuple, int] = {}
+        index = np.full(rung, -1, np.int64)
+        for i, r in enumerate(reqs):
+            p = patterns.setdefault((tuple(r.available[:k]), r.erased),
+                                    len(patterns))
+            if paged:
+                index[list(unit.pool.lease(r.req_id)[0])] = p
+            else:
+                index[i] = p
+        tel.observe("serve_dispatch_patterns", len(patterns),
+                    op=unit.op)
+        return decode_table(unit.ec, list(patterns), index, rung)
 
     def _run(self, op: str, launch: Callable):
         """The dispatch stages of one fire: ``serve.dispatch`` around
@@ -603,12 +687,13 @@ class ContinuousBatcher:
             with span("serve.pack", metric="serve_pack_seconds",
                       op=b.op):
                 stack = self._staging_for(b).pack(reqs, rung)
+            coef = self._coefficients(b, reqs, rung)
             traced = (tracing.enabled()
                       and any(r.trace is not None for r in reqs))
             if traced:
                 tracing.clear_program()
             t0 = self.clock.monotonic()
-            out = self._run(b.op, lambda: self._launch(b, stack))
+            out = self._run(b.op, lambda: self._launch(b, stack, coef))
             if self.service_model is not None:
                 # sim mode: deterministic service time instead of wall
                 # time — byte-identical SLO reports from a seed
@@ -684,18 +769,26 @@ class ContinuousBatcher:
 
     # -- ragged dispatch -------------------------------------------------
 
-    def _launch_ragged(self, q: _RaggedQueue, mask: np.ndarray):
+    def _launch_ragged(self, q: _RaggedQueue, mask: np.ndarray,
+                       coef=None):
         """Start one ragged execution over the queue's WHOLE pool: the
         mask-gated jitted program (device,
         engine.serve_dispatch_ragged) or the identical masked numpy
         batch surfaces (host).  Either way the program consumes
         ``(pages, rows, page_size) + (pages,)`` with the mask as a
         traced operand — ONE cached program per queue at any
-        occupancy."""
+        occupancy.  A generic decode queue runs the generic decode
+        program over the pool, a page a mini-stripe: ``coef`` holds
+        each live page's decode rows and zeros on dead pages, which
+        stand in for the mask."""
         self._programs.add(q.key)
         if self.executor == "device":
-            from ..codes.engine import serve_dispatch_ragged
+            from ..codes.engine import (serve_decode_generic,
+                                        serve_dispatch_ragged)
 
+            if q.generic:
+                return serve_decode_generic(q.ec, q.n_erased)(q.pool.buf,
+                                                              coef)
             call = serve_dispatch_ragged(
                 q.ec, q.op, q.available, q.erased,
                 pages=q.pool.pages, page_size=q.page_size)
@@ -704,6 +797,8 @@ class ContinuousBatcher:
             tracing.note_program(
                 "serve.host", {"op": q.op, "paged": True,
                                "plugin": type(q.ec).__name__})
+        if q.generic:
+            return q.ec.decode_chunks_operand_batch(q.pool.buf, coef)
         # the host tier runs the IDENTICAL ragged program: mask-gate
         # the pool (dead pages carry stale bytes), then the batch
         # surfaces over pages-as-mini-chunks
@@ -715,9 +810,10 @@ class ContinuousBatcher:
                 x, q.available, q.erased))
         return _host_repair(q.ec, x, q.available, q.erased)
 
-    def _execute_ragged(self, q: _RaggedQueue, mask: np.ndarray):
+    def _execute_ragged(self, q: _RaggedQueue, mask: np.ndarray,
+                        coef=None):
         """One ragged execution, returned as host arrays."""
-        return _host_arrays(self._launch_ragged(q, mask))
+        return _host_arrays(self._launch_ragged(q, mask, coef))
 
     def _fire_ragged(self, q: _RaggedQueue,
                      reason: str) -> List[EcResult]:
@@ -727,6 +823,7 @@ class ContinuousBatcher:
         n = len(reqs)
         mask = q.pool.mask()
         live = int(mask.sum())
+        coef = self._coefficients(q, reqs, q.pool.pages)
         traced = (tracing.enabled()
                   and any(r.trace is not None for r in reqs))
         if traced:
@@ -735,7 +832,8 @@ class ContinuousBatcher:
                   plugin=type(q.ec).__name__, paged=True,
                   reason=reason):
             t0 = self.clock.monotonic()
-            out = self._run(q.op, lambda: self._launch_ragged(q, mask))
+            out = self._run(q.op,
+                            lambda: self._launch_ragged(q, mask, coef))
             if self.service_model is not None:
                 # sim mode: the rung is the live page count, so the
                 # modeled bytes (live * rows * page_size) are EXACT —
@@ -812,6 +910,9 @@ class ContinuousBatcher:
         not in the measured stream.  Returns the number of warmup
         dispatches.
 
+        A pattern-generic decode bucket is one bucket for all its
+        patterns, so it warms |ladder| programs once.
+
         Paged mode warms ONE program per queue instead of |ladder| per
         bucket — the activity mask is a traced operand, so a single
         compile covers every occupancy."""
@@ -827,7 +928,7 @@ class ContinuousBatcher:
             b = self._bucket_for(req)
             st = self._staging_for(b)
             for rung in self.ladder:
-                self._execute(b, st.pack((), rung))
+                self._execute(b, st.pack((), rung), self._zero_coef(b, rung))
                 fired += 1
             # seed the service estimator with a timed WARM dispatch of
             # the top rung (the first run above paid the compile, so
@@ -840,8 +941,9 @@ class ContinuousBatcher:
                 self._est[key] = self.service_model(b, self.ladder[-1])
             else:
                 top = st.pack((), self.ladder[-1])
+                coef = self._zero_coef(b, self.ladder[-1])
                 t0 = self.clock.monotonic()
-                self._execute(b, top)
+                self._execute(b, top, coef)
                 self._est[key] = self.clock.monotonic() - t0
                 fired += 1
         self.warmup_dispatches += fired
@@ -850,6 +952,13 @@ class ContinuousBatcher:
                  f"warmed {len(seen)} buckets x {len(self.ladder)} "
                  f"rungs ({fired} dispatches)")
         return fired
+
+    @staticmethod
+    def _zero_coef(b, rung: int):
+        """A warm-up fire's coefficient operand (generic units)."""
+        if not b.generic:
+            return None
+        return np.zeros((rung, b.n_erased, b.rows), np.uint8)
 
     def _warmup_paged(self, requests: List[EcRequest]) -> int:
         """One zero-mask dispatch per distinct queue pays the compile;
@@ -864,14 +973,16 @@ class ContinuousBatcher:
                 continue
             seen.add(key)
             q = self._queue_for(req)
-            self._execute_ragged(q, np.zeros(q.pool.pages, np.uint8))
+            coef = self._zero_coef(q, q.pool.pages)
+            self._execute_ragged(q, np.zeros(q.pool.pages, np.uint8),
+                                 coef)
             fired += 1
             if self.service_model is not None:
                 self._est[key] = self.service_model(q, q.pool.pages)
             else:
                 full = np.ones(q.pool.pages, np.uint8)
                 t0 = self.clock.monotonic()
-                self._execute_ragged(q, full)
+                self._execute_ragged(q, full, coef)
                 self._est[key] = self.clock.monotonic() - t0
                 fired += 1
         self.warmup_dispatches += fired

@@ -167,6 +167,19 @@ def test_ragged_kernel_compiles(one_chip, cap):
     assert "tpu_custom_call" in text
 
 
+@pytest.mark.parametrize("cap", ROW_TILE_CAPS)
+@pytest.mark.parametrize("erased", [1, 2, 3])
+def test_operand_kernel_compiles(one_chip, erased, cap):
+    """The pattern-generic decode kernel at the served shape: a rung of
+    64 stripes of k=8 survivors, 128 KiB chunks, per-stripe
+    coefficients in SMEM."""
+    from ceph_tpu.ops.pallas_gf import apply_matrix_operand_pallas
+    x = _sds(one_chip, (STRIPES, K, CHUNK), jnp.uint8)
+    coef = _sds(one_chip, (STRIPES, erased, K), jnp.uint8)
+    _, text = _compile(apply_matrix_operand_pallas, x, coef, False, cap)
+    assert "tpu_custom_call" in text
+
+
 def test_kernels_compile_with_x64_on(one_chip):
     """A Pallas kernel traced with jax_enable_x64 on (a caller inside
     crush/bulk.py's with_x64 scope, or one that set it globally) must

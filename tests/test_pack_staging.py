@@ -90,9 +90,9 @@ def _spy(batcher):
     launched = []
     real = batcher._launch
 
-    def launch(b, stack):
+    def launch(b, stack, coef=None):
         launched.append(stack.copy())
-        return real(b, stack)
+        return real(b, stack, coef)
     batcher._launch = launch
     return launched
 
@@ -152,28 +152,30 @@ def test_results_do_not_alias_the_staging_buffer(op, executor):
 
 
 def test_buckets_of_one_shape_share_a_buffer():
-    """Two decode patterns with the same row count and chunk size
-    share one buffer; an encode bucket of another row count gets its
-    own; an oversized burst split into top-rung slices stays
+    """Two decode buckets with the same row count and chunk size (one
+    and two erasures: each a pattern-generic bucket of k survivor
+    rows) share one buffer; a repair bucket of another row count gets
+    its own; an oversized burst split into top-rung slices stays
     byte-identical, its short last slice padded with zeros."""
     rng = np.random.default_rng(13)
     batcher = _batcher("host", ladder=(1, 2, 4))
     launched = _spy(batcher)
     lost0 = [_req("decode", rng, RS42, erased=(0,)) for _ in range(3)]
-    lost5 = [_req("decode", rng, RS42, erased=(5,)) for _ in range(2)]
-    enc = [_req("encode", rng, RS42) for _ in range(11)]
-    out = _fire(batcher, lost0) + _fire(batcher, lost5)
-    b0, b5 = (batcher._bucket_for(r[0]) for r in (lost0, lost5))
-    assert b0 is not b5 and b0.rows == b5.rows == 5
+    lost05 = [_req("decode", rng, RS42, erased=(0, 5))
+              for _ in range(2)]
+    rep = [_req("repair", rng, RS42, erased=(5,)) for _ in range(11)]
+    out = _fire(batcher, lost0) + _fire(batcher, lost05)
+    b0, b05 = (batcher._bucket_for(r[0]) for r in (lost0, lost05))
+    assert b0 is not b05 and b0.rows == b05.rows == 4
     assert len(batcher._staging) == 1
-    out += _fire(batcher, enc)
-    assert set(batcher._staging) == {(5, b0.chunk_size),
-                                     (4, b0.chunk_size)}
+    out += _fire(batcher, rep)
+    assert set(batcher._staging) == {(4, b0.chunk_size),
+                                     (5, b0.chunk_size)}
     assert [d["occupancy"] for d in batcher.dispatch_log] == [3, 2, 4, 4,
                                                               3]
     assert [s.shape[0] for s in launched] == [4, 2, 4, 4, 4]
     assert not launched[0][3].any() and not launched[-1][3].any()
-    assert [r.request for r in out] == lost0 + lost5 + enc
+    assert [r.request for r in out] == lost0 + lost05 + rep
     for res in out:
         assert _same(res.output, _reference(res.request))
 
@@ -190,19 +192,20 @@ def test_pack_buffer_counter(registry):
     rng = np.random.default_rng(14)
     batcher = _batcher("host", ladder=(1, 4))
     warm = [_req("decode", rng, RS42, erased=(0,)),
-            _req("decode", rng, RS42, erased=(5,)),
-            _req("encode", rng, RS42)]
+            _req("decode", rng, RS42, erased=(0, 5)),
+            _req("repair", rng, RS42, erased=(5,))]
     batcher.warmup(warm)
     assert _buffers() == {
         "serve_pack_buffers{op=decode,outcome=allocated}": 1,
         "serve_pack_buffers{op=decode,outcome=reused}": 1,
-        "serve_pack_buffers{op=encode,outcome=allocated}": 1}
-    _fire(batcher, [_req("decode", rng, RS42, erased=(5,))
+        "serve_pack_buffers{op=repair,outcome=allocated}": 1}
+    _fire(batcher, [_req("decode", rng, RS42, erased=(0, 5))
                     for _ in range(6)])
-    _fire(batcher, [_req("encode", rng, RS42) for _ in range(2)])
+    _fire(batcher, [_req("repair", rng, RS42, erased=(5,))
+                    for _ in range(2)])
     assert batcher.dispatches == 3
     assert _buffers() == {
         "serve_pack_buffers{op=decode,outcome=allocated}": 1,
         "serve_pack_buffers{op=decode,outcome=reused}": 3,
-        "serve_pack_buffers{op=encode,outcome=allocated}": 1,
-        "serve_pack_buffers{op=encode,outcome=reused}": 1}
+        "serve_pack_buffers{op=repair,outcome=allocated}": 1,
+        "serve_pack_buffers{op=repair,outcome=reused}": 1}
