@@ -47,7 +47,7 @@ import jax.numpy as jnp
 from ..telemetry import metrics as tel
 from ..telemetry.spans import span
 from .hash import crush_hash32_2, crush_hash32_3, crush_hash32_4
-from .ln import crush_ln
+from .ln import CRUSH_LN_TABLE
 from .mapper import crush_do_rule
 from .types import (
     CRUSH_BUCKET_LIST,
@@ -97,7 +97,7 @@ BULK_BLOCK = 1 << 18
 HOST_RESIDUE_LANES = {"cpu": 512, "tpu": 4096}
 
 # negln[u] = 2^48 - crush_ln(u): the straw2 numerator, one gather
-_NEGLN = (1 << 48) - np.asarray(crush_ln(np.arange(0x10000)))
+_NEGLN = (1 << 48) - np.asarray(CRUSH_LN_TABLE, np.int64)
 
 
 def with_x64(fn):
@@ -1311,7 +1311,10 @@ def bulk_do_rule(cmap, ruleno: int, xs, result_max: int,
         cm = CompiledCrushMap(cmap, choose_args)
     if weight is None:
         weight = cm.cmap.device_weights()
-    wv = jnp.asarray(np.asarray(weight, dtype=np.int64))
+    weight = np.asarray(weight, dtype=np.int64)
+    wv = jnp.asarray(weight)
+    # the host walks read it per is_out: one list of ints for every lane
+    host_weight = weight.tolist()
     xs = np.asarray(xs, dtype=np.int64)
     if bulk_tries is None:
         bulk_tries = auto_tries(cm.cmap, ruleno, result_max)
@@ -1346,7 +1349,7 @@ def bulk_do_rule(cmap, ruleno: int, xs, result_max: int,
         c_h = np.empty(len(xs_arr), np.int32)
         for j, x in enumerate(xs_arr):
             r = crush_do_rule(cm.cmap, ruleno, int(x), result_max,
-                              weight=list(weight),
+                              weight=host_weight,
                               choose_args=choose_args)
             o_h[j] = r + [NONE] * (result_max - len(r))
             c_h[j] = len(r)
@@ -1449,7 +1452,7 @@ def bulk_do_rule(cmap, ruleno: int, xs, result_max: int,
         sp.attrs["lanes"] = n_fallback
         for i in redo:
             r = crush_do_rule(cm.cmap, ruleno, int(xs[i]), result_max,
-                              weight=list(weight), choose_args=choose_args)
+                              weight=host_weight, choose_args=choose_args)
             out[i] = r + [NONE] * (result_max - len(r))
             cnt[i] = len(r)
     from ..utils.debug import DeviceVerificationError, verification_enabled
@@ -1457,7 +1460,7 @@ def bulk_do_rule(cmap, ruleno: int, xs, result_max: int,
         # sanitizer mode: every lane re-evaluated on the host oracle
         for i in range(len(xs)):
             r = crush_do_rule(cm.cmap, ruleno, int(xs[i]), result_max,
-                              weight=list(weight),
+                              weight=host_weight,
                               choose_args=choose_args)
             r = r + [NONE] * (result_max - len(r))
             if list(out[i]) != r:

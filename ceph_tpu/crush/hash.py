@@ -1,11 +1,15 @@
 """rjenkins1 32-bit hash — src/crush/hash.{h,c}.
 
 All CRUSH placement randomness flows through crush_hash32_* (hash.c ->
-crush_hash32_rjenkins1_*).  Implemented over uint32 arrays so the SAME
-code runs scalar (0-d numpy), batched (numpy) and on TPU (jax arrays —
-numpy ufunc semantics with uint32 wraparound are identical).  Every
-operation keeps uint32 dtype; wraparound is the semantics, not an
-accident.
+crush_hash32_rjenkins1_*).  Implemented over uint32 arrays so the same
+code runs batched (numpy) and on TPU (jax arrays — numpy ufunc
+semantics with uint32 wraparound are identical).  Every operation keeps
+uint32 dtype; wraparound is the semantics, not an accident.
+
+The ``*_int`` functions are the same hashes on plain Python ints, for
+the scalar host mapper (mapper.py), where a 0-d numpy op costs many
+times an int op.  They share no code with the array versions; tests
+pin the two to each other and to the C reference's known answers.
 """
 
 from __future__ import annotations
@@ -146,4 +150,77 @@ def crush_hash32_5(a, b, c, d, e):
     b, x, h = _mix(b, x, h)  # x as mutated above
     y, c, h = _mix(y, c, h)  # y as mutated above
     d, x, h = _mix(d, x, h)
+    return h
+
+
+# -- plain-int scalar path (host mapper) -------------------------------
+
+_M32 = 0xFFFFFFFF
+
+
+def _mix_int(a, b, c):
+    """crush_hashmix on ints already in [0, 2^32): mask after every
+    subtract and left shift, so each right shift reads a uint32."""
+    a = (a - b - c) & _M32
+    a ^= c >> 13
+    b = (b - c - a) & _M32
+    b ^= (a << 8) & _M32
+    c = (c - a - b) & _M32
+    c ^= b >> 13
+    a = (a - b - c) & _M32
+    a ^= c >> 12
+    b = (b - c - a) & _M32
+    b ^= (a << 16) & _M32
+    c = (c - a - b) & _M32
+    c ^= b >> 5
+    a = (a - b - c) & _M32
+    a ^= c >> 3
+    b = (b - c - a) & _M32
+    b ^= (a << 10) & _M32
+    c = (c - a - b) & _M32
+    c ^= b >> 15
+    return a, b, c
+
+
+def crush_hash32_2_int(a: int, b: int) -> int:
+    """crush_hash32_2 on Python ints (negative ids hash as uint32)."""
+    a &= _M32
+    b &= _M32
+    h = CRUSH_HASH_SEED ^ a ^ b
+    x, y = 231232, 1232
+    a, b, h = _mix_int(a, b, h)
+    x, a, h = _mix_int(x, a, h)
+    b, y, h = _mix_int(b, y, h)
+    return h
+
+
+def crush_hash32_3_int(a: int, b: int, c: int) -> int:
+    """crush_hash32_3 on Python ints (negative ids hash as uint32)."""
+    a &= _M32
+    b &= _M32
+    c &= _M32
+    h = CRUSH_HASH_SEED ^ a ^ b ^ c
+    x, y = 231232, 1232
+    a, b, h = _mix_int(a, b, h)
+    c, x, h = _mix_int(c, x, h)
+    y, a, h = _mix_int(y, a, h)
+    b, x, h = _mix_int(b, x, h)
+    y, c, h = _mix_int(y, c, h)
+    return h
+
+
+def crush_hash32_4_int(a: int, b: int, c: int, d: int) -> int:
+    """crush_hash32_4 on Python ints (negative ids hash as uint32)."""
+    a &= _M32
+    b &= _M32
+    c &= _M32
+    d &= _M32
+    h = CRUSH_HASH_SEED ^ a ^ b ^ c ^ d
+    x, y = 231232, 1232
+    a, b, h = _mix_int(a, b, h)
+    c, d, h = _mix_int(c, d, h)
+    a, x, h = _mix_int(a, x, h)
+    y, b, h = _mix_int(y, b, h)
+    c, x, h = _mix_int(c, x, h)
+    y, d, h = _mix_int(y, d, h)
     return h

@@ -21,8 +21,8 @@ bit, regenerate the diff with scripts and amend — straw2 selection only
 changes where two draws collide within 1 ulp.
 
 Vectorized over numpy or jax uint32/int64 arrays (branch-free CLZ-style
-normalization), so the same function serves the host reference mapper
-and the TPU bulk evaluator.
+normalization) for the TPU bulk evaluator; the scalar host mapper reads
+``CRUSH_LN_TABLE``, the same function tabulated over all 65,536 inputs.
 """
 
 from __future__ import annotations
@@ -31,7 +31,7 @@ from decimal import Decimal, getcontext
 
 import numpy as np
 
-__all__ = ["RH_LH_TBL", "LL_TBL", "crush_ln"]
+__all__ = ["RH_LH_TBL", "LL_TBL", "CRUSH_LN_TABLE", "crush_ln"]
 
 
 def _generate_tables():
@@ -97,3 +97,9 @@ def _crush_ln(xin, xp):
     result = iexpon << (12 + 32)
     result = result + ((lh + ll) >> (48 - 12 - 32))
     return result
+
+
+# crush_ln(u) for every u in [0, 0xffff], as Python ints: the scalar
+# host mapper indexes it per straw2 draw, and bulk.py's device table
+# is built from it
+CRUSH_LN_TABLE = tuple(crush_ln(np.arange(0x10000)).tolist())

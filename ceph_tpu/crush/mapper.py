@@ -16,8 +16,8 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence
 
-from .hash import crush_hash32_2, crush_hash32_3, crush_hash32_4
-from .ln import crush_ln
+from .hash import crush_hash32_2_int, crush_hash32_3_int, crush_hash32_4_int
+from .ln import CRUSH_LN_TABLE
 from .types import (
     CRUSH_BUCKET_LIST,
     CRUSH_BUCKET_STRAW,
@@ -47,14 +47,9 @@ from .types import (
 S64_MIN = -(1 << 63)
 
 
-def _h2(a, b) -> int:
-    return int(crush_hash32_2(a, b))
-
-def _h3(a, b, c) -> int:
-    return int(crush_hash32_3(a, b, c))
-
-def _h4(a, b, c, d) -> int:
-    return int(crush_hash32_4(a, b, c, d))
+_h2 = crush_hash32_2_int
+_h3 = crush_hash32_3_int
+_h4 = crush_hash32_4_int
 
 
 def _div_trunc(a: int, b: int) -> int:
@@ -75,11 +70,18 @@ class _PermWork:
 
 
 class CrushWork:
-    """mapper.c -> crush_init_workspace equivalent."""
+    """mapper.c -> crush_init_workspace equivalent.  A bucket's perm
+    state is made on its first perm choose: only uniform buckets and
+    the firstn local fallback read it."""
 
     def __init__(self, cmap: CrushMap) -> None:
-        self.work: Dict[int, _PermWork] = {
-            bid: _PermWork(b.size) for bid, b in cmap.buckets.items()}
+        self.work: Dict[int, _PermWork] = {}
+
+    def perm(self, bucket: Bucket) -> _PermWork:
+        w = self.work.get(bucket.id)
+        if w is None:
+            w = self.work[bucket.id] = _PermWork(bucket.size)
+        return w
 
 
 def bucket_perm_choose(bucket: Bucket, work: _PermWork, x: int,
@@ -173,7 +175,7 @@ def bucket_straw2_choose(bucket: Bucket, x: int, r: int,
         w = weights[i]
         if w:
             u = _h3(x, ids[i], r) & 0xFFFF
-            ln = int(crush_ln(u)) - 0x1000000000000
+            ln = CRUSH_LN_TABLE[u] - 0x1000000000000
             draw = _div_trunc(ln, w)
         else:
             draw = S64_MIN
@@ -183,12 +185,12 @@ def bucket_straw2_choose(bucket: Bucket, x: int, r: int,
     return bucket.items[high]
 
 
-def crush_bucket_choose(bucket: Bucket, work: _PermWork, x: int, r: int,
+def crush_bucket_choose(bucket: Bucket, work: CrushWork, x: int, r: int,
                         arg: Optional[ChooseArg],
                         position: int) -> int:
     """mapper.c -> crush_bucket_choose dispatch."""
     if bucket.alg == CRUSH_BUCKET_UNIFORM:
-        return bucket_perm_choose(bucket, work, x, r)
+        return bucket_perm_choose(bucket, work.perm(bucket), x, r)
     if bucket.alg == CRUSH_BUCKET_LIST:
         return bucket_list_choose(bucket, x, r)
     if bucket.alg == CRUSH_BUCKET_TREE:
@@ -246,10 +248,10 @@ def crush_choose_firstn(cmap: CrushMap, work: CrushWork, bucket: Bucket,
                             and flocal >= (in_bucket.size >> 1)
                             and flocal > local_fallback_retries):
                         item = bucket_perm_choose(
-                            in_bucket, work.work[in_bucket.id], x, r)
+                            in_bucket, work.perm(in_bucket), x, r)
                     else:
                         item = crush_bucket_choose(
-                            in_bucket, work.work[in_bucket.id], x, r,
+                            in_bucket, work, x, r,
                             choose_args.get(in_bucket.id)
                             if choose_args else None, outpos)
                     if item >= cmap.max_devices:
@@ -341,7 +343,7 @@ def crush_choose_indep(cmap: CrushMap, work: CrushWork, bucket: Bucket,
                 if in_bucket.size == 0:
                     break
                 item = crush_bucket_choose(
-                    in_bucket, work.work[in_bucket.id], x, r,
+                    in_bucket, work, x, r,
                     choose_args.get(in_bucket.id) if choose_args else None,
                     outpos)
                 if item >= cmap.max_devices:

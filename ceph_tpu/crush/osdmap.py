@@ -340,10 +340,12 @@ class OSDMap:
         else:
             raw_arr = np.full((pool.pg_num, pool.size), CRUSH_ITEM_NONE,
                               np.int32)
+            weight = np.asarray(self.osd_weight, np.int64).tolist()
+            choose_args = self._choose_args()
             for i, x in enumerate(pps):
                 r = crush_do_rule(self.crush, pool.crush_rule, int(x),
-                                  pool.size, weight=list(self.osd_weight),
-                                  choose_args=self._choose_args())
+                                  pool.size, weight=weight,
+                                  choose_args=choose_args)
                 raw_arr[i, :len(r)] = r
         return np.asarray(raw_arr, dtype=np.int64), pps
 

@@ -14,7 +14,7 @@ from ceph_tpu.crush import (
     step_emit,
     step_take,
 )
-from ceph_tpu.crush.types import CRUSH_ITEM_NONE
+from ceph_tpu.crush.types import CRUSH_BUCKET_UNIFORM, CRUSH_ITEM_NONE
 
 bulk = pytest.importorskip("ceph_tpu.crush.bulk")
 
@@ -807,6 +807,61 @@ def test_residue_ladder_decides_by_lane_count(monkeypatch):
     out, _, host_deep = bulk.bulk_do_rule(cm, 0, xs, 3, return_stats=True)
     assert host_deep < host and rungs[1] in _ladder_keys(cm)
     np.testing.assert_array_equal(out, ref)
+
+
+@pytest.mark.parametrize("rule", ["chooseleaf_indep", "chooseleaf_firstn"])
+def test_host_residue_walk_matches_mapper(monkeypatch, rule):
+    """Every lane flagged by the device rung walks the host residue,
+    with one weight list shared by all lanes (a numpy reweight vector
+    with a device out), and equals a per-lane crush_do_rule."""
+    import jax
+    import jax.numpy as jnp
+    b, root = build3level(3, 3, 3, seed=7)
+    b.add_rule(0, STEPS[rule](root))
+    weight = np.full(b.map.max_devices, 0x10000, np.int64)
+    weight[4] = 0
+    weight[11] = 0x8000
+    real = bulk._get_jitted
+
+    def flag_every_lane(*args, **kw):
+        jf = real(*args, **kw)
+        return jax.jit(lambda x, w: (lambda o, c, nh: (
+            o, c, jnp.ones_like(nh)))(*jf(x, w)))
+
+    monkeypatch.setattr(bulk, "_get_jitted", flag_every_lane)
+    monkeypatch.setitem(bulk.HOST_RESIDUE_LANES, jax.default_backend(),
+                        4096)
+    xs = np.arange(300)
+    out, cnt, host = bulk.bulk_do_rule(b.map, 0, xs, 5, weight=weight,
+                                       return_stats=True)
+    assert host == len(xs)
+    for x in xs:
+        ref = crush_do_rule(b.map, 0, int(x), 5, weight=list(weight))
+        assert 4 not in ref
+        assert cnt[x] == len(ref)
+        assert list(out[x]) == ref + [CRUSH_ITEM_NONE] * (5 - len(ref))
+
+
+def test_perm_work_made_only_for_perm_buckets():
+    """CrushWork makes a bucket's perm state on its first perm choose:
+    an all-straw2 walk makes none, a mixed map one per uniform bucket
+    it reached, and results do not depend on a shared workspace."""
+    from ceph_tpu.crush.mapper import CrushWork
+    b, root = build3level(2, 2, 3)
+    b.add_rule(0, STEPS["chooseleaf_firstn"](root))
+    work = CrushWork(b.map)
+    for x in range(50):
+        crush_do_rule(b.map, 0, x, 3, work=work)
+    assert work.work == {}
+    b, root = build_uniform_mixed(seed=3)
+    b.add_rule(0, STEPS["chooseleaf_indep"](root))
+    uniform = {bid for bid, bk in b.map.buckets.items()
+               if bk.alg == CRUSH_BUCKET_UNIFORM}
+    work = CrushWork(b.map)
+    for x in range(50):
+        assert crush_do_rule(b.map, 0, x, 3, work=work) == \
+            crush_do_rule(b.map, 0, x, 3)
+    assert set(work.work) == uniform
 
 
 def test_bulk_rule_leaves_32_bit_defaults():

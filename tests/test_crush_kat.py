@@ -19,11 +19,14 @@ import pytest
 from ceph_tpu.crush.hash import (
     crush_hash32,
     crush_hash32_2,
+    crush_hash32_2_int,
     crush_hash32_3,
+    crush_hash32_3_int,
     crush_hash32_4,
+    crush_hash32_4_int,
     crush_hash32_5,
 )
-from ceph_tpu.crush.ln import crush_ln
+from ceph_tpu.crush.ln import CRUSH_LN_TABLE, crush_ln
 from ceph_tpu.crush.mapper import bucket_straw2_choose
 from ceph_tpu.crush.types import CRUSH_BUCKET_STRAW2, Bucket
 
@@ -60,6 +63,60 @@ def test_hash_vectors(vectors):
     assert checked == 6 + 64 * 5
 
 
+# the plain-int scalar hashes the host mapper runs, by vector name
+INT_HASHES = {"h2": (crush_hash32_2_int, crush_hash32_2),
+              "h3": (crush_hash32_3_int, crush_hash32_3),
+              "h4": (crush_hash32_4_int, crush_hash32_4)}
+
+
+@pytest.mark.parametrize("name", sorted(INT_HASHES))
+def test_int_hash_vectors(vectors, name):
+    int_fn = INT_HASHES[name][0]
+    checked = 0
+    for line in vectors:
+        parts = line.split()
+        if parts[0] != name:
+            continue
+        *args, expect = (int(p) for p in parts[1:])
+        got = int_fn(*args)
+        assert type(got) is int and got == expect, (line, got)
+        checked += 1
+    assert checked >= 64
+
+
+@pytest.mark.parametrize("name", sorted(INT_HASHES))
+def test_int_hash_matches_array(name):
+    """The plain-int hash equals the uint32-array hash on seeded random
+    inputs, edge values, negative bucket ids and ints above 2^32 (both
+    hash an int as its low 32 bits)."""
+    int_fn, array_fn = INT_HASHES[name]
+    arity = int(name[1])
+    rng = np.random.default_rng(2700 + arity)
+    edges = [0, 1, 0xFFFFFFFF, 1 << 32, (1 << 32) + 5, (1 << 40) + 7,
+             -1, -2, -110, -(1 << 31)]
+    cols = []
+    for j in range(arity):
+        rand = rng.integers(-(1 << 33), 1 << 34, 2000).tolist()
+        cols.append(edges[j:] + edges[:j] + rand)
+    rows = list(zip(*cols))
+    want = array_fn(*(np.array([v & 0xFFFFFFFF for v in col], np.uint32)
+                      for col in cols))
+    got = [int_fn(*row) for row in rows]
+    assert all(type(h) is int and 0 <= h <= 0xFFFFFFFF for h in got)
+    np.testing.assert_array_equal(np.array(got, np.uint32), want)
+    for row in rows[:50]:  # the array hash masks Python ints itself
+        assert int_fn(*row) == int(array_fn(*row)), row
+
+
+def test_crush_ln_table_matches_vectorised():
+    """CRUSH_LN_TABLE, the scalar mapper's crush_ln, is the vectorised
+    crush_ln at every one of its 65,536 inputs, as Python ints."""
+    assert len(CRUSH_LN_TABLE) == 0x10000
+    assert all(type(v) is int for v in CRUSH_LN_TABLE)
+    np.testing.assert_array_equal(np.array(CRUSH_LN_TABLE, np.int64),
+                                  crush_ln(np.arange(0x10000)))
+
+
 def test_crush_ln_vectors(vectors):
     checked = 0
     for line in vectors:
@@ -68,6 +125,7 @@ def test_crush_ln_vectors(vectors):
             continue
         x, expect = int(parts[1]), int(parts[2])
         assert int(crush_ln(x)) == expect, line
+        assert CRUSH_LN_TABLE[x] == expect, line
         checked += 1
     assert checked >= 0x10000 // 17
 
