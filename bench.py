@@ -54,7 +54,7 @@ REPO = os.path.dirname(os.path.abspath(__file__))
 #   v12 serving/scenario rows carry `tail_attribution`
 #   v13 audit meta carries `lockcheck`
 #   v14 `host_chaos_rows` (host fault-domain loss)
-#   v15 serving rows gain the paged twin (`serving_mixed_paged`)
+#   v15 serving rows gained a page-pool twin row (since removed)
 #   v16 `tenant_week_rows` (multi-tenant compressed week)
 #   v17 audit meta carries `detcheck`
 #   v18 no host-only error line: without a TPU, or when every device
@@ -148,14 +148,6 @@ SERVING_ROWS = [
      ["--workload", "serving", "--device", "jax",
       "--size", str(1 << 16), "--requests", "256",
       "--concurrency", "64", "--seed", "42"]),
-    # v15: the paged twin — same stream through the paged stripe pool
-    # + ragged kernel family (no shape buckets; one program per
-    # (plugin, op) pattern at any occupancy/chunk size).  Its
-    # padding_overhead is the `serving_padding` bench_diff category.
-    ("serving_mixed_paged",
-     ["--workload", "serving", "--device", "jax",
-      "--size", str(1 << 16), "--requests", "256",
-      "--concurrency", "64", "--seed", "42", "--paged"]),
 ]
 
 
@@ -356,7 +348,7 @@ MULTICHIP_ROW_FIELDS = ("n_devices", "mesh_shape", "stripes_per_device",
 SERVING_ROW_FIELDS = ("gbps_under_slo", "deadline_miss_rate",
                       "padding_overhead", "requests", "rejected",
                       "stream_compiles", "tail_attribution",
-                      "paged", "cached_programs", "page_pool")
+                      "cached_programs")
 
 PROFILE_ROW_FIELDS = ("programs", "profile_rows")
 

@@ -206,18 +206,6 @@ GF_XOR_PALLAS_PRIMS = GF_XOR_PRIMS | frozenset({
 # stripe-sharded tier must stay communication-free.
 GF_SHARD_PRIMS = GF_XLA_PRIMS | frozenset({"shard_map", "pad"})
 
-# The paged serving path's ragged programs (ISSUE 18,
-# engine.serve_dispatch_ragged): the page pool + the (pages,) activity
-# mask as a TRACED operand.  The mask gate is a GF multiply by {0,1}
-# (``mul`` is already in the family), so select_n / gather stay
-# DELIBERATELY absent — dynamic page indirection leaking into the
-# program text would be drift worth reviewing.  convert_element_type
-# covers a non-u8 mask dtype arriving at the gate's astype; today's
-# traced set is a strict subset of GF_XLA_PRIMS.
-GF_RAGGED_PRIMS = GF_XLA_PRIMS | frozenset({"convert_element_type"})
-GF_RAGGED_SHARD_PRIMS = GF_RAGGED_PRIMS | frozenset({"shard_map",
-                                                     "pad"})
-
 # CRUSH bulk rule evaluation: straw2 fixed-point draws, rjenkins hash
 # mixing, candidate-grid scans/fixpoints — integer end to end (gather
 # IS expected here: bucket item lookup is genuinely dynamic in x)
@@ -714,61 +702,6 @@ def _build_serve_dispatch() -> Built:
                  serve_dispatch_call)
 
 
-def _build_serve_dispatch_ragged() -> Built:
-    """The paged serving path's ragged device program
-    (engine.serve_dispatch_ragged): ONE jitted program per (plugin,
-    profile, op, pattern) consuming the whole page pool plus the
-    activity mask as a traced operand, so every occupancy AND every
-    co-batched chunk size shares one compile.  Audited at a scattered
-    3-live-page mask — the warm == 0 sentinel plus the masked-stream
-    test in tests/test_serve.py pin the occupancy-independence."""
-    import numpy as np
-
-    from ..codes.engine import serve_dispatch_ragged
-
-    ec = representative_instance("jerasure")
-    k = ec.get_data_chunk_count()
-    pages, page_size = 8, 512
-    fn = serve_dispatch_ragged(ec, "encode", pages=pages,
-                               page_size=page_size)
-    mask = np.zeros(pages, np.uint8)
-    mask[[0, 3, 5]] = 1
-    return Built(fn, (np.zeros((pages, k, page_size), np.uint8), mask),
-                 serve_dispatch_ragged)
-
-
-def _build_serve_dispatch_ragged_sharded() -> Built:
-    """The same ragged program sharded along the PAGE axis (pages are
-    independent mini-chunks, so the page axis is the natural shard
-    axis; padded pages carry a ZERO mask and are dead by
-    construction)."""
-    import numpy as np
-
-    from ..codes.engine import serve_dispatch_ragged
-
-    ec = representative_instance("jerasure")
-    k = ec.get_data_chunk_count()
-    pages, page_size = _SHARD_B, 512
-    fn = serve_dispatch_ragged(ec, "encode", pages=pages,
-                               page_size=page_size,
-                               mesh=_mesh_plane_all())
-    mask = np.zeros(pages, np.uint8)
-    mask[:3] = 1
-    return Built(fn, (np.zeros((pages, k, page_size), np.uint8), mask),
-                 serve_dispatch_ragged)
-
-
-def _build_serve_pool() -> Built:
-    """The paged stripe pool as a host-tier entry: split/join layout
-    round-trips (contiguous + interleaved), free-list alloc/reclaim
-    accounting, backpressure and page-table read-back
-    (serve/pool.py::pool_selftest) — mux/demux is numpy bookkeeping
-    forever: ZERO compiles, zero device arrays."""
-    from ..serve.pool import pool_selftest
-
-    return Built(pool_selftest, (), pool_selftest)
-
-
 def _build_serve_batcher() -> Built:
     """Queue/batcher/SLO bookkeeping as a host-tier entry: a seeded
     closed-loop mini-scenario on a FakeClock with the host executor
@@ -1060,17 +993,6 @@ def registry() -> Tuple[EntryPoint, ...]:
         EntryPoint("serve.dispatch", "serve", "jit",
                    _build_serve_dispatch, allow=GF_XLA_PRIMS,
                    trace_budget=16),
-        # the paged serving path (ISSUE 18): the ragged mask-gated
-        # program (+ its page-axis-sharded twin for the simulated-mesh
-        # gate) and the pool's host-tier mux/demux selftest
-        EntryPoint("serve.dispatch_ragged", "serve", "jit",
-                   _build_serve_dispatch_ragged, allow=GF_RAGGED_PRIMS,
-                   trace_budget=16),
-        EntryPoint("serve.dispatch_ragged_sharded", "serve", "jit",
-                   _build_serve_dispatch_ragged_sharded,
-                   allow=GF_RAGGED_SHARD_PRIMS, trace_budget=16),
-        EntryPoint("serve.pool", "serve", "host",
-                   _build_serve_pool, allow=None, trace_budget=0),
         EntryPoint("serve.batcher", "serve", "host",
                    _build_serve_batcher, allow=None, trace_budget=0),
         # the cluster plane (ISSUE 9): balancer-round + storm-re-eval

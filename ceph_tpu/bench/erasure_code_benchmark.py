@@ -165,20 +165,6 @@ class ErasureCodeBench:
         ap.add_argument("--concurrency", type=int, default=64,
                         help="serving workload: closed-loop in-flight "
                              "window")
-        ap.add_argument("--paged", action="store_true",
-                        help="serving workload: paged stripe pool + "
-                             "ragged kernels — mixed stripe sizes "
-                             "co-batch into one device program per "
-                             "(plugin, op) pattern (no shape buckets, "
-                             "near-zero padding)")
-        ap.add_argument("--page-size", type=int, default=None,
-                        help="serving workload (--paged): pool page "
-                             "size in bytes (default: tuned table, "
-                             "else 512)")
-        ap.add_argument("--pool-pages", type=int, default=None,
-                        help="serving workload (--paged): pages per "
-                             "queue pool (default: tuned table, "
-                             "else 64)")
         ap.add_argument("--osds", type=int, default=1000,
                         help="cluster workload: synthetic cluster "
                              "device count (ClusterSpec.sized; "
@@ -1065,10 +1051,6 @@ class ErasureCodeBench:
                             stripe_size=a.size, erasures=a.erasures,
                             arrival="closed")
         spec.concurrency = a.concurrency
-        if a.paged:
-            spec.paged = True
-            spec.page_size = a.page_size
-            spec.pool_pages = a.pool_pages
         run, tail = self._run_traced(
             lambda: run_serving_scenario(spec, executor=executor))
         bad = verify_results(run.results)
@@ -1085,13 +1067,7 @@ class ErasureCodeBench:
         res["gbps_under_slo"] = rep["gbps_under_slo"]
         res["deadline_miss_rate"] = rep["deadline_miss_rate"]
         res["padding_overhead"] = rep["padding"]["padding_overhead"]
-        res["paged"] = bool(rep["padding"].get("paged", False))
         res["cached_programs"] = rep["padding"].get("cached_programs")
-        if res["paged"]:
-            # live page-pool occupancy + lifetime accounting: after a
-            # clean drain used_pages must be 0 and allocs == reclaims
-            # (the explicit reclaim-on-demux contract)
-            res["page_pool"] = rep["padding"].get("pool")
         res["requests"] = rep["requests"]
         res["rejected"] = rep["rejected"]
         res["dispatches"] = rep["padding"]["dispatches"]

@@ -253,6 +253,77 @@ def _shard_program(raw, plane, n_out: int, n_in: int = 1):
     return fn
 
 
+def _serve_timed(ec, fn, key: tuple, kind: str, pattern: str, seam: str,
+                 plane, host_twin, rebuild, *, op: Optional[str] = None,
+                 series: str = "serve_dispatch",
+                 name: str = "engine.serve_dispatch"):
+    """The eager face of one engine program ``fn(stack, *operands)``
+    (the serve programs and the fused repair): cost-attribution
+    capture and the causal-trace program link, both under ``name``,
+    and the ``series`` dispatch histogram (labelled ``plugin``, plus
+    ``op`` when given) around a supervised dispatch at ``seam``.
+    Tracer inputs mean we are being traced into a larger program:
+    record nothing (a trace-time clock read is fiction) and call
+    ``fn`` straight, so jaxprs stay telemetry-free.  ``key`` is the
+    PatternCache key — the program identity, so two profiles of one
+    plugin class never share an attribution row; ``config`` =
+    tuned|default records whether the program was BUILT under a tuned
+    best-config table (consulted at build time, inside the cached
+    builder, so tuned configs ride the warm path with zero recompiles;
+    installing a table clears the cache, so the label never goes
+    stale)."""
+    import jax
+
+    from ..tune.table import active_source
+    ndev = plane.n_devices if plane is not None else 1
+    prof_key = ("prof",) + key
+    prof_labels = dict(
+        plugin=type(ec).__name__, kind=kind,
+        profile=",".join(f"{pk}={pv}" for pk, pv in
+                         sorted(ec.get_profile().items())),
+        pattern=pattern,
+        engine="mesh" if plane is not None else "device",
+        devices=ndev, config=active_source()[0])
+    rec_labels = dict(plugin=type(ec).__name__)
+    if op is not None:
+        rec_labels["op"] = op
+
+    def timed(*args):
+        eager = not any(isinstance(a, jax.core.Tracer) for a in args)
+        batch = int(args[0].shape[0])
+        prof = _profiler()
+        if eager and tel.enabled():
+            if plane is not None:
+                tel.counter("engine_mesh_dispatches", tier=kind,
+                            devices=str(plane.n_devices))
+            # cost-attribution capture (telemetry/profiler.py): the
+            # first eager dispatch lowers the program once for XLA
+            # cost_analysis — zero backend compiles, so the warm==0
+            # sentinel cannot see it.  Keyed per batch rung: one jit
+            # wrapper serves many stripe-batch shapes, each its own
+            # compiled program
+            pk = prof_key + (batch,)
+            prof.capture(pk, fn, args, name=name, batch=batch,
+                         **prof_labels)
+        else:
+            pk = prof_key
+        if eager and trc.enabled():
+            # causal-trace link: name the EXACT profiler series this
+            # dispatch rides, so a trace's program event joins
+            # attribution_rows() per trace
+            trc.note_program(name, dict(prof_labels, batch=batch))
+        with tel.record_dispatch(series, eager=eager, **rec_labels), \
+                prof.timed(pk, eager=eager):
+            if not eager:
+                return fn(*args)
+            from ..ops.supervisor import global_supervisor
+            return global_supervisor().dispatch(
+                seam, fn, args, host_fn=host_twin, rebuild=rebuild)
+
+    timed._raw = fn
+    return timed
+
+
 def fused_repair_call(ec, available: Tuple[int, ...],
                       erased: Tuple[int, ...], mesh=None):
     """One jitted fn: survivors (B, n_avail, C) uint8 →
@@ -341,130 +412,16 @@ def fused_repair_call(ec, available: Tuple[int, ...],
             return fused_repair_call(ec, available, erased,
                                      mesh=mesh)._raw
 
-        ndev = plane.n_devices if plane is not None else 1
-        # the PatternCache key IS the program identity (class +
-        # profile + kind + pattern + mesh) — reuse it so two profiles
-        # of one plugin class can never share an attribution row.
-        # config records whether this program was BUILT under a tuned
-        # best-config table (ISSUE 14: consultation happens at build
-        # time, inside this cached builder, so tuned configs ride the
-        # warm path with zero recompiles; installing a table clears
-        # this cache, so the label can never go stale)
-        from ..tune.table import active_source
-        prof_key = ("prof",) + key
-        prof_labels = dict(
-            plugin=type(ec).__name__, kind="fused-repair",
-            profile=",".join(f"{pk}={pv}" for pk, pv in
-                             sorted(ec.get_profile().items())),
-            pattern="e" + "_".join(map(str, erased)),
-            engine="mesh" if plane is not None else "device",
-            devices=ndev, config=active_source()[0])
-
-        def timed(stack):
-            # host-side dispatch latency histogram.  Tracer inputs
-            # mean WE are being traced into a larger program — record
-            # nothing (a trace-time clock read is fiction) and leave
-            # the jaxpr telemetry-free by construction.
-            eager = not isinstance(stack, jax.core.Tracer)
-            prof = _profiler()
-            if eager and tel.enabled():
-                if plane is not None:
-                    tel.counter("engine_mesh_dispatches",
-                                tier="fused-repair",
-                                devices=str(plane.n_devices))
-                # cost-attribution capture (telemetry/profiler.py):
-                # first eager dispatch lowers the program once for
-                # XLA cost_analysis — zero backend compiles, so the
-                # warm==0 sentinel cannot see it
-                # keyed per batch rung: one jit wrapper serves many
-                # stripe-batch shapes, each its own compiled program
-                pk = prof_key + (int(stack.shape[0]),)
-                prof.capture(pk, fn, (stack,),
-                             name="engine.fused_repair",
-                             batch=int(stack.shape[0]), **prof_labels)
-            else:
-                pk = prof_key
-            if eager and trc.enabled():
-                # causal-trace link (ISSUE 15): name the EXACT
-                # profiler series this dispatch rides, so a trace's
-                # program event joins attribution_rows() per-trace
-                trc.note_program(
-                    "engine.fused_repair",
-                    dict(prof_labels, batch=int(stack.shape[0])))
-            with tel.record_dispatch(
-                    "engine_fused_repair_dispatch",
-                    eager=eager, plugin=type(ec).__name__), \
-                    prof.timed(pk, eager=eager):
-                if not eager:
-                    return fn(stack)
-                from ..ops.supervisor import global_supervisor
-                return global_supervisor().dispatch(
-                    "engine.fused_repair", fn, (stack,),
-                    host_fn=host_twin, rebuild=rebuild)
-
-        timed._raw = fn
-        return timed
+        return _serve_timed(ec, fn, key, "fused-repair",
+                            "e" + "_".join(map(str, erased)),
+                            "engine.fused_repair", plane, host_twin,
+                            rebuild, series="engine_fused_repair_dispatch",
+                            name="engine.fused_repair")
 
     return global_pattern_cache().get_or_build(key, build)
 
 
 # -- serving dispatch seam (serve/batcher.py's one device call) ---------
-
-def _serve_timed(ec, fn, key: tuple, kind: str, pattern: str, seam: str,
-                 op: str, plane, host_twin, rebuild):
-    """The eager face of one serve program ``fn(stack, *operands)``:
-    cost-attribution capture, the causal-trace program link and the
-    ``serve_dispatch`` histogram around a supervised dispatch (tracer
-    inputs record nothing and call ``fn`` straight, so jaxprs stay
-    telemetry-free).  ``key`` is the PatternCache key — the program
-    identity, so two profiles of one plugin class never share an
-    attribution row; ``config`` = tuned|default records which config
-    regime BUILT the program (see fused_repair_call)."""
-    import jax
-
-    from ..tune.table import active_source
-    ndev = plane.n_devices if plane is not None else 1
-    prof_key = ("prof",) + key
-    prof_labels = dict(
-        plugin=type(ec).__name__, kind=kind,
-        profile=",".join(f"{pk}={pv}" for pk, pv in
-                         sorted(ec.get_profile().items())),
-        pattern=pattern,
-        engine="mesh" if plane is not None else "device",
-        devices=ndev, config=active_source()[0])
-
-    def timed(*args):
-        eager = not any(isinstance(a, jax.core.Tracer) for a in args)
-        batch = int(args[0].shape[0])
-        prof = _profiler()
-        if eager and tel.enabled():
-            if plane is not None:
-                tel.counter("engine_mesh_dispatches", tier=kind,
-                            devices=str(plane.n_devices))
-            # keyed per batch rung: one jit wrapper serves many
-            # stripe-batch shapes, each its own compiled program
-            pk = prof_key + (batch,)
-            prof.capture(pk, fn, args, name="engine.serve_dispatch",
-                         batch=batch, **prof_labels)
-        else:
-            pk = prof_key
-        if eager and trc.enabled():
-            # causal-trace link: see fused_repair_call
-            trc.note_program("engine.serve_dispatch",
-                             dict(prof_labels, batch=batch))
-        with tel.record_dispatch(
-                "serve_dispatch", eager=eager,
-                op=op, plugin=type(ec).__name__), \
-                prof.timed(pk, eager=eager):
-            if not eager:
-                return fn(*args)
-            from ..ops.supervisor import global_supervisor
-            return global_supervisor().dispatch(
-                seam, fn, args, host_fn=host_twin, rebuild=rebuild)
-
-    timed._raw = fn
-    return timed
-
 
 def serve_dispatch_call(ec, op: str, available: Tuple[int, ...] = (),
                         erased: Tuple[int, ...] = (), mesh=None):
@@ -536,8 +493,8 @@ def serve_dispatch_call(ec, op: str, available: Tuple[int, ...] = (),
 
         return _serve_timed(ec, fn, key, f"serve-{op}",
                             "e" + "_".join(map(str, erased)),
-                            f"engine.serve-{op}", op, plane, host_twin,
-                            rebuild)
+                            f"engine.serve-{op}", plane, host_twin,
+                            rebuild, op=op)
 
     return global_pattern_cache().get_or_build(key, build)
 
@@ -571,10 +528,10 @@ def decode_table(ec, patterns, index, rung: int):
     the pattern-generic decode: row ``i`` is the decode rows
     (``ec.decode_rows``) of ``patterns[index[i]]``, each pattern an
     ``(available[:k], erased)`` pair; rows whose index is negative or
-    past ``len(index)`` (pad stripes, dead pages) are zero.  Each
-    pattern's rows come from the bounded process PatternCache,
-    inverted on a miss (``engine_decode_matrix{outcome=hit|miss}``,
-    one per pattern); span ``engine.decode_table``."""
+    past ``len(index)`` (pad stripes) are zero.  Each pattern's rows
+    come from the bounded process PatternCache, inverted on a miss
+    (``engine_decode_matrix{outcome=hit|miss}``, one per pattern);
+    span ``engine.decode_table``."""
     import numpy as np
 
     from ..telemetry import span
@@ -635,233 +592,6 @@ def serve_decode_generic(ec, n_erased: int, mesh=None):
 
         return _serve_timed(ec, fn, key, "serve-decode-generic",
                             f"n{int(n_erased)}", "engine.serve-decode",
-                            "decode", plane, host_twin, rebuild)
-
-    return global_pattern_cache().get_or_build(key, build)
-
-
-# -- ragged paged serving dispatch (ISSUE 18) ---------------------------
-
-def _shard_program_ragged(raw, plane, n_out: int):
-    """Mesh variant of a ragged (pool, mask) body: the PAGE axis is
-    the sharded axis (pages are independent mini-chunks, so they fan
-    out like stripes), the mask sharded alongside, matrices
-    replicated.  Non-dividing pools zero-pad pages with a ZERO mask —
-    dead by construction, so the pad computes zeros and is sliced
-    off."""
-    import jax
-    import jax.numpy as jnp
-
-    from ..parallel.plane import single_device
-    from ..utils.shard import batch_spec, shard_map_compat
-
-    ndev = plane.n_devices
-    spec3 = batch_spec(plane.axis, 3)
-    spec1 = batch_spec(plane.axis, 1)
-
-    def body(local_pool, local_mask):
-        with single_device():
-            return raw(local_pool, local_mask)
-
-    sharded = shard_map_compat(
-        body, plane.mesh, in_specs=(spec3, spec1),
-        out_specs=tuple([spec3] * n_out) if n_out > 1 else spec3)
-
-    @jax.jit
-    def fn(pool, mask):
-        p = pool.shape[0]
-        pad = (-p) % ndev
-        if pad:
-            pool = jnp.pad(pool, ((0, pad), (0, 0), (0, 0)))
-            mask = jnp.pad(mask, ((0, pad),))
-        out = sharded(pool, mask)
-        if not pad:
-            return out
-        if n_out == 1:
-            return out[:p]
-        return tuple(o[:p] for o in out)
-
-    return fn
-
-
-def _ragged_surface(ec, op: str):
-    """The plugin's true ragged surface when it has one (matrix /
-    bitmatrix / clay composite families), else None — the generic
-    mask-gate body runs instead, byte-identically."""
-    return getattr(type(ec), f"{op}_chunks_ragged_jax", None)
-
-
-def serve_dispatch_ragged(ec, op: str, available: Tuple[int, ...] = (),
-                          erased: Tuple[int, ...] = (), *,
-                          pages: int, page_size: int, mesh=None):
-    """ONE cached, jitted ragged program per (plugin, profile, op,
-    erasure pattern, pool geometry): the paged batcher's device seam
-    (serve/pool.py stages the pool; serve/batcher.py fires it here).
-
-    The program signature is ``(pool, mask)`` — pool
-    ``(pages, rows, page_size)`` uint8, mask ``(pages,)`` {0,1} — and
-    the mask is a TRACED operand: every occupancy of the pool runs
-    the SAME compiled program, so the cached-program count for a
-    serving day is |patterns|, not |buckets| x |ladder| (the dense
-    ladder's per-rung programs).  Dead pages compute zeros in every
-    tier (GF linearity), so demux never reads them.
-
-    - ``encode``: pool pages are (k, page_size) mini-chunks -> parity
-      pages (pages, m, page_size)
-    - ``decode``: survivor pages -> (pages, n_erased, page_size)
-    - ``repair``: the fused decode -> column-assembly -> re-encode of
-      fused_repair_call, on the masked page batch -> (rec, parity)
-
-    On TPU backends the pool operand is DONATED: steady-state serving
-    re-uses the previous fire's HBM pages instead of allocating per
-    dispatch (CPU/GPU skip donation — XLA:CPU would warn and copy).
-    With an active data plane the program shards the PAGE axis
-    (pages are independent mini-chunks) under a mesh-suffixed key in
-    the same PatternCache keyspace."""
-    import jax
-
-    if op not in ("encode", "decode", "repair"):
-        raise ValueError(f"serve op {op!r} must be encode|decode|repair")
-    available = tuple(available)
-    erased = tuple(erased)
-    plane = _resolve_mesh(mesh)
-    extra = ("paged", int(pages), int(page_size))
-    if plane is not None:
-        extra += ("mesh", plane.n_devices)
-    key = pattern_key(ec, f"serve-{op}-ragged", available, erased,
-                      extra)
-
-    def build():
-        import jax.numpy as jnp
-
-        from ..ops.pallas_gf import mask_pages
-
-        if op == "repair":
-            from .stripe import _chunk_mapping
-            mapping = _chunk_mapping(ec)
-            k = ec.get_data_chunk_count()
-            aidx = {s: t for t, s in enumerate(available)}
-            eidx = {s: t for t, s in enumerate(erased)}
-            src = []
-            for c in range(k):
-                shard = mapping[c]
-                if shard in aidx:
-                    src.append(("avail", aidx[shard]))
-                elif shard in eidx:
-                    src.append(("rec", eidx[shard]))
-                else:
-                    raise IOError(
-                        f"data shard {shard} neither available nor "
-                        f"erased in pattern (avail={available}, "
-                        f"erased={erased})")
-
-        dec = _ragged_surface(ec, "decode")
-        enc = _ragged_surface(ec, "encode")
-
-        def raw(pool, mask):
-            if op == "encode":
-                if enc is not None:
-                    return enc(ec, pool, mask)
-                return ec.encode_chunks_jax(mask_pages(pool, mask))
-            if op == "decode":
-                if dec is not None:
-                    return dec(ec, pool, mask, available, erased)
-                return ec.decode_chunks_jax(mask_pages(pool, mask),
-                                            available, erased)
-            # repair: the fused_repair_call body on the page batch —
-            # survivors mask-gated ONCE so the column assembly and
-            # the re-encode see zeros on dead pages
-            x = mask_pages(pool, mask)
-            with jax.named_scope("serve_ragged.decode"):
-                if dec is not None:
-                    rec = dec(ec, pool, mask, available, erased)
-                else:
-                    rec = ec.decode_chunks_jax(x, available, erased)
-            cols = [x[:, t, :] if where == "avail" else rec[:, t, :]
-                    for where, t in src]
-            data = jnp.stack(cols, axis=1)
-            with jax.named_scope("serve_ragged.reencode"):
-                parity = ec.encode_chunks_jax(data)
-            return rec, parity
-
-        n_out = 2 if op == "repair" else 1
-        if plane is not None:
-            fn = _shard_program_ragged(raw, plane, n_out=n_out)
-        elif jax.default_backend() == "tpu":
-            # donate the pool's HBM buffer forward (see docstring);
-            # the mask is tiny and NOT donated (the batcher re-reads
-            # it for demux bookkeeping)
-            fn = jax.jit(raw, donate_argnums=(0,))
-        else:
-            fn = jax.jit(raw)
-
-        # supervised-dispatch couplings (ops/supervisor.py): numpy
-        # ground truth = zero the dead pages, then the same batch
-        # surfaces the dense host twin runs (byte-identical pinned in
-        # tests/test_serve.py); rebuild re-derives the program after
-        # a tier demotion / plane reshrink
-        def host_twin(pool, mask):
-            import numpy as np
-            x = np.asarray(pool) * (np.asarray(mask) != 0).astype(
-                np.uint8)[:, None, None]
-            if op == "encode":
-                return np.asarray(ec.encode_chunks_batch(x))
-            if op == "decode":
-                return np.asarray(ec.decode_chunks_batch(
-                    x, available, erased))
-            from ..serve.batcher import _host_repair
-            return _host_repair(ec, x, available, erased)
-
-        def rebuild():
-            return serve_dispatch_ragged(
-                ec, op, available, erased, pages=pages,
-                page_size=page_size, mesh=mesh)._raw
-
-        ndev = plane.n_devices if plane is not None else 1
-        from ..tune.table import active_source
-        prof_key = ("prof",) + key
-        prof_labels = dict(
-            plugin=type(ec).__name__, kind=f"serve-{op}-ragged",
-            profile=",".join(f"{pk}={pv}" for pk, pv in
-                             sorted(ec.get_profile().items())),
-            pattern="e" + "_".join(map(str, erased)),
-            engine="mesh" if plane is not None else "device",
-            devices=ndev, config=active_source()[0])
-
-        def timed(pool, mask):
-            # same trace-eagerness discipline as serve_dispatch_call
-            eager = not (isinstance(pool, jax.core.Tracer)
-                         or isinstance(mask, jax.core.Tracer))
-            prof = _profiler()
-            if eager and tel.enabled():
-                if plane is not None:
-                    tel.counter("engine_mesh_dispatches",
-                                tier=f"serve-{op}-ragged",
-                                devices=str(plane.n_devices))
-                # ONE program per pattern: the profiler key carries
-                # the (static) pool page count, not a rung
-                pk = prof_key + (int(pool.shape[0]),)
-                prof.capture(pk, fn, (pool, mask),
-                             name="engine.serve_dispatch_ragged",
-                             batch=int(pool.shape[0]), **prof_labels)
-            else:
-                pk = prof_key
-            if eager and trc.enabled():
-                trc.note_program(
-                    "engine.serve_dispatch_ragged",
-                    dict(prof_labels, batch=int(pool.shape[0])))
-            with tel.record_dispatch(
-                    "serve_dispatch_ragged", eager=eager,
-                    op=op, plugin=type(ec).__name__), \
-                    prof.timed(pk, eager=eager):
-                if not eager:
-                    return fn(pool, mask)
-                from ..ops.supervisor import global_supervisor
-                return global_supervisor().dispatch(
-                    f"engine.serve-{op}-ragged", fn, (pool, mask),
-                    host_fn=host_twin, rebuild=rebuild)
-
-        timed._raw = fn
-        return timed
+                            plane, host_twin, rebuild, op="decode")
 
     return global_pattern_cache().get_or_build(key, build)

@@ -633,46 +633,6 @@ class ErasureCodeClay(ErasureCode):
         y = apply_matrix_best(x, ms, W)
         return y.reshape(b, len(erased), chunk)
 
-    # -- ragged paged surfaces (ISSUE 18: serve/pool.py page pools) ------
-    #
-    # Clay's coupling spans ALL sub_chunk_no sub-chunks of a chunk at
-    # one intra-sub-chunk byte offset, so a contiguous column split
-    # would cut codewords apart.  page_interleave() makes the pool's
-    # split take column slices of EVERY sub-chunk (serve/pool.py::
-    # split_pages views the chunk as (sub, sc)), so each page IS a
-    # valid clay chunk of size page_size — and the composite-matrix
-    # surfaces below then run the true ragged kernels on the page
-    # batch, dead pages zero.
-
-    def page_unit(self) -> int:
-        return self.sub_chunk_no
-
-    def page_interleave(self) -> int:
-        return self.sub_chunk_no
-
-    def encode_chunks_ragged_jax(self, pool, mask):
-        """(P, k, page_size) pool + (P,) mask -> (P, m, page_size)
-        parity, dead pages zero (composite matrix, ragged family)."""
-        from ...ops.pallas_gf import apply_matrix_best_ragged
-        _, ms = self._encode_composite()
-        p, k, ps = pool.shape
-        sub = self.sub_chunk_no
-        x = pool.reshape(p, k * sub, ps // sub)
-        y = apply_matrix_best_ragged(x, ms, mask, W)
-        return y.reshape(p, self.m, ps)
-
-    def decode_chunks_ragged_jax(self, pool, mask, available: tuple,
-                                 erased: tuple):
-        """(P, n_avail, page_size) pool + (P,) mask ->
-        (P, n_erased, page_size), dead pages zero."""
-        from ...ops.pallas_gf import apply_matrix_best_ragged
-        _, ms = self._decode_composite(tuple(available), tuple(erased))
-        p, na, ps = pool.shape
-        sub = self.sub_chunk_no
-        x = pool.reshape(p, na * sub, ps // sub)
-        y = apply_matrix_best_ragged(x, ms, mask, W)
-        return y.reshape(p, len(erased), ps)
-
     # -- packed resident layout (ops/pallas_gf.py pack_chunks form) ------
 
     def _packed_subsplit(self, rows: int) -> int:

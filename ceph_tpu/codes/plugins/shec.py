@@ -242,29 +242,6 @@ class ErasureCodeShec(MatrixCodeMixin, ErasureCode):
         out = jax_bytes_view(out)
         return take_static(out, [worder[c] for c in erased], axis=1)
 
-    def decode_chunks_ragged_jax(self, pool, mask, available: tuple,
-                                 erased: tuple):
-        """Page-pool minimum-read decode: (P, n_avail, page_size)
-        survivors + (P,) activity mask -> (P, n_erased, page_size),
-        dead pages zero.  Overrides the mixin's ragged path — the
-        plain decode-matrix inversion there is singular for shec
-        survivor patterns; every shec decode goes through the
-        minimum-read plan, ragged included."""
-        from ...ops.pallas_gf import apply_matrix_best_ragged
-        from ...ops.xla_ops import (jax_bytes_view, jax_words_view,
-                                    take_static)
-        plan = self.tcache.get_plan(self.matrix, self.k, self.w,
-                                    frozenset(available), frozenset(erased))
-        aidx = {c: t for t, c in enumerate(available)}
-        sel = [aidx[c] for c in plan.reads]
-        worder = {c: t for t, c in enumerate(plan.want_order)}
-        _, dm_static, _ = self._plan_static(plan)
-        sub = take_static(pool, sel, axis=1)
-        words = jax_words_view(sub, self.w)
-        out = apply_matrix_best_ragged(words, dm_static, mask, self.w)
-        out = jax_bytes_view(out)
-        return take_static(out, [worder[c] for c in erased], axis=1)
-
     def decode_chunks_packed_jax(self, words, available: tuple,
                                  erased: tuple):
         """Packed-layout minimum-read decode: (batch, n_avail, R, 128)

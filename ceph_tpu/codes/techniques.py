@@ -177,36 +177,6 @@ class MatrixCodeMixin:
         from ..ops.pallas_gf import host_apply_operand
         return host_apply_operand(np.asarray(chunks)[:, :self.k], coef)
 
-    # -- ragged paged surfaces (ISSUE 18: serve/pool.py page pools) ------
-
-    def page_unit(self) -> int:
-        """Page-size quantum for the paged serving pool: pages must
-        hold whole GF(2^w) field elements so the word views stay free
-        (matrix-code column locality is element-granular)."""
-        return max(1, self.w // 8)
-
-    def encode_chunks_ragged_jax(self, pool, mask):
-        """Page-pool encode: (P, k, page_size) uint8 pool + (P,) {0,1}
-        activity mask -> (P, m, page_size) parity, dead pages zero.
-        The TRUE ragged kernel family (ops/pallas_gf.py) — the mask is
-        a traced operand, so one program serves every occupancy."""
-        from ..ops.pallas_gf import apply_matrix_best_ragged
-        words = jax_words_view(pool, self.w)
-        return jax_bytes_view(apply_matrix_best_ragged(
-            words, self._matrix_static, mask, self.w))
-
-    def decode_chunks_ragged_jax(self, pool, mask, available: tuple,
-                                 erased: tuple):
-        """Page-pool decode: (P, n_avail, page_size) survivors + mask
-        -> (P, n_erased, page_size), dead pages zero."""
-        if len(available) < self.k:
-            raise IOError(f"need {self.k} chunks, have {len(available)}")
-        from ..ops.pallas_gf import apply_matrix_best_ragged
-        _, dm_static, ns = self._decode_matrix(tuple(available), tuple(erased))
-        words = jax_words_view(pool[..., :ns, :], self.w)
-        return jax_bytes_view(apply_matrix_best_ragged(
-            words, dm_static, mask, self.w))
-
     # -- packed resident layout (ops/pallas_gf.py pack_chunks form) ------
 
     def encode_chunks_packed_jax(self, words):
@@ -320,34 +290,3 @@ class BitmatrixCodeMixin:
                                                   tuple(erased))
         return apply_bitmatrix_best(chunks[..., :ns, :], dm_static, self.w,
                                     self.packetsize)
-
-    # -- ragged paged surfaces (ISSUE 18) --------------------------------
-
-    def page_unit(self) -> int:
-        """Bitmatrix codes mix across the w packets of one
-        w*packetsize block but never across blocks — the block is the
-        column-locality quantum, so every pool page must hold whole
-        blocks."""
-        return self.w * self.packetsize
-
-    def encode_chunks_ragged_jax(self, pool, mask):
-        """Page-pool bitmatrix encode: mask-gate the pool (pure GF
-        scaling, see ops/pallas_gf.py::mask_pages) and run the packet
-        kernel family on the page batch — dead pages zero by XOR
-        linearity."""
-        from ..ops.pallas_gf import mask_pages
-        return apply_bitmatrix_best(mask_pages(pool, mask),
-                                    self._bitmatrix_static, self.w,
-                                    self.packetsize)
-
-    def decode_chunks_ragged_jax(self, pool, mask, available: tuple,
-                                 erased: tuple):
-        """Page-pool bitmatrix decode, dead pages zero."""
-        if len(available) < self.k:
-            raise IOError(f"need {self.k} chunks, have {len(available)}")
-        from ..ops.pallas_gf import mask_pages
-        _, dm_static, ns = self._decode_bitmatrix(tuple(available),
-                                                  tuple(erased))
-        return apply_bitmatrix_best(
-            mask_pages(pool[..., :ns, :], mask), dm_static, self.w,
-            self.packetsize)

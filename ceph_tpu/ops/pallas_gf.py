@@ -1201,320 +1201,6 @@ def apply_bitmatrix_best(chunks: jax.Array, bitmatrix_rows, w: int,
     return apply_bitmatrix_xla(chunks, bitmatrix_rows, w, packetsize)
 
 
-# -- ragged paged family (ISSUE 18) --------------------------------------
-#
-# The paged serving path (serve/pool.py + codes/engine.py ::
-# serve_dispatch_ragged) co-batches requests of DIFFERENT stripe sizes
-# into one fixed-shape page pool (P, s, page_size) plus a per-fire
-# (P,) activity mask: page p is live when some request's page table
-# points at it, dead when it sits on the pool free list (dead pages
-# carry stale bytes — reclaim does not scrub).  The kernels below are
-# the ragged twins of the dense matrix family: they walk the mask
-# instead of a dense padded batch, and EVERY tier writes zeros for
-# dead pages, so the three tiers (Pallas page-skip, masked XLA, numpy
-# active-page walk) are byte-identical by construction — GF(2^w)
-# matrix applies are linear, so zero pages in means zero pages out.
-#
-# - "pallas": the mask rides the scalar-prefetch channel (SMEM) and
-#   the grid's page dimension predicates on it with pl.when — a dead
-#   page costs one zero-fill store, not an xtime/XOR schedule.
-# - "mask":   multiply the pool by the {0,1} mask (pure GF scaling —
-#   no select/gather primitives, so the jaxpr stays inside the GF
-#   allowlist family) and run the DENSE engine-selection table on the
-#   result; the tier for backends without Mosaic and for shapes the
-#   Pallas gates decline.
-# - "numpy":  gather the live pages, run the host ground truth on
-#   them alone, scatter into a zeroed output.
-
-RAGGED_MIN_PAGES = 2
-
-
-def tuned_ragged_cutover() -> int:
-    """The ragged-cutover consultation seam: minimum pool page count
-    for the page-skipping Pallas kernel from the installed best-config
-    table (kind ``ragged-cutover``), else RAGGED_MIN_PAGES.  Below the
-    cutover the mask tier runs — byte-identical, so a tuned value
-    moves only WHERE dead pages are skipped."""
-    from ..tune.table import consult
-    cfg = consult("ragged-cutover")
-    if cfg:
-        v = cfg.get("min_pages")
-        if isinstance(v, int) and not isinstance(v, bool) and v >= 1:
-            return v
-    return RAGGED_MIN_PAGES
-
-
-def _gf8_ragged_kernel(matrix_t, s: int, r: int, interpret: bool,
-                       packed: bool = False):
-    """Ragged w=8 kernel body: the dense specialized body under a
-    pl.when on this grid step's page-mask word (scalar-prefetch ref —
-    index 0 of the kernel args).  Dead pages write zeros so every
-    tier agrees byte-for-byte."""
-    dense = _gf8_matrix_kernel(matrix_t, s, r, interpret, packed)
-
-    def kernel(mask_ref, in_ref, out_ref):
-        live = mask_ref[pl.program_id(0)] != 0
-
-        @pl.when(live)
-        def _run():
-            dense(in_ref, out_ref)
-
-        @pl.when(jnp.logical_not(live))
-        def _zero():
-            zero = jnp.zeros_like(in_ref[0, 0])
-            for i in range(r):
-                out_ref[0, i] = zero
-
-    return kernel
-
-
-def pallas_matrix_ragged_supported(shape, w: int) -> bool:
-    """Pool-shape gate for the ragged Pallas kernels: the dense
-    padded gate plus a leading page axis."""
-    return (len(shape) == 3
-            and pallas_matrix_padded_supported(shape, w))
-
-
-@functools.partial(jax.jit, static_argnums=(1, 3, 4))
-def apply_matrix_pallas_ragged(pool: jax.Array, matrix_t,
-                               mask: jax.Array,
-                               interpret: bool = False,
-                               row_tile_cap: int | None = None
-                               ) -> jax.Array:
-    """Apply a static (r, s) GF(2^8) matrix to a page pool
-    (P, s, page_size) uint8 under a (P,) activity mask ->
-    (P, r, page_size), dead pages zero.  The mask is a TRACED operand
-    (scalar-prefetch), so one compiled program serves every occupancy
-    of the pool — the paged serving path's zero-recompile contract."""
-    r = len(matrix_t)
-    s = len(matrix_t[0])
-    assert pool.ndim == 3 and pool.shape[1] == s
-    assert pool.dtype == jnp.uint8
-    p, _, c = pool.shape
-    rows = c // LANE
-    tiles = pool.reshape(p, s, rows, LANE)
-    pad = (-rows) % SUBLANE_U8
-    if pad:
-        tiles = jnp.pad(tiles, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    prows = rows + pad
-    rt = _row_tile8(prows, row_tile_cap)
-    out = _pallas_call(
-        _gf8_ragged_kernel(matrix_t, s, r, interpret),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(p, prows // rt),
-            in_specs=[pl.BlockSpec((1, s, rt, LANE),
-                                   lambda i, j, m: (i, 0, j, 0))],
-            out_specs=pl.BlockSpec((1, r, rt, LANE),
-                                   lambda i, j, m: (i, 0, j, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((p, r, prows, LANE), jnp.uint8),
-        interpret=interpret,
-    )(mask.astype(jnp.int32), tiles)
-    if pad:
-        out = out[..., :rows, :]
-    return out.reshape(p, r, c)
-
-
-@functools.partial(jax.jit, static_argnums=(1, 3, 4))
-def apply_matrix_pallas_packed_ragged(words: jax.Array, matrix_t,
-                                      mask: jax.Array,
-                                      interpret: bool = False,
-                                      row_tile_cap: int | None = None
-                                      ) -> jax.Array:
-    """Packed-layout ragged apply: (P, s, R, 128) uint32 pool under a
-    (P,) mask -> (P, r, R, 128), dead pages zero — the resident-word
-    twin of apply_matrix_pallas_ragged."""
-    r = len(matrix_t)
-    s = len(matrix_t[0])
-    assert words.ndim == 4 and words.shape[1] == s
-    assert words.dtype == jnp.uint32 and words.shape[-1] == LANE
-    p, _, rows, _ = words.shape
-    tiles = words
-    pad = (-rows) % SUBLANE_U32
-    if pad:
-        tiles = jnp.pad(tiles, ((0, 0), (0, 0), (0, pad), (0, 0)))
-    prows = rows + pad
-    rt = _row_tile8(prows * 4, row_tile_cap) // 4
-    if rt == 0 or prows % rt:
-        rt = prows
-    out = _pallas_call(
-        _gf8_ragged_kernel(matrix_t, s, r, interpret, packed=True),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1,
-            grid=(p, prows // rt),
-            in_specs=[pl.BlockSpec((1, s, rt, LANE),
-                                   lambda i, j, m: (i, 0, j, 0))],
-            out_specs=pl.BlockSpec((1, r, rt, LANE),
-                                   lambda i, j, m: (i, 0, j, 0)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((p, r, prows, LANE), jnp.uint32),
-        interpret=interpret,
-    )(mask.astype(jnp.int32), tiles)
-    if pad:
-        out = out[..., :rows, :]
-    return out
-
-
-def mask_pages(pool: jax.Array, mask: jax.Array) -> jax.Array:
-    """Zero the dead pages of a pool by multiplying with the {0,1}
-    mask — the ragged family's XLA-tier gate.  A multiply, not a
-    select: GF region values are bytes, so scaling by 0/1 IS the page
-    predicate, and the jaxpr stays select_n/gather-free (the ragged
-    audit allowlist pins it)."""
-    m = mask.astype(pool.dtype)
-    return pool * m.reshape((pool.shape[0],) + (1,) * (pool.ndim - 1))
-
-
-def select_ragged_engine(shape, matrix_t, w: int = 8,
-                         packed: bool = False,
-                         engine: str | None = None) -> str:
-    """Engine table for the ragged paged family — the dense table
-    (select_matrix_engine, mesh tier excluded: page sharding happens
-    one level up in codes/engine.py::serve_dispatch_ragged) projected
-    onto the three ragged tiers:
-
-    - "pallas": the page-skipping kernel — dense table picked the
-      Pallas kernel for this pool shape AND the pool has at least
-      tuned_ragged_cutover() pages (below it the predicate overhead
-      cannot pay for itself).
-    - "mask":   mask-multiply + the dense tier on the product (any
-      backend, any shape; the dense tier re-selects inside).
-    - "numpy":  the fallback policy floored to host — the active-page
-      walk (callers must not dispatch through jax at all)."""
-    inner = select_matrix_engine(shape, matrix_t, w, packed=packed,
-                                 engine=engine, mesh=0)
-    if inner == "numpy":
-        return "numpy"
-    if inner == "pallas" and shape[0] >= tuned_ragged_cutover():
-        return "pallas"
-    return "mask"
-
-
-def _run_matrix_bytes_ragged(pool: jax.Array, matrix_t, w: int,
-                             mask: jax.Array, eng: str) -> jax.Array:
-    """Execute ONE ragged tier on a byte-layout pool (the dispatch
-    body of apply_matrix_best_ragged)."""
-    if eng == "pallas":
-        return apply_matrix_pallas_ragged(pool, matrix_t, mask,
-                                          row_tile_cap=
-                                          tuned_row_tile_cap(False))
-    x = mask_pages(pool, mask)
-    inner = select_matrix_engine(x.shape, matrix_t, w, mesh=0)
-    if inner == "numpy":
-        inner = "xla"
-    return _run_matrix_bytes(x, matrix_t, w, inner)
-
-
-def _run_matrix_packed_ragged(words: jax.Array, matrix_t,
-                              mask: jax.Array, eng: str) -> jax.Array:
-    """Packed-layout twin of _run_matrix_bytes_ragged."""
-    if eng == "pallas":
-        return apply_matrix_pallas_packed_ragged(
-            words, matrix_t, mask,
-            row_tile_cap=tuned_row_tile_cap(True))
-    x = mask_pages(words, mask)
-    inner = select_matrix_engine(x.shape, matrix_t, 8, packed=True,
-                                 mesh=0)
-    if inner == "numpy":
-        inner = "xla"
-    return _run_matrix_packed(x, matrix_t, inner)
-
-
-def _host_apply_bytes_ragged(pool, matrix_t, mask):
-    """Numpy ground-truth twin of the ragged byte dispatch: walk the
-    LIVE pages only (the host tier genuinely skips dead pages — same
-    work profile as the Pallas predicate), scatter into zeros."""
-    arr = np.asarray(pool)
-    live = np.asarray(mask) != 0
-    r = len(matrix_t)
-    out = np.zeros((arr.shape[0], r, arr.shape[-1]), np.uint8)
-    if live.any():
-        out[live] = _host_apply_bytes(arr[live], matrix_t)
-    return out
-
-
-def _host_apply_packed_ragged(words, matrix_t, mask):
-    """Packed-layout twin of _host_apply_bytes_ragged."""
-    arr = np.asarray(words)
-    live = np.asarray(mask) != 0
-    r = len(matrix_t)
-    out = np.zeros((arr.shape[0], r) + arr.shape[-2:], np.uint32)
-    if live.any():
-        out[live] = _host_apply_packed(arr[live], matrix_t)
-    return out
-
-
-def _supervised_ragged_dispatch(seam: str, pool, mask, matrix_t,
-                                packed: bool, eng: str):
-    """Supervised-plane routing for one eager ragged dispatch —
-    mirror of _supervised_matrix_dispatch with the (pool, mask)
-    two-operand signature."""
-    from .supervisor import global_supervisor
-
-    def body(v, m, _eng=eng):
-        if _eng == "numpy":
-            return (_host_apply_packed_ragged(v, matrix_t, m) if packed
-                    else _host_apply_bytes_ragged(v, matrix_t, m))
-        if packed:
-            return _run_matrix_packed_ragged(v, matrix_t, m, _eng)
-        return _run_matrix_bytes_ragged(v, matrix_t, 8, m, _eng)
-
-    def rebuild():
-        eng2 = select_ragged_engine(pool.shape, matrix_t, 8,
-                                    packed=packed)
-        return lambda v, m: body(v, m, eng2)
-
-    host_fn = (lambda v, m: _host_apply_packed_ragged(v, matrix_t, m)) \
-        if packed else \
-        (lambda v, m: _host_apply_bytes_ragged(v, matrix_t, m))
-    return global_supervisor().dispatch(
-        seam, body, (pool, mask), host_fn=host_fn, rebuild=rebuild)
-
-
-def apply_matrix_best_ragged(pool: jax.Array, matrix_t,
-                             mask: jax.Array, w: int = 8) -> jax.Array:
-    """Ragged dispatch over the page-pool tiers via
-    select_ragged_engine, byte-identical in every branch (dead pages
-    zero everywhere).  w=16/32 pools run the mask tier (the word
-    kernels have no ragged variant; the mask multiply is exact on the
-    word views too)."""
-    from ..telemetry.metrics import record_dispatch
-    if w != 8:
-        x = mask_pages(pool, mask)
-        inner = select_matrix_engine(x.shape, matrix_t, w, mesh=0)
-        if inner in ("numpy", "mesh"):
-            inner = "xla"
-        return _run_matrix_bytes(x, matrix_t, w, inner)
-    eng = select_ragged_engine(pool.shape, matrix_t, 8)
-    eager = not (isinstance(pool, jax.core.Tracer)
-                 or isinstance(mask, jax.core.Tracer))
-    with record_dispatch("ops_apply_matrix_ragged", eager=eager,
-                         engine=eng, layout="bytes"):
-        if eager:
-            return _supervised_ragged_dispatch(
-                "ops.apply_matrix_ragged", pool, mask, matrix_t,
-                False, eng)
-        return _run_matrix_bytes_ragged(pool, matrix_t, 8, mask, eng)
-
-
-def apply_matrix_packed_best_ragged(words: jax.Array, matrix_t,
-                                    mask: jax.Array) -> jax.Array:
-    """Packed-layout ragged dispatch (resident (P, s, R, 128) uint32
-    pools) — the packed twin of apply_matrix_best_ragged."""
-    from ..telemetry.metrics import record_dispatch
-    eng = select_ragged_engine(words.shape, matrix_t, 8, packed=True)
-    eager = not (isinstance(words, jax.core.Tracer)
-                 or isinstance(mask, jax.core.Tracer))
-    with record_dispatch("ops_apply_matrix_ragged", eager=eager,
-                         engine=eng, layout="packed"):
-        if eager:
-            return _supervised_ragged_dispatch(
-                "ops.apply_matrix_packed_ragged", words, mask,
-                matrix_t, True, eng)
-        return _run_matrix_packed_ragged(words, matrix_t, mask, eng)
-
-
 # -- operand-coefficient family (pattern-generic decode) -----------------
 #
 # Every kernel above bakes its matrix into the trace, so a decode

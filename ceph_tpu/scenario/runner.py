@@ -112,12 +112,7 @@ def run_serving_scenario(spec, clock=None, executor: str = "device",
                            slo=slo)
     batcher = ContinuousBatcher(clock=clock, ladder=spec.ladder,
                                 executor=executor,
-                                service_model=service_model,
-                                paged=getattr(spec, "paged", False),
-                                page_size=getattr(spec, "page_size",
-                                                  None),
-                                pool_pages=getattr(spec, "pool_pages",
-                                                   None))
+                                service_model=service_model)
     if sla is None:
         sla = SlaRecorder(slo)
     monitor = False

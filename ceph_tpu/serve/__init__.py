@@ -12,13 +12,8 @@ package is the conversion layer:
 - ``batcher`` — the continuous batcher: shape buckets keyed exactly
                 like the PatternCache, batch dim padded up a small
                 fixed rung ladder, deadline-slack firing; zero warm
-                recompiles by construction.  ``paged=True`` swaps the
-                buckets for ragged page-pool queues (mixed stripe
-                sizes, one program per pattern, page-tail-only
-                padding).
-- ``pool``    — the paged stripe pool: fixed-size pages, page-table
-                indirection, explicit reclaim at demux (the ragged
-                mode's staging buffer).
+                recompiles by construction.  Mixed stripe sizes
+                are served by one bucket per chunk size.
 - ``sla``     — per-op-class SLO policy + evaluation (p50/p99/p999,
                 GB/s-under-SLO, deadline-miss and padding overheads).
 - ``loadgen`` — seeded open/closed-loop traffic generation and the
@@ -34,15 +29,6 @@ entry pins the bookkeeping compile-free).
 from .queue import OPS, AdmissionQueue, EcRequest, EcResult
 from .sla import BurnRateMonitor, SlaRecorder, SloPolicy
 from .batcher import LADDER, ContinuousBatcher, rung_for
-from .pool import (
-    PagedStripePool,
-    PoolExhausted,
-    effective_page_size,
-    join_pages,
-    pool_selftest,
-    split_pages,
-    tuned_pool_config,
-)
 from .loadgen import (
     CodecSpec,
     LoadGenerator,
@@ -64,20 +50,13 @@ __all__ = [
     "LADDER",
     "LoadGenerator",
     "OPS",
-    "PagedStripePool",
-    "PoolExhausted",
     "ServingRun",
     "SlaRecorder",
     "SloPolicy",
     "TrafficSpec",
     "default_spec",
-    "effective_page_size",
-    "join_pages",
-    "pool_selftest",
     "rung_for",
     "run_serving_scenario",
-    "split_pages",
     "throughput_service_model",
-    "tuned_pool_config",
     "verify_results",
 ]

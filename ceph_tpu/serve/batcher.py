@@ -35,18 +35,6 @@ it, the Ragged-Paged-Attention discipline (PAPERS.md, arxiv
   batches fill; under trickle traffic nobody waits past their
   deadline for co-batchees that never come.
 
-**Paged mode** (``paged=True``) replaces the shape buckets with
-per-(plugin, profile, op, pattern) queues over a bounded
-:class:`~ceph_tpu.serve.pool.PagedStripePool`: mixed stripe sizes
-co-batch into ONE ragged device program per queue
-(codes/engine.py :: serve_dispatch_ragged — the per-fire activity mask
-is a traced operand), the only padding is page-tail bytes, pool
-exhaustion is the backpressure signal (fire + retry) and pages are
-reclaimed explicitly at demux.  Deadline-slack firing, demux
-byte-identity and the warm==0 contract are unchanged; the cached-
-program count collapses from |buckets| x |ladder| to |patterns|
-(``cached_program_count()`` witnesses it).
-
 Execution goes through :func:`~ceph_tpu.codes.engine.serve_dispatch_call`
 (``executor="device"``; repair reuses the scrub path's fused
 decode→re-encode program and cache entry) or the plugins' numpy batch
@@ -66,7 +54,7 @@ nested in it is its child), and under each ``serve.batch``:
 (the device result), ``serve.d2h`` (its copy to the host) and
 ``serve.demux`` (slicing out the per-request results).
 ``serve_dispatches`` carries the fire ``reason``: ``full``,
-``deadline``, ``flush`` or ``backpressure``.
+``deadline`` or ``flush``.
 
 **Staging.**  Dense fires pack into one reused host buffer per
 ``(rows, chunk_size)``, sized for the top rung and allocated at warm-up
@@ -88,8 +76,6 @@ from ..telemetry import span
 from ..telemetry import tracing
 from ..utils.detcheck import default_clock
 from ..utils.log import dout
-from .pool import (PagedStripePool, PoolExhausted, effective_page_size,
-                   tuned_pool_config)
 from .queue import AdmissionQueue, EcRequest, EcResult
 
 # padded stripe-batch sizes: every dispatch shape's batch dim is one
@@ -221,43 +207,6 @@ class _Bucket:
         return min(r.deadline for r in self.requests)
 
 
-class _RaggedQueue:
-    """One paged queue: same plugin/profile/op/pattern — same RAGGED
-    device program, ANY chunk size (the shape-bucket collapse of
-    ISSUE 18).  Owns the bounded page pool; ``chunk_size`` is the PAGE
-    size and a firing "rung" is the live page count, so the
-    ``(bucket, rung) -> seconds`` service-model contract carries over
-    bytes-exact (rung * rows * chunk_size == live_pages * rows *
-    page_size)."""
-
-    __slots__ = ("key", "ec", "op", "available", "erased", "rows",
-                 "page_size", "pool", "requests", "generic", "n_erased")
-
-    def __init__(self, key, ec, op, available, erased, rows,
-                 page_size, pool_pages, generic: bool = False,
-                 n_erased: int = 0) -> None:
-        self.key = key
-        self.ec = ec
-        self.op = op
-        self.available = available
-        self.erased = erased
-        self.rows = rows
-        self.generic = generic
-        self.n_erased = n_erased
-        self.page_size = page_size
-        self.pool = PagedStripePool(pool_pages, rows, page_size,
-                                    ec.page_interleave())
-        self.requests: List[EcRequest] = []
-
-    @property
-    def chunk_size(self) -> int:
-        return self.page_size
-
-    @property
-    def oldest_deadline(self) -> float:
-        return min(r.deadline for r in self.requests)
-
-
 class ContinuousBatcher:
     """Coalesce an admission queue into shape-bucketed dispatches.
 
@@ -278,10 +227,7 @@ class ContinuousBatcher:
                  ladder: Optional[Tuple[int, ...]] = None,
                  executor: str = "device",
                  service_model: Optional[Callable] = None,
-                 min_slack: float = _MIN_SLACK,
-                 paged: bool = False,
-                 page_size: Optional[int] = None,
-                 pool_pages: Optional[int] = None) -> None:
+                 min_slack: float = _MIN_SLACK) -> None:
         from ..utils.retry import SystemClock
 
         if ladder is None:
@@ -302,25 +248,9 @@ class ContinuousBatcher:
         self.executor = executor
         self.service_model = service_model
         self.min_slack = min_slack
-        self.paged = bool(paged)
-        if self.paged:
-            cfg_ps, cfg_pp = tuned_pool_config()
-            self.page_size = (int(page_size) if page_size is not None
-                              else cfg_ps)
-            self.pool_pages = (int(pool_pages) if pool_pages is not None
-                               else cfg_pp)
-            if self.page_size < 1 or self.pool_pages < 1:
-                raise ValueError(
-                    f"page_size {self.page_size} / pool_pages "
-                    f"{self.pool_pages} must be positive")
-        else:
-            self.page_size = page_size
-            self.pool_pages = pool_pages
         self._instances: Dict[tuple, object] = {}
         self._buckets: "Dict[tuple, _Bucket]" = {}
-        self._queues: "Dict[tuple, _RaggedQueue]" = {}
-        # distinct programs this stream exercised: dense (key, rung)
-        # pairs vs one key per paged queue — the collapse witness
+        # distinct (bucket key, rung) programs this stream exercised
         self._programs: set = set()
         self._est: Dict[tuple, float] = {}
         # (rows, chunk_size) -> the dense path's reused staging buffer
@@ -332,10 +262,6 @@ class ContinuousBatcher:
         self.stripes = 0
         self.padded_stripes = 0
         self.padded_bytes = 0
-        # paged-mode byte accounting: the only padding is page-tail
-        # bytes, so overhead is byte-based, not stripe-based
-        self.paged_tail_bytes = 0
-        self.paged_data_bytes = 0
         self.warmup_dispatches = 0
 
     # -- plugin instance + bucket resolution ----------------------------
@@ -398,39 +324,6 @@ class ContinuousBatcher:
             self._buckets[key] = b
         return b
 
-    def ragged_key(self, req: EcRequest) -> tuple:
-        """The paged-queue identity — the PatternCache key WITHOUT the
-        chunk-size extra: mixed stripe sizes co-batch into one queue,
-        one pool, ONE ragged device program.  A pattern-generic decode
-        queue is keyed by the erasure count (its pages take the
-        generic decode program, a page a mini-stripe)."""
-        from ..codes.engine import generic_decode_key, pattern_key
-
-        ec = self._instance(req.plugin, req.profile)
-        if self._generic(ec, req):
-            return generic_decode_key(ec, len(req.erased))
-        return pattern_key(ec, f"serve-{req.op}", req.available,
-                           req.erased)
-
-    def _queue_for(self, req: EcRequest) -> _RaggedQueue:
-        key = self.ragged_key(req)
-        q = self._queues.get(key)
-        if q is None:
-            ec = self._instance(req.plugin, req.profile)
-            ps = effective_page_size(self.page_size, ec.page_unit())
-            if self._generic(ec, req):
-                q = _RaggedQueue(key, ec, req.op, (), (),
-                                 ec.get_data_chunk_count(), ps,
-                                 self.pool_pages, generic=True,
-                                 n_erased=len(req.erased))
-            else:
-                rows = (ec.get_data_chunk_count() if req.op == "encode"
-                        else len(req.available))
-                q = _RaggedQueue(key, ec, req.op, req.available,
-                                 req.erased, rows, ps, self.pool_pages)
-            self._queues[key] = q
-        return q
-
     # -- admission -------------------------------------------------------
 
     def admit(self, requests: List[EcRequest]) -> List[EcResult]:
@@ -444,65 +337,27 @@ class ContinuousBatcher:
         now = self.clock.monotonic()
         for req in requests:
             req.drained = now
+        results: List[EcResult] = []
         with span("serve.admit", metric="serve_admit_seconds"):
-            if self.paged:
-                return self._admit_paged(requests)
-            return self._admit_dense(requests)
-
-    def _admit_dense(self, requests: List[EcRequest]) -> List[EcResult]:
-        results: List[EcResult] = []
-        for req in requests:
-            b = self._bucket_for(req)
-            want = (len(req.available) if b.generic else b.rows,
-                    b.chunk_size)
-            if tuple(req.payload.shape) != want:
-                raise ValueError(
-                    f"request {req.req_id}: payload shape "
-                    f"{tuple(req.payload.shape)} != {want} for "
-                    f"op={req.op} plugin={req.plugin}")
-            b.requests.append(req)
-            if req.trace is not None:
-                # the request→bucket link: bucket identity ≡ device-
-                # program identity, so the trace names the program
-                # family it will ride before the batch even fires
-                req.trace.add("bucket", self.clock.monotonic(),
-                              bucket="|".join(str(p) for p in b.key),
-                              pending=len(b.requests))
-            if len(b.requests) >= self.ladder[-1]:
-                results += self._fire(b, "full")
-        return results
-
-    def _admit_paged(self, requests: List[EcRequest]) -> List[EcResult]:
-        """Paged admission: stage each request's pages into its
-        queue's pool.  A full pool is the backpressure signal — fire
-        the queue NOW (demux reclaims every page), then retry the
-        write; a pool with no free page left after a write fires too
-        (continuous batching).  A single request no empty pool could
-        hold raises ValueError (size the pool, don't wedge it)."""
-        results: List[EcResult] = []
-        for req in requests:
-            q = self._queue_for(req)
-            chunk = q.ec.get_chunk_size(req.stripe_size)
-            want = (len(req.available) if q.generic else q.rows, chunk)
-            if tuple(req.payload.shape) != want:
-                raise ValueError(
-                    f"request {req.req_id}: payload shape "
-                    f"{tuple(req.payload.shape)} != {want} for "
-                    f"op={req.op} plugin={req.plugin}")
-            payload = req.payload[:q.rows]
-            try:
-                q.pool.write(req.req_id, payload)
-            except PoolExhausted:
-                results += self._fire_ragged(q, "backpressure")
-                q.pool.write(req.req_id, payload)
-            q.requests.append(req)
-            if req.trace is not None:
-                req.trace.add("bucket", self.clock.monotonic(),
-                              bucket="|".join(str(p) for p in q.key),
-                              pending=len(q.requests),
-                              pages=q.pool.used_pages())
-            if q.pool.free_pages() == 0:
-                results += self._fire_ragged(q, "full")
+            for req in requests:
+                b = self._bucket_for(req)
+                want = (len(req.available) if b.generic else b.rows,
+                        b.chunk_size)
+                if tuple(req.payload.shape) != want:
+                    raise ValueError(
+                        f"request {req.req_id}: payload shape "
+                        f"{tuple(req.payload.shape)} != {want} for "
+                        f"op={req.op} plugin={req.plugin}")
+                b.requests.append(req)
+                if req.trace is not None:
+                    # the request→bucket link: bucket identity ≡ device-
+                    # program identity, so the trace names the program
+                    # family it will ride before the batch even fires
+                    req.trace.add("bucket", self.clock.monotonic(),
+                                  bucket="|".join(str(p) for p in b.key),
+                                  pending=len(b.requests))
+                if len(b.requests) >= self.ladder[-1]:
+                    results += self._fire(b, "full")
         return results
 
     # -- deadline-aware firing ------------------------------------------
@@ -526,18 +381,6 @@ class ContinuousBatcher:
             return False
         return b.oldest_deadline - now - self._margin(b.key) <= 0.0
 
-    def _units(self):
-        """Every fireable unit — dense buckets and paged queues (both
-        carry key / requests / oldest_deadline, so the deadline-slack
-        policy is mode-blind)."""
-        yield from self._buckets.values()
-        yield from self._queues.values()
-
-    def _fire_unit(self, b, reason: str) -> List[EcResult]:
-        if isinstance(b, _RaggedQueue):
-            return self._fire_ragged(b, reason)
-        return self._fire(b, reason)
-
     def poll(self, queue: Optional[AdmissionQueue] = None
              ) -> List[EcResult]:
         """One batcher turn: drain the queue, fire full buckets, then
@@ -548,29 +391,30 @@ class ContinuousBatcher:
         if queue is not None:
             results += self.admit(queue.drain())
         now = self.clock.monotonic()
-        due = sorted((b for b in self._units() if self._due(b, now)),
+        due = sorted((b for b in self._buckets.values()
+                      if self._due(b, now)),
                      key=lambda b: b.oldest_deadline)
         for b in due:
-            results += self._fire_unit(b, "deadline")
+            results += self._fire(b, "deadline")
         return results
 
     def flush(self) -> List[EcResult]:
-        """Fire every non-empty bucket/queue (end of stream)."""
+        """Fire every non-empty bucket (end of stream)."""
         results: List[EcResult] = []
-        for b in sorted((b for b in self._units() if b.requests),
+        for b in sorted((b for b in self._buckets.values() if b.requests),
                         key=lambda b: b.oldest_deadline):
-            results += self._fire_unit(b, "flush")
+            results += self._fire(b, "flush")
         return results
 
     def next_wakeup(self) -> Optional[float]:
         """Earliest absolute time any bucket becomes due (the sim
         driver advances its FakeClock here when idle)."""
         times = [b.oldest_deadline - self._margin(b.key)
-                 for b in self._units() if b.requests]
+                 for b in self._buckets.values() if b.requests]
         return min(times) if times else None
 
     def pending(self) -> int:
-        return sum(len(b.requests) for b in self._units())
+        return sum(len(b.requests) for b in self._buckets.values())
 
     # -- dispatch --------------------------------------------------------
 
@@ -621,33 +465,26 @@ class ContinuousBatcher:
         """One batched execution, returned as op-shaped host arrays."""
         return _host_arrays(self._launch(b, stack, coef))
 
-    def _coefficients(self, unit, reqs: List[EcRequest], rung: int):
+    def _coefficients(self, b: _Bucket, reqs: List[EcRequest], rung: int):
         """A generic decode fire's coefficient operand, one row per
-        stripe of the rung (a dense bucket) or per page of the pool (a
-        paged queue), zero where no request is; None for any other
-        unit.  Observes the fire's distinct erasure patterns
+        stripe of the rung, zero where no request is; None for any
+        other bucket.  Observes the fire's distinct erasure patterns
         (``serve_dispatch_patterns``) for every decode/repair fire."""
-        if unit.op == "encode":
+        if b.op == "encode":
             return None
-        if not unit.generic:
-            tel.observe("serve_dispatch_patterns", 1, op=unit.op)
+        if not b.generic:
+            tel.observe("serve_dispatch_patterns", 1, op=b.op)
             return None
         from ..codes.engine import decode_table
 
-        k = unit.rows
-        paged = isinstance(unit, _RaggedQueue)
+        k = b.rows
         patterns: Dict[tuple, int] = {}
         index = np.full(rung, -1, np.int64)
         for i, r in enumerate(reqs):
-            p = patterns.setdefault((tuple(r.available[:k]), r.erased),
-                                    len(patterns))
-            if paged:
-                index[list(unit.pool.lease(r.req_id)[0])] = p
-            else:
-                index[i] = p
-        tel.observe("serve_dispatch_patterns", len(patterns),
-                    op=unit.op)
-        return decode_table(unit.ec, list(patterns), index, rung)
+            index[i] = patterns.setdefault(
+                (tuple(r.available[:k]), r.erased), len(patterns))
+        tel.observe("serve_dispatch_patterns", len(patterns), op=b.op)
+        return decode_table(b.ec, list(patterns), index, rung)
 
     def _run(self, op: str, launch: Callable):
         """The dispatch stages of one fire: ``serve.dispatch`` around
@@ -767,136 +604,6 @@ class ContinuousBatcher:
             service=service, batch_occupancy=n, batch_rung=rung,
             deadline_met=(r.deadline is None or t1 <= r.deadline))
 
-    # -- ragged dispatch -------------------------------------------------
-
-    def _launch_ragged(self, q: _RaggedQueue, mask: np.ndarray,
-                       coef=None):
-        """Start one ragged execution over the queue's WHOLE pool: the
-        mask-gated jitted program (device,
-        engine.serve_dispatch_ragged) or the identical masked numpy
-        batch surfaces (host).  Either way the program consumes
-        ``(pages, rows, page_size) + (pages,)`` with the mask as a
-        traced operand — ONE cached program per queue at any
-        occupancy.  A generic decode queue runs the generic decode
-        program over the pool, a page a mini-stripe: ``coef`` holds
-        each live page's decode rows and zeros on dead pages, which
-        stand in for the mask."""
-        self._programs.add(q.key)
-        if self.executor == "device":
-            from ..codes.engine import (serve_decode_generic,
-                                        serve_dispatch_ragged)
-
-            if q.generic:
-                return serve_decode_generic(q.ec, q.n_erased)(q.pool.buf,
-                                                              coef)
-            call = serve_dispatch_ragged(
-                q.ec, q.op, q.available, q.erased,
-                pages=q.pool.pages, page_size=q.page_size)
-            return call(q.pool.buf, mask)
-        if tracing.enabled():
-            tracing.note_program(
-                "serve.host", {"op": q.op, "paged": True,
-                               "plugin": type(q.ec).__name__})
-        if q.generic:
-            return q.ec.decode_chunks_operand_batch(q.pool.buf, coef)
-        # the host tier runs the IDENTICAL ragged program: mask-gate
-        # the pool (dead pages carry stale bytes), then the batch
-        # surfaces over pages-as-mini-chunks
-        x = q.pool.buf * (mask != 0).astype(np.uint8)[:, None, None]
-        if q.op == "encode":
-            return np.asarray(q.ec.encode_chunks_batch(x))
-        if q.op == "decode":
-            return np.asarray(q.ec.decode_chunks_batch(
-                x, q.available, q.erased))
-        return _host_repair(q.ec, x, q.available, q.erased)
-
-    def _execute_ragged(self, q: _RaggedQueue, mask: np.ndarray,
-                        coef=None):
-        """One ragged execution, returned as host arrays."""
-        return _host_arrays(self._launch_ragged(q, mask, coef))
-
-    def _fire_ragged(self, q: _RaggedQueue,
-                     reason: str) -> List[EcResult]:
-        reqs, q.requests = q.requests, []
-        if not reqs:
-            return []
-        n = len(reqs)
-        mask = q.pool.mask()
-        live = int(mask.sum())
-        coef = self._coefficients(q, reqs, q.pool.pages)
-        traced = (tracing.enabled()
-                  and any(r.trace is not None for r in reqs))
-        if traced:
-            tracing.clear_program()
-        with span("serve.batch", op=q.op, occupancy=n, rung=live,
-                  plugin=type(q.ec).__name__, paged=True,
-                  reason=reason):
-            t0 = self.clock.monotonic()
-            out = self._run(q.op,
-                            lambda: self._launch_ragged(q, mask, coef))
-            if self.service_model is not None:
-                # sim mode: the rung is the live page count, so the
-                # modeled bytes (live * rows * page_size) are EXACT —
-                # no padded-rung bytes in the model either
-                self.clock.sleep(self.service_model(q, live))
-            t1 = self.clock.monotonic()
-            service = t1 - t0
-            if _SERVICE_JITTER is not None:
-                service = _SERVICE_JITTER(service, self.dispatches)
-            self._est[q.key] = (service if q.key not in self._est else
-                                (1 - _EWMA_ALPHA) * self._est[q.key]
-                                + _EWMA_ALPHA * service)
-            self.dispatches += 1
-            self.stripes += n
-            # the ONLY padding in the paged path: per-request page-tail
-            # bytes (zero whenever page_size divides the chunk size)
-            tail_cols = sum(q.pool.tail_bytes(r.req_id) for r in reqs)
-            self.padded_bytes += tail_cols * q.rows
-            self.paged_tail_bytes += tail_cols * q.rows
-            self.paged_data_bytes += sum(
-                r.payload.shape[1] * q.rows for r in reqs)
-            tel.counter("serve_dispatches", op=q.op, reason=reason)
-            tel.counter("serve_stripes", n, op=q.op)
-            tel.observe("serve_batch_occupancy", n, op=q.op)
-            self.dispatch_log.append({
-                "bucket": "|".join(str(p) for p in q.key),
-                "op": q.op, "occupancy": n, "rung": live,
-                "req_ids": [r.req_id for r in reqs], "paged": True})
-            with span("serve.demux", metric="serve_demux_seconds",
-                      op=q.op):
-                results = []
-                for r in reqs:
-                    if q.op == "repair":
-                        rec, parity = out
-                        payload_out = (q.pool.read(r.req_id, rec),
-                                       q.pool.read(r.req_id, parity))
-                    else:
-                        payload_out = q.pool.read(r.req_id, out)
-                    results.append(self._result(
-                        r, payload_out, q.op, t0, t1, service, n, live))
-                    # explicit page reclaim at demux — the pool is
-                    # empty again the moment every rider has its
-                    # bytes back
-                    q.pool.reclaim(r.req_id)
-        if traced:
-            program = tracing.take_program()
-            batch_seq = self.dispatches - 1
-            t_done = self.clock.monotonic()
-            for r, res in zip(reqs, results):
-                tr = r.trace
-                if tr is None:
-                    continue
-                tr.add("fire", t0, occupancy=n, rung=live,
-                       batch_seq=batch_seq, executor=self.executor,
-                       paged=True,
-                       co_batched=[x.req_id for x in reqs])
-                if program is not None:
-                    tr.add("program", t0, series=program)
-                tr.add("dispatch_end", t1)
-                tr.add("done", t_done,
-                       deadline_met=res.deadline_met)
-        return results
-
     # -- warmup ----------------------------------------------------------
 
     def warmup(self, requests: List[EcRequest]) -> int:
@@ -911,13 +618,7 @@ class ContinuousBatcher:
         dispatches.
 
         A pattern-generic decode bucket is one bucket for all its
-        patterns, so it warms |ladder| programs once.
-
-        Paged mode warms ONE program per queue instead of |ladder| per
-        bucket — the activity mask is a traced operand, so a single
-        compile covers every occupancy."""
-        if self.paged:
-            return self._warmup_paged(requests)
+        patterns, so it warms |ladder| programs once."""
         seen = set()
         fired = 0
         for req in requests:
@@ -955,86 +656,19 @@ class ContinuousBatcher:
 
     @staticmethod
     def _zero_coef(b, rung: int):
-        """A warm-up fire's coefficient operand (generic units)."""
+        """A warm-up fire's coefficient operand (generic buckets)."""
         if not b.generic:
             return None
         return np.zeros((rung, b.n_erased, b.rows), np.uint8)
 
-    def _warmup_paged(self, requests: List[EcRequest]) -> int:
-        """One zero-mask dispatch per distinct queue pays the compile;
-        a second (full-mask, zero pool) dispatch times steady-state
-        service for the deadline-slack estimator (the sim model is the
-        estimator in sim mode, as on the dense path)."""
-        seen = set()
-        fired = 0
-        for req in requests:
-            key = self.ragged_key(req)
-            if key in seen:
-                continue
-            seen.add(key)
-            q = self._queue_for(req)
-            coef = self._zero_coef(q, q.pool.pages)
-            self._execute_ragged(q, np.zeros(q.pool.pages, np.uint8),
-                                 coef)
-            fired += 1
-            if self.service_model is not None:
-                self._est[key] = self.service_model(q, q.pool.pages)
-            else:
-                full = np.ones(q.pool.pages, np.uint8)
-                t0 = self.clock.monotonic()
-                self._execute_ragged(q, full, coef)
-                self._est[key] = self.clock.monotonic() - t0
-                fired += 1
-        self.warmup_dispatches += fired
-        if fired:
-            dout("serve", 10,
-                 f"warmed {len(seen)} paged queues ({fired} "
-                 f"dispatches, one program each)")
-        return fired
-
     # -- accounting ------------------------------------------------------
 
     def cached_program_count(self) -> int:
-        """Distinct programs this batcher's stream exercised: dense =
-        (bucket, rung) pairs (every rung is its own XLA program);
-        paged = one per queue (the mask is traced, so every occupancy
-        AND every chunk size shares one compile) — the program-count
-        collapse the paged path exists for."""
+        """Distinct programs this batcher's stream exercised: (bucket,
+        rung) pairs (every rung is its own XLA program)."""
         return len(self._programs)
 
-    def pool_stats(self) -> dict:
-        """Aggregate page-pool accounting across the paged queues
-        (live occupancy feeds the bench serving rows)."""
-        qs = list(self._queues.values())
-        return {
-            "queues": len(qs),
-            "pages": sum(q.pool.pages for q in qs),
-            "used_pages": sum(q.pool.used_pages() for q in qs),
-            "high_water": sum(q.pool.high_water for q in qs),
-            "allocs": sum(q.pool.allocs for q in qs),
-            "reclaims": sum(q.pool.reclaims for q in qs),
-            "backpressure": sum(q.pool.backpressure for q in qs),
-        }
-
     def padding_stats(self) -> dict:
-        if self.paged:
-            total = self.paged_data_bytes + self.paged_tail_bytes
-            return {
-                "dispatches": self.dispatches,
-                "stripes": self.stripes,
-                # paged mode never pads whole stripes; overhead is the
-                # byte-based page-tail ratio (0.0 when the page size
-                # divides every chunk size in the mix)
-                "padded_stripes": 0,
-                "padded_bytes": self.padded_bytes,
-                "padding_overhead": (
-                    round(self.paged_tail_bytes / total, 6)
-                    if total else 0.0),
-                "warmup_dispatches": self.warmup_dispatches,
-                "paged": True,
-                "cached_programs": self.cached_program_count(),
-                "pool": self.pool_stats(),
-            }
         total = self.stripes + self.padded_stripes
         return {
             "dispatches": self.dispatches,
@@ -1044,7 +678,6 @@ class ContinuousBatcher:
             "padding_overhead": (round(self.padded_stripes / total, 6)
                                  if total else 0.0),
             "warmup_dispatches": self.warmup_dispatches,
-            "paged": False,
             "cached_programs": self.cached_program_count(),
         }
 

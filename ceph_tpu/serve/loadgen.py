@@ -29,7 +29,7 @@ bench configuration.
 from __future__ import annotations
 
 import itertools
-from dataclasses import dataclass, field
+from dataclasses import dataclass, field, fields
 from typing import Dict, List, Optional, Tuple
 
 import numpy as np
@@ -80,12 +80,6 @@ class TrafficSpec:
     ladder: Tuple[int, ...] = LADDER
     queue_capacity: int = 4096
     pool: int = 8                    # distinct stripes per codec
-    # paged serving (ISSUE 18): ragged queues over a page pool instead
-    # of shape buckets over the rung ladder; None = tuned/default pool
-    # geometry (serve/pool.py::tuned_pool_config)
-    paged: bool = False
-    page_size: Optional[int] = None
-    pool_pages: Optional[int] = None
     # multi-tenant weeks (ISSUE 19, scenario/week.py): the tenant
     # every request in this stream bills against ("" = legacy
     # single-tenant), and the diurnal open-loop arrival modulation —
@@ -115,8 +109,6 @@ class TrafficSpec:
             "concurrency": self.concurrency, "erasures": self.erasures,
             "ladder": list(self.ladder),
             "queue_capacity": self.queue_capacity, "pool": self.pool,
-            "paged": self.paged, "page_size": self.page_size,
-            "pool_pages": self.pool_pages,
             "tenant": self.tenant,
             "diurnal_period_s": self.diurnal_period_s,
             "diurnal_min_frac": self.diurnal_min_frac,
@@ -124,6 +116,13 @@ class TrafficSpec:
 
     @classmethod
     def from_dict(cls, d: dict) -> "TrafficSpec":
+        """The spec ``to_dict`` wrote.  A key that names no field (a
+        knob of a serving mode this build no longer has) is refused,
+        so a saved spec never runs as something it did not ask for."""
+        unknown = sorted(set(d) - {f.name for f in fields(cls)})
+        if unknown:
+            raise ValueError(f"TrafficSpec has no field(s) "
+                             f"{', '.join(unknown)}")
         return cls(
             seed=d["seed"], n_requests=d["n_requests"],
             codecs=[CodecSpec.from_dict(c) for c in d["codecs"]],
@@ -132,9 +131,6 @@ class TrafficSpec:
             concurrency=d["concurrency"], erasures=d["erasures"],
             ladder=tuple(d["ladder"]),
             queue_capacity=d["queue_capacity"], pool=d["pool"],
-            paged=bool(d.get("paged", False)),
-            page_size=d.get("page_size"),
-            pool_pages=d.get("pool_pages"),
             tenant=d.get("tenant", ""),
             diurnal_period_s=d.get("diurnal_period_s"),
             diurnal_min_frac=d.get("diurnal_min_frac", 1.0))
@@ -240,10 +236,10 @@ class LoadGenerator:
 
     ``share_payloads`` (week-scale streams, scenario/week.py):
     requests reference the generator's pooled arrays instead of
-    copying them — every consumer (the batcher stacks payloads into a
-    fresh dispatch buffer; the pool pages copy on write) treats
-    payloads as read-only, so sharing is safe and turns a million-
-    request stream from gigabytes into the pool's footprint."""
+    copying them — every consumer (the batcher copies payloads into
+    its staging buffer) treats payloads as read-only, so sharing is
+    safe and turns a million-request stream from gigabytes into the
+    pool's footprint."""
 
     def __init__(self, spec: TrafficSpec,
                  share_payloads: bool = False) -> None:

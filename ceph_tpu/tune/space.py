@@ -21,11 +21,6 @@ kind                    consulted by
                         horizon (CSE_TOPK)
 ``serve-ladder``        serve/batcher.py::ContinuousBatcher (the batch
                         rung ladder)
-``stripe-pool``         serve/pool.py::tuned_pool_config (paged-mode
-                        page size + pool page count)
-``ragged-cutover``      ops/pallas_gf.py::tuned_ragged_cutover (min
-                        live pages before the ragged Pallas kernel
-                        beats mask-multiply + the dense tier)
 ``mesh-fanout``         parallel/plane.py::_build_plane (auto-plane
                         shard fan-out width)
 ``matrix-engine``       select_matrix_engine per-matrix tier pin
@@ -49,8 +44,6 @@ DEFAULTS: Dict[str, dict] = {
     "engine-select": {"mxu_matrix_min": 2048, "xor_cutover": (3, 4)},
     "xor-schedule": {"cse_topk": 128},
     "serve-ladder": {"ladder": (1, 4, 16, 64)},
-    "stripe-pool": {"page_size": 512, "pool_pages": 64},
-    "ragged-cutover": {"min_pages": 2},
     "mesh-fanout": {"n_devices": 0},      # 0 = every visible device
     "matrix-engine": {"engine": None},    # None = the heuristic table
 }
@@ -73,14 +66,6 @@ SPACES: Dict[str, Dict[str, Tuple]] = {
                                 (1, 8, 64),
                                 (1, 2, 8, 32),
                                 (1, 4, 16, 64, 256))},
-    # paged-pool geometry: smaller pages cut tail padding, cost more
-    # page-table entries; more pages co-batch more before a fire but
-    # grow the HBM-resident pool (pages * rows * page_size per queue)
-    "stripe-pool": {"page_size": (256, 512, 1024),
-                    "pool_pages": (32, 64, 128)},
-    # live-page count above which the ragged Pallas kernel (skips dead
-    # grid rows) beats mask-multiply feeding the dense tier
-    "ragged-cutover": {"min_pages": (1, 2, 8)},
     # auto-plane shard fan-out width (capped at the visible devices)
     "mesh-fanout": {"n_devices": (1, 2, 4, 8)},
     # per-matrix engine-tier pin: every tier is byte-identical by

@@ -566,46 +566,6 @@ def analytic_sweep(seed: int = 0, platform: Optional[str] = None,
         if base_score else None,
     })
 
-    # -- paged stripe-pool geometry (kind: stripe-pool) -----------------
-    # page-tail padding fraction over a seeded mixed chunk-size day,
-    # plus a small modeled cost for fire count (small pools fire more
-    # often) and pool HBM footprint (pages * page_size)
-    chunk_mix = [int(v) for v in rng.choice(
-        np.array([512, 1024, 2048, 4096, 6144, 10240]), size=128)]
-
-    def pool_score(cfg: dict) -> Tuple[float, float]:
-        ps, pp = int(cfg["page_size"]), int(cfg["pool_pages"])
-        tail = sum((-c) % ps for c in chunk_mix)
-        data = sum(chunk_mix)
-        frac = tail / (data + tail)
-        pages_needed = sum((c + ps - 1) // ps for c in chunk_mix)
-        fires = max(1.0, pages_needed / pp)
-        return (frac + 0.0005 * fires + 1e-9 * pp * ps,
-                round(100.0 * data / (data + tail), 4))
-
-    pool_default = tspace.default_config("stripe-pool")
-    base_sc, base_ut = pool_score(pool_default)
-    best_cfg, best_sc, best_ut = dict(pool_default), base_sc, base_ut
-    for cand in tspace.candidates("stripe-pool"):
-        sc, ut = pool_score(cand)
-        if sc < best_sc:
-            best_cfg, best_sc, best_ut = dict(cand), sc, ut
-    pool_key = tuning_key("*", "stripe-pool", "*", "*", device_count, 0)
-    if best_cfg != pool_default:
-        table.set(pool_key, best_cfg, mode="analytic", score=best_sc,
-                  baseline_score=base_sc,
-                  baseline_config=dict(pool_default))
-    report.rows.append({
-        "name": "stripe-pool", "key": key_str(pool_key),
-        "kind": "stripe-pool",
-        "before": {"config": dict(pool_default),
-                   "utilization_pct": base_ut},
-        "after": {"config": dict(best_cfg),
-                  "utilization_pct": best_ut},
-        "improvement_pct": round(100.0 * (base_sc - best_sc)
-                                 / base_sc, 2) if base_sc else None,
-    })
-
     # -- mesh fan-out width (kind: mesh-fanout) -------------------------
     if device_count > 1:
         rep_bytes = 64 * (s_rep + r_rep) * (1 << 18)

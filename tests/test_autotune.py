@@ -164,10 +164,6 @@ def test_consult_defaults_with_no_table():
     assert tuned_cse_topk() == 128
     assert tuned_xor_cutover() == (3, 4)
     assert tuned_ladder() == LADDER
-    from ceph_tpu.ops.pallas_gf import tuned_ragged_cutover
-    from ceph_tpu.serve.pool import tuned_pool_config
-    assert tuned_pool_config() == (512, 64)
-    assert tuned_ragged_cutover() == 2
 
 
 def test_space_defaults_match_live_constants():
@@ -183,11 +179,8 @@ def test_space_defaults_match_live_constants():
     assert DEFAULTS["engine-select"]["xor_cutover"] == XOR_DENSE_CUTOVER
     assert DEFAULTS["xor-schedule"]["cse_topk"] == CSE_TOPK
     assert tuple(DEFAULTS["serve-ladder"]["ladder"]) == LADDER
-    from ceph_tpu.ops.pallas_gf import RAGGED_MIN_PAGES
-    from ceph_tpu.serve.pool import DEFAULT_PAGE_SIZE, DEFAULT_POOL_PAGES
-    assert DEFAULTS["stripe-pool"]["page_size"] == DEFAULT_PAGE_SIZE
-    assert DEFAULTS["stripe-pool"]["pool_pages"] == DEFAULT_POOL_PAGES
-    assert DEFAULTS["ragged-cutover"]["min_pages"] == RAGGED_MIN_PAGES
+    # the seams of the removed page-pool serving path have no kind
+    assert {"stripe-pool", "ragged-cutover"}.isdisjoint(kinds())
     # every kind's default value is itself a candidate (the sweep can
     # never do worse than the status quo on its own model)
     for kind in kinds():

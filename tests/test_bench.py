@@ -267,8 +267,9 @@ def test_bench_metric_version_and_slice_field():
     assert "lockcheck" in bench._audit_meta()
     assert "tenant_week_isolation" in dict(bench.TENANT_WEEK_ROWS)
     assert "victim_gbps_under_slo" in bench.TENANT_WEEK_ROW_FIELDS
-    assert "serving_mixed_paged" in dict(bench.SERVING_ROWS)
-    assert "--paged" in dict(bench.SERVING_ROWS)["serving_mixed_paged"]
+    # one serving row: the dense shape buckets are the only path
+    assert [name for name, _ in bench.SERVING_ROWS] == [
+        "serving_mixed_closed"]
     assert "tail_attribution" in bench.SCENARIO_ROW_FIELDS
     assert "tail_attribution" in bench.SERVING_ROW_FIELDS
     for rows in (bench.HOST_CHAOS_ROWS, bench.AUTOTUNE_ROWS,

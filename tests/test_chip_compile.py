@@ -158,16 +158,6 @@ def test_mxu_route_compiles_clay_composite(one_chip):
 
 
 @pytest.mark.parametrize("cap", ROW_TILE_CAPS)
-def test_ragged_kernel_compiles(one_chip, cap):
-    from ceph_tpu.ops.pallas_gf import apply_matrix_pallas_ragged
-    pool = _sds(one_chip, (512, K, 64 * 1024), jnp.uint8)
-    mask = _sds(one_chip, (512,), jnp.uint8)
-    _, text = _compile(apply_matrix_pallas_ragged, pool, _rs_matrix(),
-                       mask, False, cap)
-    assert "tpu_custom_call" in text
-
-
-@pytest.mark.parametrize("cap", ROW_TILE_CAPS)
 @pytest.mark.parametrize("erased", [1, 2, 3])
 def test_operand_kernel_compiles(one_chip, erased, cap):
     """The pattern-generic decode kernel at the served shape: a rung of
@@ -185,8 +175,8 @@ def test_kernels_compile_with_x64_on(one_chip):
     crush/bulk.py's with_x64 scope, or one that set it globally) must
     still lower to 32-bit index maps: Mosaic refused 'func.return'
     before ops/pallas_gf.py applied every pallas_call with x64 off."""
-    from ceph_tpu.ops.pallas_gf import (apply_matrix_pallas,
-                                        apply_matrix_pallas_ragged)
+    from ceph_tpu.ops.pallas_gf import (apply_matrix_operand_pallas,
+                                        apply_matrix_pallas)
     prev = jax.config.jax_enable_x64
     jax.config.update("jax_enable_x64", True)
     try:
@@ -194,10 +184,9 @@ def test_kernels_compile_with_x64_on(one_chip):
         _, text = _compile(apply_matrix_pallas, x, _rs_matrix(), False,
                            None)
         assert "tpu_custom_call" in text
-        pool = _sds(one_chip, (512, K, 64 * 1024), jnp.uint8)
-        mask = _sds(one_chip, (512,), jnp.uint8)
-        _, text = _compile(apply_matrix_pallas_ragged, pool,
-                           _rs_matrix(), mask, False, None)
+        coef = _sds(one_chip, (STRIPES, 2, K), jnp.uint8)
+        _, text = _compile(apply_matrix_operand_pallas, x, coef, False,
+                           None)
         assert "tpu_custom_call" in text
     finally:
         jax.config.update("jax_enable_x64", prev)

@@ -1,8 +1,8 @@
 """The pattern-generic decode of w=8 matrix codes: the operand-coefficient
 GF(2^8) kernel (ops/pallas_gf.py, Pallas interpret mode and its XLA and
 numpy tiers), the engine's generic decode program and coefficient table
-(codes/engine.py), and the batcher's one bucket per erasure count
-(serve/batcher.py).
+(codes/engine.py), and the batcher's one bucket per (erasure count,
+chunk size) (serve/batcher.py).
 
 Truth is the plain references of the benchmark (benchmark/reference/
 isa.py, jerasure.py), which import nothing of the program: every stripe
@@ -74,12 +74,12 @@ def _ec(name):
                                                         dict(profile))
 
 
-def _stripes(name, patterns, seed):
+def _stripes(name, patterns, seed, chunk=CHUNK):
     """One reference-encoded stripe per pattern: (full (P, 11, C), the
     survivors each pattern's decode reads (P, 8, C) and their ids)."""
     _, profile, ref = CODES[name]
     rng = np.random.default_rng(seed)
-    data = rng.integers(0, 256, (len(patterns), 8, CHUNK), np.uint8)
+    data = rng.integers(0, 256, (len(patterns), 8, chunk), np.uint8)
     full = np.concatenate([data, ref.parity(data, profile)], axis=1)
     avail = [tuple(i for i in range(N) if i not in p)[:8]
              for p in patterns]
@@ -221,53 +221,70 @@ def _hist(name):
     return c, s
 
 
-def _requests(name, patterns, seed):
+def _requests(name, patterns, seed, chunk=CHUNK):
     plugin, profile, _ = CODES[name]
-    full, _, _ = _stripes(name, patterns, seed)
+    full, _, _ = _stripes(name, patterns, seed, chunk)
     reqs, want = [], {}
     for i, p in enumerate(patterns):
         # every survivor named; the decode reads the first k of them
         avail = tuple(j for j in range(N) if j not in p)
         r = EcRequest(op="decode", plugin=plugin, profile=dict(profile),
-                      stripe_size=8 * CHUNK, payload=full[i, list(avail)],
+                      stripe_size=8 * chunk, payload=full[i, list(avail)],
                       available=avail, erased=p, deadline=1.0)
         reqs.append(r)
         want[r.req_id] = full[i, list(p)]
     return reqs, want
 
 
-@pytest.mark.parametrize("executor", ("host", "device"))
+def _sized_requests(name, patterns, seed, sizes):
+    reqs, want = [], {}
+    for chunk in sizes:
+        r, w = _requests(name, patterns, seed, chunk)
+        reqs += r
+        want.update(w)
+    return reqs, want
+
+
+@pytest.mark.parametrize("executor,sizes", [
+    pytest.param("host", (CHUNK,), id="host"),
+    pytest.param("device", (CHUNK,), id="device"),
+    pytest.param("host", (CHUNK, 2 * CHUNK), id="host-two_sizes")])
 @pytest.mark.parametrize("name", sorted(CODES))
-def test_batcher_one_bucket_for_55_patterns(name, executor, registry,
-                                            fresh_cache):
-    """55 two-shard patterns warm ONE bucket (one cached program per
-    rung), fire at the full top rung twice, and demux every request
-    its own chunks; the fires observe 55 patterns each, the first
-    fire's decode rows miss and the second's hit."""
+def test_batcher_one_bucket_for_55_patterns(name, executor, sizes,
+                                            registry, fresh_cache):
+    """55 two-shard patterns warm ONE bucket per chunk size (one
+    cached program per rung), each fires at the full top rung twice,
+    and every request gets its own chunks back; the fires observe 55
+    patterns each, the first fire's decode rows miss and the rest
+    hit (decode rows do not depend on the chunk size)."""
     ladder = (1, 5, 11, 55)
+    n = len(sizes)
     b = ContinuousBatcher(clock=FakeClock(), executor=executor,
                           ladder=ladder, service_model=lambda u, r: 1e-4)
-    reqs, want = _requests(name, DOUBLE, seed=5)
-    assert len({b.bucket_key(r) for r in reqs}) == 1
-    assert b.warmup(reqs) == len(ladder)
-    assert len(b._buckets) == 1
-    assert b.cached_program_count() == len(ladder)
-    more, more_want = _requests(name, DOUBLE[::-1], seed=6)
+    reqs, want = _sized_requests(name, DOUBLE, 5, sizes)
+    assert len({b.bucket_key(r) for r in reqs}) == n
+    assert b.warmup(reqs) == n * len(ladder)
+    assert sorted(bk.chunk_size for bk in b._buckets.values()) == \
+        sorted(sizes)
+    assert all(bk.generic and bk.n_erased == 2
+               for bk in b._buckets.values())
+    assert b.cached_program_count() == n * len(ladder)
+    more, more_want = _sized_requests(name, DOUBLE[::-1], 6, sizes)
     want.update(more_want)
     results = b.admit(reqs) + b.admit(more)
     assert b.pending() == 0
     assert [(d["occupancy"], d["rung"]) for d in b.dispatch_log] == \
-        [(55, 55), (55, 55)]
+        [(55, 55)] * (2 * n)
     assert _series("serve_dispatches") == {
-        "serve_dispatches{op=decode,reason=full}": 2}
-    assert b.cached_program_count() == len(ladder)
+        "serve_dispatches{op=decode,reason=full}": 2 * n}
+    assert b.cached_program_count() == n * len(ladder)
     assert [r.request for r in results] == reqs + more
     for res in results:
         assert np.array_equal(res.output, want[res.request.req_id])
-    assert _hist("serve_dispatch_patterns") == (2, 110)
+    assert _hist("serve_dispatch_patterns") == (2 * n, 110 * n)
     assert _series("engine_decode_matrix") == {
         "engine_decode_matrix{outcome=miss}": 55,
-        "engine_decode_matrix{outcome=hit}": 55}
+        "engine_decode_matrix{outcome=hit}": 55 * (2 * n - 1)}
     if executor == "device":
         keys = [k for k in fresh_cache._entries
                 if k[2].startswith("serve-decode")]
@@ -289,24 +306,6 @@ def test_batcher_mixed_erasure_counts(name, registry):
     for res in results:
         assert np.array_equal(res.output, want[res.request.req_id])
     assert _hist("serve_dispatch_patterns") == (2, 66)
-
-
-@pytest.mark.parametrize("executor", ("host", "device"))
-def test_paged_generic_queue(executor, registry):
-    """The paged path takes the same program: one queue for the 55
-    patterns, a page a mini-stripe, dead pages' rows zero."""
-    b = ContinuousBatcher(clock=FakeClock(), executor=executor,
-                          paged=True, page_size=1024, pool_pages=256,
-                          service_model=lambda u, r: 1e-4)
-    reqs, want = _requests("isa_cauchy", DOUBLE, seed=8)
-    assert b.warmup(reqs[:1]) == 1
-    results = b.admit(reqs) + b.flush()
-    assert len(b._queues) == 1 and b.cached_program_count() == 1
-    assert len(results) == len(reqs)
-    for res in results:
-        assert np.array_equal(res.output, want[res.request.req_id])
-    count, total = _hist("serve_dispatch_patterns")
-    assert total == 55 and count == b.dispatches
 
 
 def test_sharded_generic_decode(registry):

@@ -189,42 +189,71 @@ def test_top_programs_orders_by_total_seconds():
 # ----------------------------------------------------------------------
 # the engine seams feed the profiler
 
-def test_engine_dispatch_rows_and_traced_silence():
+@pytest.mark.parametrize("seam", ["serve_encode", "fused_repair"])
+def test_engine_dispatch_rows_and_traced_silence(seam):
+    """Both engine seams run through one eager dispatch wrapper and
+    keep their own names: the profiler row (``engine.serve_dispatch``
+    kind ``serve-encode``; ``engine.fused_repair`` kind
+    ``fused-repair``), one row per batch rung, and the dispatch
+    histogram (``serve_dispatch{op,plugin}``;
+    ``engine_fused_repair_dispatch{plugin}``, no ``op``).  A traced
+    dispatch records nothing."""
     import jax
 
-    from ceph_tpu.codes.engine import serve_dispatch_call
+    from ceph_tpu.codes.engine import (fused_repair_call,
+                                       serve_dispatch_call)
     from ceph_tpu.codes.registry import ErasureCodePluginRegistry
 
     ec = ErasureCodePluginRegistry.instance().factory(
         "jerasure", {"technique": "reed_sol_van", "k": "4", "m": "2"})
-    data = np.random.default_rng(3).integers(
-        0, 256, (4, 4, 1024), np.uint8)
-    prev = set_global_profiler(ProgramProfiler())
-    try:
+    plugin = type(ec).__name__
+    if seam == "serve_encode":
         fn = serve_dispatch_call(ec, "encode")
-        np.asarray(fn(jax.device_put(data)))
-        np.asarray(fn(jax.device_put(data)))
+        rows_in, name, kind = 4, "engine.serve_dispatch", "serve-encode"
+        series = f"serve_dispatch_seconds{{op=encode,plugin={plugin}}}"
+    else:
+        fn = fused_repair_call(ec, (0, 1, 2, 4, 5), (3,))
+        rows_in, name, kind = 5, "engine.fused_repair", "fused-repair"
+        series = f"engine_fused_repair_dispatch_seconds{{plugin={plugin}}}"
+    data = np.random.default_rng(3).integers(
+        0, 256, (4, rows_in, 1024), np.uint8)
+    prev = set_global_profiler(ProgramProfiler())
+    prev_reg = set_global_metrics(MetricsRegistry())
+    try:
+        jax.block_until_ready(fn(jax.device_put(data)))
+        jax.block_until_ready(fn(jax.device_put(data)))
         rows = telemetry.global_profiler().attribution_rows()
-        (row,) = [r for r in rows
-                  if r["name"] == "engine.serve_dispatch"]
-        assert row["kind"] == "serve-encode"
+        (row,) = [r for r in rows if r["name"] == name]
+        assert row["kind"] == kind
+        assert row["pattern"] == ("e" if seam == "serve_encode"
+                                  else "e3")
         assert row["batch"] == "4" and row["devices"] == "1"
         assert row["source"] == "xla" and row["bytes_accessed"] > 0
         assert row["calls"] == 2
+        (body,) = telemetry.global_metrics().dump().values()
+        dispatch = {k: v for k, v in body.items()
+                    if k.startswith(("serve_dispatch_",
+                                     "engine_fused_repair_dispatch_"))}
+        assert set(dispatch) == {
+            series, series.replace("_seconds{", "_calls{")}
+        assert dispatch[series]["count"] == 2
         # a DIFFERENT batch rung through the same cached program gets
         # its own row (per-shape attribution)
-        np.asarray(fn(jax.device_put(data[:2])))
+        jax.block_until_ready(fn(jax.device_put(data[:2])))
         rows = telemetry.global_profiler().attribution_rows()
-        assert len([r for r in rows
-                    if r["name"] == "engine.serve_dispatch"]) == 2
+        assert len([r for r in rows if r["name"] == name]) == 2
         # traced dispatch records nothing: the jaxpr stays
         # profiler-free exactly like it stays telemetry-free
         set_global_profiler(ProgramProfiler())
+        set_global_metrics(MetricsRegistry())
         jitted = jax.jit(lambda a: fn(a))
-        np.asarray(jitted(jax.device_put(data)))
+        jax.block_until_ready(jitted(jax.device_put(data)))
         assert telemetry.global_profiler().attribution_rows() == []
+        (body,) = telemetry.global_metrics().dump().values()
+        assert series not in body
     finally:
         set_global_profiler(prev)
+        set_global_metrics(prev_reg)
 
 
 def test_profile_entrypoints_subset_rows_complete():

@@ -57,6 +57,16 @@ FAMILY_CODECS = [
 ]
 
 
+MIXED_SIZES = (2048, 4096, 8192)
+
+
+def mixed_codecs(base: CodecSpec):
+    """The same (plugin, profile) at three stripe sizes: three dense
+    buckets per pattern, one per chunk size."""
+    return [CodecSpec(f"{base.name}_{s}", base.plugin,
+                      dict(base.profile), s) for s in MIXED_SIZES]
+
+
 def small_spec(codecs, n=40, seed=7, **kw):
     kw.setdefault("ladder", (1, 2, 4, 8))
     kw.setdefault("concurrency", 16)
@@ -95,18 +105,33 @@ def test_fakeclock_determinism(arrival):
 # ----------------------------------------------------------------------
 # byte identity, all five families
 
-@pytest.mark.parametrize("codec", FAMILY_CODECS,
-                         ids=[c.name for c in FAMILY_CODECS])
-def test_batched_equals_per_request_host(codec):
+def _chunk_sizes(run):
+    """(chunk sizes of the fired buckets, of the requests served)."""
+    ec_of = run.batcher._instance
+    served = {ec_of(r.request.plugin, r.request.profile).get_chunk_size(
+        r.request.stripe_size) for r in run.results}
+    return {b.chunk_size for b in run.batcher._buckets.values()}, served
+
+
+@pytest.mark.parametrize(
+    "codecs",
+    [pytest.param([c], id=c.name) for c in FAMILY_CODECS]
+    + [pytest.param(mixed_codecs(c), id=f"{c.name}-mixed_sizes")
+       for c in FAMILY_CODECS])
+def test_batched_equals_per_request_host(codecs):
     """Batched (padded, demuxed) execution is byte-identical to
-    per-request execution for every plugin family: ground truth from
+    per-request execution for every plugin family, at one stripe size
+    and at three (one dense bucket per chunk size): ground truth from
     the generator AND a direct per-request surface call both match."""
-    spec = small_spec([codec], n=24)
+    spec = small_spec(codecs, n=24)
     run = sim_run(spec)
     assert len(run.results) == 24
     assert verify_results(run.results) == []
+    buckets, served = _chunk_sizes(run)
+    assert buckets == served
+    assert len(served) == len(codecs)
     reg = ErasureCodePluginRegistry.instance()
-    ec = reg.factory(codec.plugin, dict(codec.profile))
+    ec = reg.factory(codecs[0].plugin, dict(codecs[0].profile))
     ec.min_xla_bytes = float("inf")
     for res in run.results[:6]:
         req = res.request
@@ -121,12 +146,19 @@ def test_batched_equals_per_request_host(codec):
             assert np.array_equal(rec, ref)
 
 
-def test_batched_equals_per_request_device_seam():
+@pytest.mark.parametrize("codecs", [
+    pytest.param([RS4, SHEC4], id="two_codecs"),
+    pytest.param(mixed_codecs(RS4) + mixed_codecs(SHEC4),
+                 id="mixed_sizes")])
+def test_batched_equals_per_request_device_seam(codecs):
     """The jitted serve dispatch seam (engine.serve_dispatch_call)
-    returns the same bytes as the per-request device surfaces."""
-    spec = small_spec([RS4, SHEC4], n=24)
+    returns the same bytes as the per-request device surfaces, for
+    one stripe size and for a mix of three."""
+    spec = small_spec(codecs, n=24)
     run = sim_run(spec, executor="device")
     assert verify_results(run.results) == []
+    buckets, served = _chunk_sizes(run)
+    assert buckets == served
     reg = ErasureCodePluginRegistry.instance()
     for res in run.results[:8]:
         req = res.request
@@ -143,26 +175,27 @@ def test_batched_equals_per_request_device_seam():
 # ----------------------------------------------------------------------
 # zero warm recompiles + armed recompile budget, 500-request stream
 
-def test_500_stream_zero_recompiles_budget_armed():
-    """The acceptance gate: a seeded 500+-request mixed (plugin × op ×
-    stripe-size) stream through a warmed batcher is byte-identical to
-    ground truth, compiles ZERO programs, and never builds a new
-    pattern under an armed recompile budget."""
+@pytest.mark.parametrize("codecs,n", [
+    pytest.param([RS4,
+                  CodecSpec("rs_k4_m2_8k", "jerasure",
+                            {"technique": "reed_sol_van", "k": "4",
+                             "m": "2"}, 8192),
+                  SHEC4], 500, id="rs_two_sizes_shec"),
+    pytest.param(mixed_codecs(RS4) + mixed_codecs(SHEC4), 200,
+                 id="mixed_sizes")])
+def test_500_stream_zero_recompiles_budget_armed(codecs, n):
+    """The acceptance gate: a seeded mixed (plugin × op × stripe-size)
+    stream through a warmed batcher is byte-identical to ground truth,
+    compiles ZERO programs, and never builds a new pattern under an
+    armed recompile budget (500 requests; 200 over three sizes of
+    two codecs)."""
     from ceph_tpu.analysis.jaxpr_audit import _CompileCounter
     from ceph_tpu.codes.engine import global_pattern_cache
 
-    codecs = [
-        RS4,
-        CodecSpec("rs_k4_m2_8k", "jerasure",
-                  {"technique": "reed_sol_van", "k": "4", "m": "2"},
-                  8192),
-        SHEC4,
-    ]
-    spec = small_spec(codecs, n=500, seed=13, concurrency=32,
-                      pool=4)
+    spec = small_spec(codecs, n=n, seed=13, concurrency=32, pool=4)
     # run 1: cold — compiles the bucket ladder + warms every pattern
     first = sim_run(spec, executor="device")
-    assert len(first.results) == 500
+    assert len(first.results) == n
     assert verify_results(first.results) == []
     # arm: any pattern build past this point raises loudly
     cache = global_pattern_cache()
@@ -173,7 +206,7 @@ def test_500_stream_zero_recompiles_budget_armed():
             second = sim_run(spec, executor="device")
     finally:
         cache.recompile_budget = prev_budget
-    assert len(second.results) == 500
+    assert len(second.results) == n
     assert verify_results(second.results) == []
     # the whole warm pipeline — ladder warmup included — compiled
     # nothing (counter covers the entire second run)
@@ -312,22 +345,20 @@ def test_slo_report_shape_and_padding_section():
 # audit registration
 
 def test_serve_entries_registered_and_green():
-    """serve.dispatch + serve.dispatch_ragged (jit tier) and
-    serve.batcher + serve.pool (host tier) are registered entry points
-    and pass the trace rules + the recompile sentinel (warm == 0 for
-    the dispatch programs; zero compiles and zero device arrays for
-    the bookkeeping)."""
+    """serve.dispatch (jit tier) and serve.batcher (host tier) are
+    registered entry points and pass the trace rules + the recompile
+    sentinel (warm == 0 for the dispatch program; zero compiles and
+    zero device arrays for the bookkeeping)."""
     from ceph_tpu.analysis.entrypoints import registry
     from ceph_tpu.analysis.jaxpr_audit import (audit_entry_point,
                                                run_sentinel)
     ents = {e.name: e for e in registry()}
     assert ents["serve.dispatch"].kind == "jit"
-    assert ents["serve.dispatch_ragged"].kind == "jit"
-    assert ents["serve.dispatch_ragged_sharded"].kind == "jit"
     assert ents["serve.batcher"].kind == "host"
-    assert ents["serve.pool"].kind == "host"
-    for name in ("serve.dispatch", "serve.dispatch_ragged",
-                 "serve.pool", "serve.batcher"):
+    assert not [n for n in ents if n.startswith("serve.")
+                and n not in ("serve.dispatch", "serve.dispatch_sharded",
+                              "serve.batcher")]
+    for name in ("serve.dispatch", "serve.batcher"):
         e = ents[name]
         built = e.build()
         audit = audit_entry_point(e, built)
@@ -490,270 +521,22 @@ def test_default_spec_is_mixed_and_seeded():
     assert [r.req_id for r in reqs] == list(range(16))
 
 
-# ----------------------------------------------------------------------
-# paged stripe pool + ragged serving (ISSUE 18)
-
-MIXED_SIZES = (2048, 4096, 8192)
-
-
-def mixed_codecs(base: CodecSpec):
-    """The same (plugin, profile) at three stripe sizes — one ragged
-    queue, three dense buckets."""
-    return [CodecSpec(f"{base.name}_{s}", base.plugin,
-                      dict(base.profile), s) for s in MIXED_SIZES]
-
-
-def paged_spec(codecs, n=40, seed=7, **kw):
-    kw.setdefault("paged", True)
-    kw.setdefault("page_size", 512)
-    kw.setdefault("pool_pages", 64)
-    return small_spec(codecs, n=n, seed=seed, **kw)
-
-
-@pytest.mark.parametrize("codec", FAMILY_CODECS,
-                         ids=[c.name for c in FAMILY_CODECS])
-def test_paged_mixed_sizes_byte_identity_host(codec):
-    """Mixed stripe sizes co-batched in ONE ragged queue demux
-    byte-identical to per-request execution, for every plugin family:
-    generator ground truth AND a direct per-request surface call."""
-    spec = paged_spec(mixed_codecs(codec), n=30)
-    run = sim_run(spec)
-    assert len(run.results) == 30
-    assert verify_results(run.results) == []
-    st = run.batcher.padding_stats()
-    assert st["paged"] is True
-    # 512 divides every chunk in the mix: zero page-tail padding
-    assert st["padding_overhead"] == 0.0
-    # the three stripe sizes really co-batched: every fired queue key
-    # is chunk-size-free, so sizes share dispatch-log buckets
-    assert all(d.get("paged") for d in run.batcher.dispatch_log)
-    reg = ErasureCodePluginRegistry.instance()
-    ec = reg.factory(codec.plugin, dict(codec.profile))
-    ec.min_xla_bytes = float("inf")
-    for res in run.results[:6]:
-        req = res.request
-        if req.op == "encode":
-            ref = np.asarray(
-                ec.encode_chunks_batch(req.payload[None]))[0]
-            assert np.array_equal(res.output, ref)
-        else:
-            ref = np.asarray(ec.decode_chunks_batch(
-                req.payload[None], req.available, req.erased))[0]
-            rec = res.output[0] if req.op == "repair" else res.output
-            assert np.array_equal(rec, ref)
-
-
-def test_paged_mixed_sizes_device_seam():
-    """The jitted ragged seam (engine.serve_dispatch_ragged) serves a
-    mixed-size mixed-plugin stream byte-identical to ground truth."""
-    spec = paged_spec(mixed_codecs(RS4) + mixed_codecs(SHEC4), n=36,
-                      seed=11)
-    run = sim_run(spec, executor="device")
-    assert len(run.results) == 36
-    assert verify_results(run.results) == []
-    assert run.batcher.padding_stats()["padding_overhead"] == 0.0
-
-
-def test_ragged_ops_bytes_and_packed_identity():
-    """The ops-layer ragged family (bytes + packed resident layout)
-    matches mask-then-dense for a scattered activity mask, and dead
-    pages come back zero."""
-    from ceph_tpu.ops.pallas_gf import (apply_matrix_best,
-                                        apply_matrix_best_ragged,
-                                        apply_matrix_packed_best,
-                                        apply_matrix_packed_best_ragged,
-                                        mask_pages)
-    from ceph_tpu.ops.xla_ops import jax_bytes_view, jax_words_view
-    reg = ErasureCodePluginRegistry.instance()
-    ec = reg.factory(RS4.plugin, dict(RS4.profile))
-    ms = ec._matrix_static
-    rng = np.random.default_rng(5)
-    pool = rng.integers(0, 256, (6, ec.k, 512), dtype=np.uint8)
-    mask = np.array([1, 0, 1, 1, 0, 1], np.uint8)
-    words = np.asarray(jax_words_view(pool, 8))
-    out = np.asarray(jax_bytes_view(
-        apply_matrix_best_ragged(words, ms, mask, 8)))
-    ref = np.asarray(jax_bytes_view(apply_matrix_best(
-        np.asarray(mask_pages(words, mask)), ms, 8)))
-    assert np.array_equal(out, ref)
-    assert not out[mask == 0].any()
-    assert out[mask == 1].any()
-    # packed resident twin
-    packed = np.ascontiguousarray(
-        words.reshape(6, ec.k, -1, 4, 128).transpose(0, 1, 2, 4, 3)
-    ).view(np.uint32).reshape(6, ec.k, -1, 128)
-    pout = np.asarray(apply_matrix_packed_best_ragged(packed, ms, mask))
-    pref = np.asarray(apply_matrix_packed_best(
-        np.asarray(mask_pages(packed, mask)), ms))
-    assert np.array_equal(pout, pref)
-    assert not pout[mask == 0].any()
-
-
-def test_pool_exhaustion_backpressure():
-    """A write that cannot allocate fires the queue (demux reclaims
-    every page), then retries — requests keep flowing with the
-    backpressure counter as the witness, and bytes stay identical."""
-    clock = FakeClock()
-    batcher = ContinuousBatcher(clock=clock, executor="host",
-                                service_model=lambda b, r: 1e-4,
-                                paged=True, page_size=512,
-                                pool_pages=3)
-    ec = batcher._instance(RS4.plugin, RS4.profile)
-    reqs = []
-    rng = np.random.default_rng(3)
-    for i in range(4):  # 2 pages each, pool holds 3
-        pay = rng.integers(0, 256, (ec.k, 1024), dtype=np.uint8)
-        reqs.append(EcRequest(op="encode", plugin=RS4.plugin,
-                              profile=RS4.profile, stripe_size=4096,
-                              payload=pay, req_id=i, arrival=0.0,
-                              deadline=99.0))
-    fired = batcher.admit(reqs) + batcher.flush()
-    assert sorted(r.request.req_id for r in fired) == [0, 1, 2, 3]
-    ps = batcher.pool_stats()
-    assert ps["backpressure"] >= 1
-    assert ps["used_pages"] == 0 and ps["allocs"] == ps["reclaims"]
-    ec.min_xla_bytes = float("inf")
-    for res in fired:
-        ref = np.asarray(
-            ec.encode_chunks_batch(res.request.payload[None]))[0]
-        assert np.array_equal(res.output, ref)
-    # a single request no empty pool could hold is a sizing error
-    big = rng.integers(0, 256, (ec.k, 4096), dtype=np.uint8)
-    with pytest.raises(ValueError, match="pool"):
-        batcher.admit([EcRequest(op="encode", plugin=RS4.plugin,
-                                 profile=RS4.profile,
-                                 stripe_size=16384, payload=big,
-                                 req_id=9, arrival=0.0, deadline=99.0)])
-
-
-def test_page_reclaim_after_demux_accounting():
-    """Every fire returns its pages at demux: after a full mixed run
-    the pools are empty, allocs == reclaims, and the high-water mark
-    shows real co-residency happened."""
-    spec = paged_spec(mixed_codecs(RS4), n=24)
-    run = sim_run(spec)
-    assert verify_results(run.results) == []
-    ps = run.batcher.pool_stats()
-    assert ps["used_pages"] == 0
-    assert ps["allocs"] == ps["reclaims"] > 0
-    assert ps["high_water"] > 1
-    # tail-padding accounting stays byte-based and zero here
-    st = run.batcher.padding_stats()
-    assert st["padded_stripes"] == 0
-    assert st["padded_bytes"] == 0
-    # and a non-dividing page size shows nonzero page-tail bytes
-    spec2 = paged_spec([CodecSpec("rs_odd", RS4.plugin,
-                                  dict(RS4.profile), 4096)],
-                       n=8, page_size=768)
-    run2 = sim_run(spec2)
-    assert verify_results(run2.results) == []
-    st2 = run2.batcher.padding_stats()
-    assert st2["padded_bytes"] > 0
-    assert 0.0 < st2["padding_overhead"] < 1.0
-
-
-def test_paged_zero_recompiles_budget_armed():
-    """The paged acceptance gate: a warmed ragged stream over mixed
-    sizes compiles NOTHING on its second run — compile counter at 0
-    under an armed PatternCache recompile budget, and the cached-
-    program count stays at one program per (op, pattern) queue."""
-    from ceph_tpu.analysis.jaxpr_audit import _CompileCounter
-    from ceph_tpu.codes.engine import global_pattern_cache
-
-    spec = paged_spec(mixed_codecs(RS4) + mixed_codecs(SHEC4), n=200,
-                      seed=13, concurrency=32, pool=4)
-    first = sim_run(spec, executor="device")
-    assert len(first.results) == 200
-    assert verify_results(first.results) == []
-    cache = global_pattern_cache()
-    prev_budget = cache.recompile_budget
-    cache.recompile_budget = cache.builds
-    try:
-        with _CompileCounter() as counter:
-            second = sim_run(spec, executor="device")
-    finally:
-        cache.recompile_budget = prev_budget
-    assert len(second.results) == 200
-    assert verify_results(second.results) == []
-    assert counter.count == 0
-    assert second.report["stream_compiles"] == 0
-    assert first.batcher.dispatch_log == second.batcher.dispatch_log
-
-
-def test_paged_contention_pinned_acceptance():
-    """THE pinned mixed-size contention scenario (ISSUE 18 acceptance):
-    same seed, dense rung-ladder baseline vs paged ragged serving —
-    padding overhead < 1%, cached-program count strictly below the
-    bucket x rung count, GB/s-under-SLO at least matching."""
-    codecs = (mixed_codecs(RS4)
-              + [CodecSpec("rs_k4_m2_6k", RS4.plugin,
-                           dict(RS4.profile), 24576)]
-              + mixed_codecs(SHEC4))
-    base = dict(n=120, seed=29, concurrency=24)
-    dense = sim_run(small_spec(codecs, ladder=(1, 2, 4, 8), **base))
-    paged = sim_run(paged_spec(codecs, ladder=(1, 2, 4, 8),
-                               pool_pages=96, **base))
-    assert verify_results(dense.results) == []
-    assert verify_results(paged.results) == []
-    dstats = dense.batcher.padding_stats()
-    pstats = paged.batcher.padding_stats()
-    # the contention mix forces real dense padding; paged pays none
-    assert dstats["padding_overhead"] > 0.05
-    assert pstats["padding_overhead"] < 0.01
-    # program-count collapse: |patterns| strictly below |buckets|x|rungs|
-    assert paged.batcher.cached_program_count() < \
-        dense.batcher.cached_program_count()
-    # serving throughput under SLO at least matches the baseline
-    assert paged.report["gbps_under_slo"] >= \
-        dense.report["gbps_under_slo"]
-
-
-def test_paged_determinism_and_spec_roundtrip():
-    """Paged runs are as deterministic as dense ones (same seed ⇒ same
-    dispatch log + SLO report) and the paged fields survive the
-    TrafficSpec dict round-trip."""
-    spec = paged_spec(mixed_codecs(RS4), n=30)
-    a = sim_run(spec)
-    b = sim_run(spec)
-    assert a.batcher.dispatch_log == b.batcher.dispatch_log
-    assert json.dumps(a.report, sort_keys=True) == \
-        json.dumps(b.report, sort_keys=True)
-    spec2 = TrafficSpec.from_dict(spec.to_dict())
-    assert spec2.paged is True
-    assert spec2.page_size == 512 and spec2.pool_pages == 64
-    # dense specs round-trip their default too
-    spec3 = TrafficSpec.from_dict(small_spec([RS4]).to_dict())
-    assert spec3.paged is False
-
-
-def test_pool_selftest_and_interleave_roundtrip():
-    """The serve.pool host entry's selftest is green, and the
-    interleaved split/join honors clay's sub-chunk coupling (every
-    page a valid mini-chunk)."""
-    from ceph_tpu.serve import (PagedStripePool, PoolExhausted,
-                                pool_selftest, split_pages, join_pages)
-    st = pool_selftest()
-    assert st["ok"] and st["round_trips"] > 0
-    rng = np.random.default_rng(2)
-    arr = rng.integers(0, 256, (5, 2048), dtype=np.uint8)
-    pages = split_pages(arr, 512, interleave=8)
-    assert pages.shape == (4, 5, 512)
-    assert np.array_equal(join_pages(pages, 2048, interleave=8), arr)
-    # non-multiple page size is rejected up front
-    with pytest.raises(ValueError, match="interleave"):
-        split_pages(arr, 516, interleave=8)
-    # duplicate staging is rejected
-    pool = PagedStripePool(4, 5, 512)
-    pool.write("x", arr)
-    with pytest.raises(ValueError, match="already staged"):
-        pool.write("x", arr)
-    with pytest.raises(PoolExhausted):
-        pool.write("y", arr)
+@pytest.mark.parametrize("field,value", [
+    ("paged", True), ("page_size", 512), ("pool_pages", 64)])
+def test_traffic_spec_refuses_removed_fields(field, value):
+    """A saved spec that still names a knob of the removed page-pool
+    serving mode is refused, naming the field, instead of running on
+    the dense buckets without saying so."""
+    d = small_spec([RS4]).to_dict()
+    assert TrafficSpec.from_dict(d).to_dict() == d
+    d[field] = value
+    with pytest.raises(ValueError, match=field):
+        TrafficSpec.from_dict(d)
 
 
 def test_bench_diff_serving_padding_red_green(tmp_path, capsys):
     """Satellite: bench_diff's `serving_padding` category is the one
-    LOWER-is-better series — a paged row whose padding_overhead
+    LOWER-is-better series — a serving row whose padding_overhead
     reinflates past the floor trips rc 4; movement inside the
     absolute near-zero slack stays green."""
     import importlib.util
@@ -766,32 +549,32 @@ def test_bench_diff_serving_padding_red_green(tmp_path, capsys):
     spec.loader.exec_module(bd)
     prior = {"metric": "m", "value": 100.0, "git_sha": "aaa",
              "timestamp": "2026-01-01T00:00:00+00:00",
-             "serving_rows": {"serving_mixed_paged": {
+             "serving_rows": {"serving_mixed_closed": {
                  "gbps": 1.0, "gbps_under_slo": 1.0,
-                 "padding_overhead": 0.005, "paged": True}}}
+                 "padding_overhead": 0.05}}}
     (tmp_path / "BENCH_r01.json").write_text(json.dumps(
         {"n": 1, "cmd": "bench", "rc": 0, "tail": "", "parsed": prior}))
-    # red: padding reinflates 0.005 -> 0.2 (dense-bucket territory)
+    # red: padding reinflates 0.05 -> 0.2
     cur = {"metric": "m", "value": 100.0, "git_sha": "bbb",
            "timestamp": "2026-02-01T00:00:00+00:00",
-           "serving_rows": {"serving_mixed_paged": {
+           "serving_rows": {"serving_mixed_closed": {
                "gbps": 1.0, "gbps_under_slo": 1.0,
-               "padding_overhead": 0.2, "paged": True}}}
+               "padding_overhead": 0.2}}}
     (tmp_path / "BENCH_CURRENT.json").write_text(json.dumps(cur))
     rc = bd.main(["--repo", str(tmp_path), "--json"])
     assert rc == 4
     report = json.loads(capsys.readouterr().out)
     assert report["regressions"] == [
-        "serving_padding:serving_mixed_paged"]
-    # green: 0.005 -> 0.008 sits inside the absolute near-zero slack
-    cur["serving_rows"]["serving_mixed_paged"][
-        "padding_overhead"] = 0.008
+        "serving_padding:serving_mixed_closed"]
+    # green: 0.05 -> 0.055 sits inside the floor
+    cur["serving_rows"]["serving_mixed_closed"][
+        "padding_overhead"] = 0.055
     (tmp_path / "BENCH_CURRENT.json").write_text(json.dumps(cur))
     assert bd.main(["--repo", str(tmp_path)]) == 0
     capsys.readouterr()
-    # and a genuine paged improvement (0.005 -> 0.0) reads as ok/new
-    # direction, never a regression
-    cur["serving_rows"]["serving_mixed_paged"][
+    # and a genuine improvement (0.05 -> 0.0) never reads as a
+    # regression
+    cur["serving_rows"]["serving_mixed_closed"][
         "padding_overhead"] = 0.0
     (tmp_path / "BENCH_CURRENT.json").write_text(json.dumps(cur))
     assert bd.main(["--repo", str(tmp_path)]) == 0

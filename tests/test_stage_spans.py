@@ -73,8 +73,9 @@ def test_dense_device_stream_stage_histograms(world):
     """One bucket fills (fired by ``full`` inside ``admit``); the full
     fire's service time uses up the other bucket's slack, so the same
     poll fires it by ``deadline``: one sample per dispatch in each
-    stage histogram, reasons summing to the dispatches, and the full
-    fire nested in ``serve.admit`` left out of its self time."""
+    stage histogram, reasons summing to the dispatches, the full fire
+    nested in ``serve.admit`` left out of its self time, and none of
+    the removed series written."""
     clk = world
     rng = np.random.default_rng(1)
     b = _device_batcher(clk)
@@ -103,6 +104,12 @@ def test_dense_device_stream_stage_histograms(world):
     assert reasons == {"serve_dispatches{op=encode,reason=deadline}": 1,
                        "serve_dispatches{op=encode,reason=full}": 1}
     assert sum(reasons.values()) == b.dispatches
+    # the series whose information padding_stats() keeps are not
+    # written
+    (body,) = telemetry.global_metrics().dump().values()
+    for name in REMOVED:
+        assert not any(k == name or k.startswith(name + "{")
+                       for k in body), name
 
 
 def test_batch_wait_plus_admission_wait_is_queue_wait(world):
@@ -133,40 +140,6 @@ def test_batch_wait_plus_admission_wait_is_queue_wait(world):
     assert n_b == n_q == 2
     assert s_b + admission == pytest.approx(s_q)
     assert s_b == pytest.approx(2 * 0.25)
-
-
-def test_paged_reasons_and_removed_series(world):
-    """The ragged path fires by ``backpressure`` and ``flush``, gets
-    the stage spans where its stage exists (no pack: the pool is staged
-    at admission), and none of the removed series is written."""
-    clk = world
-    rng = np.random.default_rng(3)
-    b = ContinuousBatcher(clock=clk, executor="device",
-                          service_model=lambda q, r: 1e-4, paged=True,
-                          page_size=256, pool_pages=3)
-    q = AdmissionQueue(clock=clk)
-    for _ in range(2):                   # 2 pages each, the pool holds 3
-        q.submit(_req(1024, rng))
-    b.warmup([_req(1024, rng)])
-    out = b.poll(q) + b.flush()
-    assert len(out) == 2 and b.dispatches == 2
-    assert _series("serve_dispatches") == {
-        "serve_dispatches{op=encode,reason=backpressure}": 1,
-        "serve_dispatches{op=encode,reason=flush}": 1}
-    for name in STAGE_HISTS[1:]:
-        assert _hist(name)[0] == b.dispatches, name
-    assert _hist("serve_pack_seconds")[0] == 0
-    dense = _device_batcher(clk)
-    dense.warmup([_req(256, rng)])
-    for _ in range(2):
-        q.submit(_req(256, rng))
-    dense.poll(q)
-    dense.flush()
-    assert dense.padding_stats()["padded_stripes"] == 2
-    (body,) = telemetry.global_metrics().dump().values()
-    for name in REMOVED:
-        assert not any(k == name or k.startswith(name + "{")
-                       for k in body), name
 
 
 def test_host_executor_has_no_device_stages(world):

@@ -23,10 +23,9 @@ carries (ISSUE 10):
    ``autotune:<row>`` (the tuner's best after-utilization-% — a tuned
    config that later regresses fails CI, ISSUE 14),
    ``serving_padding:<row>`` (the ONE lower-is-better series:
-   serving padding_overhead — the paged stripe pool of ISSUE 18
-   holds it near zero, and a silent reinflation toward dense-bucket
-   padding must trip the sentinel; judged inverted, with an absolute
-   near-zero slack).
+   serving padding_overhead — the share of dispatched stripes that
+   were rung padding; a silent reinflation must trip the sentinel;
+   judged inverted, with an absolute near-zero slack).
    Other ratios/latency rows are deliberately excluded — one
    sentinel, one direction per category.
 3. **Diff with per-row noise floors** — the CURRENT record
@@ -105,11 +104,11 @@ FLOORS: Dict[str, float] = {
     # must still trip the sentinel (ISSUE 14)
     "autotune": 0.50,
     # serving padding_overhead (ISSUE 18): the one LOWER-is-better
-    # category — the fraction of dispatched bytes that were padding.
-    # The paged rows sit near zero (page tails only), so the ratio is
-    # taken with an absolute slack (PADDING_EPS) and a wide relative
-    # floor: a paged row silently reinflating toward dense-bucket
-    # padding must trip the sentinel, seeded-mix jitter must not
+    # category — the fraction of dispatched stripes that were rung
+    # padding.  Near-zero values make a bare ratio explode on noise,
+    # so the ratio is taken with an absolute slack (PADDING_EPS) and
+    # a wide relative floor: a row silently reinflating its padding
+    # must trip the sentinel, seeded-mix jitter must not
     "serving_padding": 0.50,
 }
 
@@ -117,9 +116,8 @@ FLOORS: Dict[str, float] = {
 # the minimum, and a regression is current ABOVE best * (1 + floor)
 LOWER_IS_BETTER = frozenset({"serving_padding"})
 
-# absolute slack for near-zero lower-is-better ratios: 0.01 is the
-# paged acceptance bound (padding_overhead < 0.01 under the pinned
-# mixed-size contention test), so movement inside it never trips
+# absolute slack for near-zero lower-is-better ratios: movement of
+# padding_overhead inside 0.01 never trips
 PADDING_EPS = 0.01
 
 

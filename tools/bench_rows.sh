@@ -110,16 +110,6 @@ run_row "row 7: serving — mixed rs/shec/clay request stream, closed loop (GB/s
     --workload serving -s $((1<<16)) --requests 256 \
     --concurrency 64 --seed 42 --json
 
-# row 7b (metric_version 15, ISSUE 18): same stream through the paged
-# stripe pool + ragged kernel family — mixed stripe sizes co-batch into
-# ONE device program per (plugin, op) pattern (no shape buckets).  The
-# row carries paged/cached_programs/page_pool and its byte-based
-# padding_overhead is the bench_diff `serving_padding` category.
-run_row "row 7b: serving (paged) — ragged co-batching over the paged stripe pool (near-zero padding; metric_version 15)" \
-    python -m ceph_tpu.bench.erasure_code_benchmark \
-    --workload serving -s $((1<<16)) --requests 256 \
-    --concurrency 64 --seed 42 --paged --json
-
 run_row "row 8: multichip — mesh-sharded encode over every visible device (ISSUE 8; byte-verified vs single-device, per-device partition in stripes_per_device)" \
     python -m ceph_tpu.bench.erasure_code_benchmark \
     -p jerasure -P technique=reed_sol_van -P k=8 -P m=3 \

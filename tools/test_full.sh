@@ -213,19 +213,9 @@ JAX_PLATFORMS=cpu \
     --entry engine.fused_repair_sharded \
     --entry engine.fused_repair_host_sharded \
     --entry serve.dispatch_sharded \
-    --entry serve.dispatch_ragged_sharded \
     --entry ops.apply_matrix_best_sharded \
     --entry crush.bulk_rule_sharded \
     || { echo "simulated-mesh gate: sharded entry audit failed"; exit 1; }
-# Ragged serving gate (ISSUE 18): the paged path's mask-gated program
-# must hold on the same 8-way virtual mesh — the page axis is the
-# shard axis, padded pages ride a zero mask
-JAX_PLATFORMS=cpu \
-    XLA_FLAGS="--xla_force_host_platform_device_count=8" \
-    python tools/tpu_lint.py --trace \
-    --entry serve.dispatch_ragged \
-    --entry serve.pool \
-    || { echo "ragged serving gate: paged entry audit failed"; exit 1; }
 JAX_PLATFORMS=cpu \
     XLA_FLAGS="--xla_force_host_platform_device_count=8" \
     python -m pytest tests/test_multichip.py tests/test_parallel.py -q \
